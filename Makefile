@@ -5,7 +5,7 @@ PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 .PHONY: all test test-cpu bench gen-protobuf native bpf verify-maps lint perftest bytecode-image \
-        dryrun smoke clean
+        dryrun smoke smoke-chip clean
 
 all: native gen-protobuf
 
@@ -16,11 +16,18 @@ test:
 test-cpu:
 	$(CPU_ENV) $(PY) -m pytest tests/ -x -q
 
+# needs a TPU (exits non-zero without one). The bench-* targets below ask
+# for the CPU by name: they give counts, bytes and correctness, and their
+# rates are the CPU's own — printed under cpu_* names, never a chip's
 bench:
 	$(PY) bench.py
 
 bench-cpu:
 	JAX_PLATFORMS=cpu $(PY) bench.py
+
+# the quickest proof that the system still starts on the chip
+smoke-chip:
+	$(PY) chip_smoke.py
 
 # host path only (~15s): pack/transfer/fold rates, pack-thread scaling,
 # roll-stall — the per-PR CI artifact (no device ingest loop, no oracle)
@@ -33,9 +40,9 @@ bench-host:
 bench-host-traced:
 	TRACE_SAMPLE=0.01 JAX_PLATFORMS=cpu $(PY) bench.py --host-only
 
-# per-stage device breakdown (~60s): ingest ablations (signals/asym/fanout
-# on/off), pallas-vs-scatter A/B (TPU), superbatch ladder 1x/2x/4x — the
-# per-PR CI artifact tracking the fusion win (docs/tpu_sketch.md)
+# per-stage breakdown on the CPU (~60s): ingest ablations (signals/asym/
+# fanout on/off), superbatch ladder 1x/2x/4x. The Pallas arms need a TPU
+# and do not run here
 bench-device:
 	JAX_PLATFORMS=cpu $(PY) bench.py --device-only
 
@@ -173,7 +180,7 @@ accuracy:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	  $(PY) scripts/accuracy_sweep.py
 
-# host-path + per-stage device profiles (run on the real chip when healthy)
+# host-path + per-stage profiles of whatever device JAX finds
 profile:
 	$(PY) benchmarks/host_path_profile.py
 	$(PY) benchmarks/ingest_stage_profile.py

@@ -25,8 +25,8 @@ SECONDS = 3.0
 
 
 def main() -> None:
-    from netobserv_tpu.utils.platform import maybe_force_cpu
-    maybe_force_cpu()
+    from netobserv_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     import jax
 
     from netobserv_tpu.datapath import flowpack

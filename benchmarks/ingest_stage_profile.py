@@ -26,8 +26,8 @@ SEGMENTS = 3
 
 
 def main() -> None:
-    from netobserv_tpu.utils.platform import maybe_force_cpu
-    maybe_force_cpu()
+    from netobserv_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 

@@ -10,15 +10,13 @@ import threading
 
 from netobserv_tpu import __version__
 from netobserv_tpu.agent import FlowsAgent
-from netobserv_tpu.config import load_config
+from netobserv_tpu.config import EXPORT_TPU_SKETCH, load_config
 from netobserv_tpu.metrics.server import start_metrics_server
 
 log = logging.getLogger("netobserv_tpu")
 
 
 def main() -> int:
-    from netobserv_tpu.utils.platform import maybe_force_cpu
-    maybe_force_cpu()  # honor an explicit JAX_PLATFORMS=cpu request
     cfg = load_config()
     logging.basicConfig(
         level=getattr(logging, cfg.log_level.upper(), logging.INFO),
@@ -26,6 +24,11 @@ def main() -> int:
         stream=sys.stderr)
     log.info("starting netobserv_tpu agent %s (export=%s)",
              __version__, cfg.export)
+    if cfg.export == EXPORT_TPU_SKETCH or cfg.federation_mode == "aggregator":
+        # the JAX-backed planes: persist compiled executables across
+        # restarts (jax-free exporters never import jax)
+        from netobserv_tpu.utils.platform import enable_compile_cache
+        log.info("jax compilation cache: %s", enable_compile_cache())
 
     dbg = None
     if cfg.pprof_addr:

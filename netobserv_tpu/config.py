@@ -325,7 +325,6 @@ class AgentConfig:  # noqa: PLR0902 - deliberately wide, mirrors reference
     sketch_checkpoint_dir: str = field(default="", **_env("SKETCH_CHECKPOINT_DIR"))
     sketch_checkpoint_every: int = field(default=0, **_env("SKETCH_CHECKPOINT_EVERY", "0"))
     sketch_mesh_shape: str = field(default="", **_env("SKETCH_MESH_SHAPE"))  # e.g. "2x4"
-    sketch_devices: str = field(default="", **_env("SKETCH_DEVICES"))  # "", "cpu", "tpu"
     #: auto (default) = fused MXU kernels on TPU at widths >= 16K, XLA
     #: scatter elsewhere; true/false (any bool spelling) force one path
     sketch_use_pallas: str = field(default="auto",
@@ -385,13 +384,12 @@ class AgentConfig:  # noqa: PLR0902 - deliberately wide, mirrors reference
     #: the CM planes + HLL banks narrow (u8 base + u16/u32 overflow tiers
     #: with in-executable saturation promotion; 6-bit packed HLL
     #: registers) — ~4x less HBM per resident sketch window at equal
-    #: geometry (docs/tpu_sketch.md "Tiered counter planes"). With the
-    #: fused Pallas walks the fold runs TIER-INTERIOR, directly on the
-    #: packed tiles (no wide decode temporary; width % 512 == 0 and
-    #: top_group <= 512 dividing it); otherwise folds decode to the
-    #: canonical wide tables transiently inside the same executable —
-    #: bit-exact either way. Single-device only; unset is bit-identical
-    #: to the wide-resident path.
+    #: geometry (docs/tpu_sketch.md "Tiered counter planes"). Folds decode
+    #: to the canonical wide tables transiently inside the same executable
+    #: (`tiered=decode` in /debug/executables) — on a TPU always: the
+    #: tier-interior Pallas walk is dead code on the device (Mosaic refuses
+    #: it; it runs only interpreted, in the CPU suites). Single-device
+    #: only; unset is bit-identical to the wide-resident path.
     sketch_tiered: bool = field(default=False, **_env("SKETCH_TIERED", "false"))
     #: CM columns sharing one u16 MID overflow cell (power of two)
     sketch_tier_mid_group: int = field(

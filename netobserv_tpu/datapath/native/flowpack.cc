@@ -74,8 +74,8 @@ size_t fp_pack(const uint8_t *events, size_t n, struct fp_columns *out) {
 }
 
 // Dense TPU feed: one (batch_size, FP_DENSE_WORDS) u32 row-major array per
-// batch instead of six column arrays — a single host->device transfer on a
-// tunneled/PCIe link instead of six round trips, and a single pass over the
+// batch instead of six column arrays — a single host->device transfer
+// instead of six round trips, and a single pass over the
 // raw event bytes (no intermediate FlowBatch, no Python copies). Row layout
 // (must match flowpack.py pack_dense/DENSE_WORDS and the device-side unpack
 // in sketch/state.py dense_to_arrays):

@@ -137,7 +137,22 @@ def heavy_identity_index(report) -> dict:
     return out
 
 
-def report_to_json(report, max_heavy: int = 64,
+#: heavy-hitter rows in the window report a sink receives (and the rows
+#: victim names are drawn from). /query/topk serves `?n=` up to the whole
+#: slot table from the query snapshot, which renders more
+REPORT_HEAVY = 64
+
+
+def _for_sink(obj: dict) -> dict:
+    """The rendered report as a sink receives it: the snapshot's copy may
+    carry the whole slot table, a sink's stays at REPORT_HEAVY rows."""
+    heavy = obj["HeavyHitters"]
+    if len(heavy) <= REPORT_HEAVY:
+        return obj
+    return {**obj, "HeavyHitters": heavy[:REPORT_HEAVY]}
+
+
+def report_to_json(report, max_heavy: int = REPORT_HEAVY,
                    scan_fanout_threshold: float = DEFAULT_SCAN_FANOUT,
                    ddos_z_threshold: float = DEFAULT_DDOS_Z,
                    synflood_min: float = DEFAULT_SYNFLOOD_MIN,
@@ -234,9 +249,10 @@ def report_to_json(report, max_heavy: int = 64,
     # rendering must never dispatch a device op)
     from netobserv_tpu.query.core import victim_bucket_names
     n_buckets = np.asarray(report.ddos_z).shape[0]
+    named = sel[:REPORT_HEAVY]
     dst_bucket_names = victim_bucket_names(
-        words[np.asarray(sel, dtype=np.int64)] if sel
-        else words[:0], heavy, n_buckets)
+        words[np.asarray(named, dtype=np.int64)] if named
+        else words[:0], heavy[:REPORT_HEAVY], n_buckets)
 
     def victims(bucket: int) -> list:
         return dst_bucket_names.get(int(bucket), [])
@@ -360,7 +376,7 @@ class TpuSketchExporter(Exporter):
     supports_columnar = True
 
     def __init__(self, batch_size: int = 8192, window_s: float = 60.0,
-                 sketch_cfg=None, mesh_shape: str = "", devices: str = "",
+                 sketch_cfg=None, mesh_shape: str = "",
                  sink: Optional[ReportSink] = None, metrics=None,
                  checkpoint_dir: str = "", checkpoint_every: int = 0,
                  decay_factor: Optional[float] = None,
@@ -919,9 +935,13 @@ class TpuSketchExporter(Exporter):
                         # divergent SPMD programs later — fail the startup
                         # loudly instead of hanging a collective mid-run
                         raise
-                    # single process: warm is best-effort, never fatal
-                    log.warning("superbatch ladder warm (k=%d) failed: %s",
-                                k, exc)
+                    # single process: never fatal — the entry stays
+                    # unselectable and folds ride the smaller ones. But a
+                    # warm fails by not lowering or compiling, which no
+                    # retry repairs: say so at error level, by name
+                    log.error("superbatch ladder entry %r failed to warm "
+                              "and stays disabled: %s",
+                              getattr(ring._ingests[k], "name", k), exc)
 
         if block:
             _warm()
@@ -1770,7 +1790,11 @@ class TpuSketchExporter(Exporter):
         prev = (self._prev_heavy_index if tenant is None
                 else self._tenant_prev_heavy.get(tenant))
         obj = report_to_json(
-            report, scan_fanout_threshold=self._scan_fanout,
+            # the whole slot table, heaviest first: /query/topk serves
+            # `?n=` up to the table size from the snapshot's copy; a sink
+            # gets the first REPORT_HEAVY (_for_sink)
+            report, max_heavy=self._cfg.topk,
+            scan_fanout_threshold=self._scan_fanout,
             ddos_z_threshold=self._ddos_z,
             synflood_min=self._synflood_min,
             synflood_ratio=self._synflood_ratio,
@@ -1834,6 +1858,16 @@ class TpuSketchExporter(Exporter):
                    "window_s": self._window_s,
                    "refresh_s": self._query_refresh_s,
                    "overloaded": self.overloaded})
+        ring = self._ring
+        if isinstance(ring, staging.ShardedResidentStagingRing):
+            # which superbatch entries are compiled and selectable (the
+            # construction warm enables them one by one; one that failed to
+            # compile never appears) and what has been dispatched so far
+            st["superbatch"] = {
+                "ladder": list(ring.ladder),
+                "warm": ring.warm_entries(),
+                "folds": {str(k): n for k, n
+                          in sorted(ring.superbatch_folds.items())}}
         if getattr(self, "_tiered_degraded", False):
             # mirror of the tiered_degraded supervisor condition: why
             # resident memory is wide despite SKETCH_TIERED being set
@@ -2057,7 +2091,7 @@ class TpuSketchExporter(Exporter):
             if self._metrics is not None:
                 self._metrics.count_error("tpu-sketch-query")
         with wtrace.stage("report_sink"):
-            self._sink(obj)
+            self._sink(_for_sink(obj))
         # sketch-warehouse write LAST, in its own try: the report already
         # reached the sink and the query snapshot already swapped in, so a
         # failing (or wedged) archive disk loses only durability of THIS
@@ -2168,7 +2202,7 @@ class TpuSketchExporter(Exporter):
                         self._metrics.count_error("tpu-sketch-query")
         with wtrace.stage("report_sink"):
             for obj in objs:
-                self._sink(obj)
+                self._sink(_for_sink(obj))
         if self._archive is not None and tables is not None:
             try:
                 with wtrace.stage("archive_write"):

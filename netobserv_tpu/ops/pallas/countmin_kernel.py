@@ -26,6 +26,14 @@ from netobserv_tpu.ops.pallas import tier_tiles
 
 TILE_W = 512
 CHUNK_B = 1024
+#: contraction precision of every one-hot matmul in ops/pallas. The MXU's
+#: default for f32 operands is ONE bf16 pass, which rounds each value to 8
+#: mantissa bits before it is added (seen on the v5e, PR 21: byte sums off by
+#: up to 159 per counter, in both directions — a Count-Min that can
+#: UNDERestimate). HIGHEST keeps the f32 product exact, so integer-valued
+#: sums below 2^24 equal the scatter twin's bit for bit, compiled as
+#: interpreted.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def _fold_kernel(counts_ref, idx_ref, vals_ref, out_ref, *, depth: int,
@@ -36,12 +44,12 @@ def _fold_kernel(counts_ref, idx_ref, vals_ref, out_ref, *, depth: int,
 
     def chunk_body(i, acc):
         sl = pl.dslice(i * CHUNK_B, CHUNK_B)
-        vals = vals_ref[sl].reshape(1, CHUNK_B)
+        vals = vals_ref[:, sl]                       # [1, CHUNK_B]
         new_rows = []
         for r in range(depth):  # static unroll over sketch depth
             idx = idx_ref[r, sl].reshape(CHUNK_B, 1)
             onehot = (idx == lanes).astype(jnp.float32)  # [CHUNK_B, TILE_W]
-            contrib = jnp.dot(vals, onehot,
+            contrib = jnp.dot(vals, onehot, precision=EXACT,
                               preferred_element_type=jnp.float32)
             new_rows.append(acc[r] + contrib[0])
         return jnp.stack(new_rows)
@@ -68,7 +76,7 @@ def _fold2_kernel(counts_ref, idx_ref, vals_ref, out_ref, *, depth: int,
         for r in range(depth):  # static unroll over sketch depth
             idx = idx_ref[r, sl].reshape(CHUNK_B, 1)
             onehot = (idx == lanes).astype(jnp.float32)  # [CHUNK_B, TILE_W]
-            contrib = jnp.dot(vals, onehot,
+            contrib = jnp.dot(vals, onehot, precision=EXACT,
                               preferred_element_type=jnp.float32)  # [2, W]
             new_rows.append(acc[:, r] + contrib)
         return jnp.stack(new_rows, axis=1)           # [2, d, TILE_W]
@@ -157,7 +165,7 @@ def _tier2_kernel(base_ref, mid_ref, top_ref, idx_ref, vals_ref,
         for r in range(depth):  # static unroll over sketch depth
             idx = idx_ref[r, sl].reshape(CHUNK_B, 1)
             onehot = (idx == lanes).astype(jnp.float32)  # [CHUNK_B, TILE_W]
-            contrib = jnp.dot(vals, onehot,
+            contrib = jnp.dot(vals, onehot, precision=EXACT,
                               preferred_element_type=jnp.float32)  # [2, W]
             new_rows.append(acc[:, r] + contrib)
         return jnp.stack(new_rows, axis=1)           # [2, d, TILE_W]
@@ -190,10 +198,20 @@ def _tier2_kernel(base_ref, mid_ref, top_ref, idx_ref, vals_ref,
     jax.lax.fori_loop(0, n_chunks, q_body, 0)
 
 
-def tiered_eligible(width: int, spec) -> bool:
+def tiered_eligible(width: int, spec, interpret: bool | None = None) -> bool:
     """Static gate for the tier-interior walk: whole tiles, whole top
-    groups per tile (so promotion never crosses a tile boundary)."""
-    return width % TILE_W == 0 and TILE_W % spec.top_group == 0
+    groups per tile (so promotion never crosses a tile boundary) — and only
+    where the kernel INTERPRETS. Compiled, Mosaic refuses it twice over (v5e,
+    PR 21): the tier blocks are `TILE_W // group` lanes wide — 16 and 2 at
+    the default groups, neither a multiple of 128 nor the full dimension —
+    and with lane-aligned groups (2, 4) the u32 top tier has no cast to f32.
+    A TPU therefore folds tiers through the decode wrap, which compiles,
+    and this walk is dead code on the device (debt D5: delete it or make it
+    compile)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return (interpret and width % TILE_W == 0
+            and TILE_W % spec.top_group == 0)
 
 
 def update_two_tiered(plane_a, plane_b, h1: jax.Array, h2: jax.Array,
@@ -210,7 +228,7 @@ def update_two_tiered(plane_a, plane_b, h1: jax.Array, h2: jax.Array,
         interpret = jax.default_backend() != "tpu"
     d, w = plane_a.base.shape
     assert plane_b.base.shape == (d, w)
-    assert tiered_eligible(w, spec), \
+    assert tiered_eligible(w, spec, interpret), \
         f"width {w} / top_group {spec.top_group} ineligible for tier tiles"
     mg, tg = spec.mid_group, spec.top_group
     b = h1.shape[0]
@@ -291,11 +309,11 @@ def update(cm: CountMin, h1: jax.Array, h2: jax.Array, values: jax.Array,
         in_specs=[
             pl.BlockSpec((d, TILE_W), lambda j: (0, j)),   # counts tile
             pl.BlockSpec((d, idx.shape[1]), lambda j: (0, 0)),  # all indices
-            pl.BlockSpec((idx.shape[1],), lambda j: (0,)),      # all values
+            pl.BlockSpec((1, idx.shape[1]), lambda j: (0, 0)),  # all values
         ],
         out_specs=pl.BlockSpec((d, TILE_W), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((d, w), jnp.float32),
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(cm.counts.astype(jnp.float32), idx, vals)
+    )(cm.counts.astype(jnp.float32), idx, vals.reshape(1, -1))
     return CountMin(counts=new_counts)

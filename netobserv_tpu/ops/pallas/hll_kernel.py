@@ -27,8 +27,8 @@ def _fold_kernel(regs_ref, idx_ref, rank_ref, out_ref, *, n_chunks: int):
 
     def chunk_body(i, acc):
         sl = pl.dslice(i * CHUNK_B, CHUNK_B)
-        idx = idx_ref[sl].reshape(CHUNK_B, 1)
-        rank = rank_ref[sl].reshape(CHUNK_B, 1)
+        idx = idx_ref[0, sl].reshape(CHUNK_B, 1)
+        rank = rank_ref[0, sl].reshape(CHUNK_B, 1)
         contrib = jnp.max(jnp.where(idx == lanes, rank, 0), axis=0)
         return jnp.maximum(acc, contrib)
 
@@ -56,14 +56,17 @@ def _fold_flat(regs_flat: jax.Array, idx: jax.Array, rank: jax.Array,
         grid=(m // TILE_M,),
         in_specs=[
             pl.BlockSpec((1, TILE_M), lambda j: (0, j)),
-            pl.BlockSpec((idx.shape[0],), lambda j: (0,)),
-            pl.BlockSpec((idx.shape[0],), lambda j: (0,)),
+            # whole-batch blocks ride as [1, B] rows: a 1-D block does not
+            # survive vmap (the tenant stack), whose batching rule leaves
+            # (Squeezed, B) — a shape Mosaic refuses
+            pl.BlockSpec((1, idx.shape[0]), lambda j: (0, 0)),
+            pl.BlockSpec((1, idx.shape[0]), lambda j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, TILE_M), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(regs_flat.reshape(1, m), idx, rank)
+    )(regs_flat.reshape(1, m), idx.reshape(1, -1), rank.reshape(1, -1))
     return new_regs.reshape(m)
 
 

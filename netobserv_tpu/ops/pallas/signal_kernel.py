@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from netobserv_tpu.ops.pallas import tier_tiles
+from netobserv_tpu.ops.pallas.countmin_kernel import EXACT
 
 CHUNK_B = 1024
 #: packed-HLL register-triple tile width of the tiered variant's grid
@@ -87,15 +88,15 @@ def _signal_fold_body(main_ref, aux_ref, idx_ref, vals_ref, main_out,
             return (idx == lanes).astype(jnp.float32)           # [C, W]
 
         # one one-hot build per index family, shared by its value rows
-        c_dst = jnp.dot(vals[0:3], onehot(0, lanes_m),
+        c_dst = jnp.dot(vals[0:3], onehot(0, lanes_m), precision=EXACT,
                         preferred_element_type=jnp.float32)     # [3, m]
-        c_src = jnp.dot(vals[3:4], onehot(1, lanes_m),
+        c_src = jnp.dot(vals[3:4], onehot(1, lanes_m), precision=EXACT,
                         preferred_element_type=jnp.float32)     # [1, m]
-        c_pair = jnp.dot(vals[4:6], onehot(2, lanes_m),
+        c_pair = jnp.dot(vals[4:6], onehot(2, lanes_m), precision=EXACT,
                          preferred_element_type=jnp.float32)    # [2, m]
-        c_dscp = jnp.dot(vals[6:7], onehot(3, lanes_a),
+        c_dscp = jnp.dot(vals[6:7], onehot(3, lanes_a), precision=EXACT,
                          preferred_element_type=jnp.float32)    # [1, AUX_W]
-        c_cause = jnp.dot(vals[7:8], onehot(4, lanes_a),
+        c_cause = jnp.dot(vals[7:8], onehot(4, lanes_a), precision=EXACT,
                           preferred_element_type=jnp.float32)   # [1, AUX_W]
         new_main = acc_main + jnp.concatenate([c_dst, c_src, c_pair], axis=0)
         new_aux = acc_aux + jnp.concatenate([c_dscp, c_cause], axis=0)

@@ -27,9 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from netobserv_tpu.ops import countmin, ewma, hll, quantile, topk
-from netobserv_tpu.parallel.mesh import (
-    DATA_AXIS, SKETCH_AXIS, shard_map_compat,
-)
+from netobserv_tpu.parallel.mesh import DATA_AXIS, SKETCH_AXIS
 from netobserv_tpu.sketch import state as sk
 from netobserv_tpu.utils import retrace
 
@@ -177,11 +175,11 @@ def make_sharded_ingest_fn(mesh: Mesh, cfg: sk.SketchConfig,
     # one spec as a pytree PREFIX covers the whole batch: every column is
     # row-sharded over the data axis, whatever feature columns it carries
     batch_specs = P(DATA_AXIS)
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, batch_specs),
         out_specs=(specs, P(DATA_AXIS)) if with_token else specs,
-        check=False,
+        check_vma=False,
     )
     # retrace watchdog (utils/retrace.py): the wrapper delegates .lower /
     # ._cache_size, so the HLO no-collectives checks still introspect it
@@ -241,11 +239,11 @@ def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
                       enable_asym=cfg.enable_asym)
         return _add_lead(s), tbl[None], flat[:1]
 
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(specs, P(DATA_AXIS), P(DATA_AXIS)),
-        check=False,
+        check_vma=False,
     )
     return retrace.watch(
         jax.jit(shmapped, donate_argnums=(0, 1) if donate else ()),
@@ -398,11 +396,11 @@ def make_fold_delta_fn(mesh: Mesh, cfg: sk.SketchConfig,
         new = jax.tree.map(lambda a, b: jnp.where(mine, a, b), merged, s)
         return _add_lead(new)
 
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         local_fold, mesh=mesh,
         # tables + owner are replicated to every device; the fold masks
         in_specs=(specs, P(), P()),
-        out_specs=specs, check=False,
+        out_specs=specs, check_vma=False,
     )
     return retrace.watch(
         jax.jit(shmapped, donate_argnums=(0,) if donate else ()),
@@ -525,9 +523,9 @@ def make_merge_fn(mesh: Mesh, cfg: sk.SketchConfig,
         out_specs = (specs, report_specs, table_specs)
     else:
         out_specs = (specs, report_specs)
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         local_roll, mesh=mesh, in_specs=(specs,),
-        out_specs=out_specs, check=False,
+        out_specs=out_specs, check_vma=False,
     )
     return retrace.watch(jax.jit(shmapped, donate_argnums=(0,)),
                          "sharded_merge")

@@ -38,28 +38,6 @@ class MeshSpec:
         raise ValueError(f"bad mesh shape {text!r} (want D or DxS)")
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """`jax.shard_map` across the jax versions this project meets: newer
-    releases expose it at the top level with `check_vma`; 0.4.x only has
-    `jax.experimental.shard_map.shard_map` with the same knob spelled
-    `check_rep`. One definition so every shard_map call site stays
-    version-agnostic."""
-    fn = getattr(jax, "shard_map", None)
-    kwargs = {"check_vma": check}
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-        kwargs = {"check_rep": check}
-    try:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
-    except TypeError:
-        # the transition releases spell the knob the other way around
-        other = ({"check_rep": check} if "check_vma" in kwargs
-                 else {"check_vma": check})
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **other)
-
-
 def make_mesh(spec: Optional[MeshSpec] = None,
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     devices = list(devices if devices is not None else jax.devices())

@@ -87,6 +87,20 @@ def _describe(args: tuple, limit: int = 600) -> str:
     return desc if len(desc) <= limit else desc[:limit] + "...(truncated)"
 
 
+def _avals(args: tuple) -> tuple:
+    """`args` as ShapeDtypeStructs (sharding kept) — what `.lower` needs to
+    rebuild the same executable without holding the buffers."""
+    try:
+        import jax
+
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
+            args)
+    except Exception:  # never let diagnostics break the caller
+        return ()
+
+
 def _donated_bytes(args: tuple) -> int:
     """Sum of array-argument bytes at compile time: the donated-buffer HBM
     estimate for one dispatch of this signature (the state arrays the fold
@@ -110,8 +124,8 @@ class Watched:
 
     __slots__ = ("_fn", "name", "warmup_calls", "calls", "compiles",
                  "retraces", "last_retrace", "dispatch_seconds",
-                 "compile_seconds", "last_signature", "donated_bytes",
-                 "tenants", "tiered", "__weakref__")
+                 "compile_seconds", "last_signature", "last_avals",
+                 "donated_bytes", "tenants", "tiered", "__weakref__")
 
     def __init__(self, fn: Callable, name: str, warmup_calls: int,
                  tenants: Optional[int] = None,
@@ -126,6 +140,10 @@ class Watched:
         self.dispatch_seconds = 0.0
         self.compile_seconds = 0.0
         self.last_signature: str = ""
+        #: abstract arguments (shape, dtype, sharding) of the last compile:
+        #: `w.lower(*w.last_avals)` re-lowers the executable that serves
+        #: steady state, for HLO checks of what was really dispatched
+        self.last_avals: tuple = ()
         self.donated_bytes = 0
         #: tenant count of a tenant-stacked (vmapped) executable — the
         #: /debug/executables registry reports the stacked fold as ONE fn
@@ -144,6 +162,16 @@ class Watched:
         t0 = time.perf_counter()
         try:
             return self._fn(*args, **kwargs)
+        except Exception as exc:
+            if self.calls <= self.warmup_calls:
+                # a first call that fails is a lowering or compile refusal
+                # (or a backend that will not start): not transient, and
+                # the callers' swallow-and-count handlers do not know which
+                # executable it was
+                log.error("first call of jitted entry %r failed — the "
+                          "executable did not lower/compile: %s: %s",
+                          self.name, type(exc).__name__, exc)
+            raise
         finally:
             # one monotonic-clock pair per DISPATCH (per batch, never per
             # record) — the wall attribution the accounting registry exists
@@ -181,6 +209,7 @@ class Watched:
             # as the tier-interior walk or the decode-to-wide wrap
             sig = f"tiered={self.tiered} {sig}"
         self.last_signature = sig
+        self.last_avals = _avals(args)
         self.donated_bytes = _donated_bytes(args)
         if self.calls <= self.warmup_calls:
             return  # expected warmup compile
@@ -254,7 +283,9 @@ def watch(fn: Callable, name: str,
     return w
 
 
-def _live_watched() -> list[Watched]:
+def watched() -> list[Watched]:
+    """Every live wrapper (the registry rows' owners — `lower` one with its
+    `last_avals` to inspect the executable it dispatched)."""
     return [w for w in (r() for r in _registry) if w is not None]
 
 
@@ -278,7 +309,7 @@ def configure(enabled: Optional[bool] = None,
 
 def snapshot() -> list[dict]:
     """Per-entry-point compile accounting (live wrappers), for /debug/jax."""
-    return [w.stats() for w in _live_watched()]
+    return [w.stats() for w in watched()]
 
 
 def total_retraces() -> int:

@@ -24,10 +24,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
 
-from netobserv_tpu.utils.platform import maybe_force_cpu  # noqa: E402
-
-maybe_force_cpu()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
@@ -316,6 +312,8 @@ def run_mesh_hll_case(zipf_s: float, seed: int = 0):
 
 
 def main() -> None:
+    from netobserv_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     rows = []
     for zipf_s in (1.1, 1.2, 1.5, 2.0):
         for width in (1 << 12, 1 << 14, 1 << 16):

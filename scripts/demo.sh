@@ -93,12 +93,12 @@ else
   echo "skipped (needs root + iproute2)"
 fi
 
-section "3. TPU-sketch analytics (window reports; CPU mesh if no chip)"
+section "3. TPU-sketch analytics (window reports, on the CPU)"
 JAX_PLATFORMS=cpu DATAPATH=synthetic EXPORT=tpu-sketch SKETCH_WINDOW=3s \
   SKETCH_CM_WIDTH=16384 SKETCH_TOPK=64 CACHE_ACTIVE_TIMEOUT=300ms \
   timeout 10 $PY -m netobserv_tpu 2>/dev/null | head -1 || true
 
-section "4. Benchmark (host path + roll stall + device loop)"
+section "4. Benchmark on the CPU (counts and correctness; rates are the CPU's own)"
 JAX_PLATFORMS=cpu timeout 480 $PY bench.py 2>/dev/null | tail -1 || true
 
 section "5. Multichip dry-run (8 virtual devices)"

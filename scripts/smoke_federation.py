@@ -35,9 +35,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main() -> int:
-    from netobserv_tpu.utils.platform import maybe_force_cpu
-    maybe_force_cpu()
-
     from netobserv_tpu.config import AgentConfig
     from netobserv_tpu.exporter.federation import FederationDeltaSink
     from netobserv_tpu.exporter.tpu_sketch import TpuSketchExporter
@@ -192,9 +189,6 @@ def run_failure_path(checkpoint_dir: str = "") -> dict:
     caller owns `checkpoint_dir` cleanup; "" runs without checkpointing
     (the window counter then restarts at 0 — seq monotonicity is only
     asserted when a checkpoint dir is given)."""
-    from netobserv_tpu.utils.platform import maybe_force_cpu
-    maybe_force_cpu()
-
     from netobserv_tpu.config import AgentConfig
     from netobserv_tpu.exporter.federation import FederationDeltaSink
     from netobserv_tpu.exporter.tpu_sketch import TpuSketchExporter

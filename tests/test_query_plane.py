@@ -229,6 +229,24 @@ def test_roll_publishes_snapshot_with_tables():
         exp.close()
 
 
+def test_sink_report_keeps_64_heavy_rows_while_the_snapshot_keeps_all():
+    """/query/topk promises `?n=` up to the slot table, so the snapshot's
+    report renders all of it; a sink's copy stays at REPORT_HEAVY rows."""
+    from netobserv_tpu.exporter import tpu_sketch
+    from netobserv_tpu.query.core import topk_payload
+
+    snap = _snap()
+    row = snap["report"]["HeavyHitters"][0]
+    snap["report"]["HeavyHitters"] = [
+        {**row, "EstBytes": 900.0 - i} for i in range(100)]
+    sunk = tpu_sketch._for_sink(snap["report"])
+    assert len(sunk["HeavyHitters"]) == tpu_sketch.REPORT_HEAVY == 64
+    served = topk_payload(snap, n=1024)["topk"]
+    assert len(served) == 100 and served[:64] == sunk["HeavyHitters"]
+    small = _snap()["report"]
+    assert tpu_sketch._for_sink(small) is small
+
+
 def test_snapshot_age_grows_without_refresh_and_resets_at_roll():
     m = Metrics()
     exp = make_exporter(metrics=m)

@@ -234,7 +234,12 @@ def test_zero_retraces_across_ladder():
 
     exp = TpuSketchExporter(batch_size=B, window_s=3600, sketch_cfg=CFG,
                             sink=lambda rep: None, superbatch=(1, 2, 4))
+    # /query/status says which entries a fold may select: x1 from the
+    # start, the rest only once their warm compile landed
+    assert exp.query_status()["superbatch"] == {
+        "ladder": [1, 2, 4], "warm": [1], "folds": {}}
     exp.warm_superbatch_ladder(block=True)
+    assert exp.query_status()["superbatch"]["warm"] == [1, 2, 4]
     # single-device names ingest_resident_lanes_x{k}; the 8-virtual-device
     # mesh (tests/conftest.py) names sharded_ingest_resident_x{k}
     prefixes = ("ingest_resident_lanes_x", "sharded_ingest_resident_x")

@@ -463,6 +463,37 @@ def test_retrace_warmup_window_suppresses_false_positives():
             in text)
 
 
+def test_first_call_failure_names_the_executable_at_error_level(caplog):
+    """A first call that fails did not lower or compile — not transient.
+    The callers swallow and count without knowing which executable it was,
+    so the wrapper says it, by name, at ERROR; later failures stay theirs."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = retrace.watch(jax.jit(lambda x: x.reshape(7)), "refused_entry")
+    with caplog.at_level("ERROR", logger="netobserv_tpu.retrace"):
+        with pytest.raises(TypeError):
+            fn(jnp.ones(3))
+        assert ["refused_entry" in r.getMessage() and r.levelname == "ERROR"
+                for r in caplog.records] == [True]
+        with pytest.raises(TypeError):
+            fn(jnp.ones(3))
+        assert len(caplog.records) == 1
+
+
+def test_last_avals_relower_the_dispatched_executable():
+    """`last_avals` (shape, dtype, sharding of the last compile) is enough
+    to re-lower what was dispatched, without holding its buffers."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = retrace.watch(jax.jit(lambda a, b: a @ b["w"]), "avals_entry")
+    fn(jnp.ones((4, 8)), {"w": np.ones((8, 2), np.float32)})
+    assert jax.tree.map(lambda x: x.shape, fn.last_avals) == \
+        ((4, 8), {"w": (8, 2)})
+    assert "dot_general" in fn.lower(*fn.last_avals).as_text()
+
+
 def test_retrace_watchdog_on_real_ingest_changed_batch_shape():
     """The CI-speed force-retrace: a jitted dense ingest fed a CHANGED batch
     shape after warmup must fire sketch_retraces_total."""
