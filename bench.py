@@ -755,7 +755,6 @@ def tiered_ablation_stats(segs: int = 4) -> dict:
         BASE_MAX, TierSpec, array_bytes, counter_table_bytes,
         plane_occupancy,
     )
-    from netobserv_tpu.utils import retrace
 
     rng = np.random.default_rng(777)
     universe, pool = make_pool(rng)
@@ -773,18 +772,20 @@ def tiered_ablation_stats(segs: int = 4) -> dict:
         """Deterministic fold sequence (feed tracked for the recall
         oracle) + per-segment steady-state rates, like tpu_ingest_rate."""
         state = sk.init_state(cfg)
-        ingest = sk.make_ingest_fn(donate=True, use_pallas=use_pallas,
-                                   tier_interior=tier_interior)
+        name, form = "bench_ingest", None
         if cfg.tiered is not None:
-            # watched so the artifact's executables stamp attributes the
-            # fold form (tiered=interior|decode), like /debug/executables;
-            # the registry holds wrappers weakly, so pin them until the
-            # artifact is printed (bench processes are short-lived)
+            # named so the artifact's executables stamp attributes the
+            # fold form (tiered=interior|decode), like /debug/executables
             form = sk.tiered_fold_form(cfg._replace(use_pallas=use_pallas))
             if tier_interior is False:
                 form = "decode"
-            ingest = retrace.watch(
-                ingest, f"bench_tiered_ingest_{form}", tiered=form)
+            name = f"bench_tiered_ingest_{form}"
+        ingest = sk.make_ingest_fn(donate=True, use_pallas=use_pallas,
+                                   tier_interior=tier_interior, name=name,
+                                   tiered=form)
+        if form is not None:
+            # the registry holds wrappers weakly, so pin them until the
+            # artifact is printed (bench processes are short-lived)
             _WATCHED_KEEPALIVE.append(ingest)
         feed: list[int] = []
         it = 0

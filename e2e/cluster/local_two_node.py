@@ -132,33 +132,48 @@ def main() -> dict:
     try:
         agents.append(start_agent(NS_A, A_NODE, "nodeA", port, "egress"))
         agents.append(start_agent(NS_B, B_NODE, "nodeB", port, "ingress"))
-        time.sleep(4)  # attach + first eviction timer
-        for p in agents:
-            assert p.poll() is None, f"agent died: {p.stderr.read()[-2000:]}"
+        def send(n: int, src_port: int, dst_port: int, payload: int) -> None:
+            sender = subprocess.run(ns_exec(NS_A, sys.executable, "-c", (
+                "import socket, time\n"
+                "s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)\n"
+                f"s.bind(('{A_IP}', {src_port}))\n"
+                f"for _ in range({n}):\n"
+                f"    s.sendto(b'x' * {payload}, ('{B_IP}', {dst_port}))\n"
+                "    time.sleep(0.05)\n")),
+                capture_output=True, text=True)
+            assert sender.returncode == 0, sender.stderr
+
+        def totals(node: str, dst_port: int = 7777) -> tuple[int, int]:
+            hits = logql(
+                port, f'{{job="netobserv",node="{node}"}} | json '
+                      f'| SrcAddr="{A_IP}" | DstAddr="{B_IP}" '
+                      f'| DstPort={dst_port}')
+            return (sum(int(h.get("Packets", 0)) for h in hits),
+                    sum(int(h.get("Bytes", 0)) for h in hits))
+
+        # attach + first eviction timer: proven, not slept for — probe
+        # datagrams on another port until BOTH agents account one (a fixed
+        # 4 s sleep lost the first measured datagrams whenever the machine
+        # was busy: "nodeA packets 8 != 9")
+        ready = time.time() + 60
+        while True:
+            for p in agents:
+                assert p.poll() is None, \
+                    f"agent died: {p.stderr.read()[-2000:]}"
+            send(1, 47001, 7776, 10)
+            time.sleep(0.5)
+            if totals("nodeA", 7776)[0] and totals("nodeB", 7776)[0]:
+                break
+            assert time.time() < ready, "agents did not attach within 60s"
 
         # known traffic: 9 UDP datagrams, 100B payload, nodeA -> nodeB
         n_pkts, payload = 9, 100
-        sender = subprocess.run(ns_exec(NS_A, sys.executable, "-c", (
-            "import socket, time\n"
-            "s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)\n"
-            f"s.bind(('{A_IP}', 47000))\n"
-            f"for _ in range({n_pkts}):\n"
-            f"    s.sendto(b'x' * {payload}, ('{B_IP}', 7777))\n"
-            "    time.sleep(0.05)\n")),
-            capture_output=True, text=True)
-        assert sender.returncode == 0, sender.stderr
+        send(n_pkts, 47000, 7777, payload)
 
         # flows evict on the 300ms timer, so one logical flow surfaces as a
         # few records; the per-flow accounting assertion sums them (the
         # reference queries Loki the same way and aggregates)
         expected_bytes = n_pkts * (payload + 8 + 20 + 14)  # L2 frame bytes
-
-        def totals(node: str) -> tuple[int, int]:
-            hits = logql(
-                port, f'{{job="netobserv",node="{node}"}} | json '
-                      f'| SrcAddr="{A_IP}" | DstAddr="{B_IP}" | DstPort=7777')
-            return (sum(int(h.get("Packets", 0)) for h in hits),
-                    sum(int(h.get("Bytes", 0)) for h in hits))
 
         deadline = time.time() + 20
         sent = recv = (0, 0)

@@ -6,7 +6,7 @@ The range plane answers ``/query/range?from=&to=`` (and the
 covering segments and merging their K table snapshots in ONE fixed-shape
 device dispatch: a warmed LADDER of merge sizes (powers of two up to
 `ladder_max` — the `SKETCH_SUPERBATCH` pattern), one pre-built jit per
-ladder k, every entry `retrace.watch`ed. K segments pad UP to the next
+ladder k, every entry made by `retrace.jit`. K segments pad UP to the next
 ladder size with ZERO tables (the exact merge identity: CM/hist/rates add
 zeros, HLL maxes zeros, an all-invalid slot table contributes no
 candidates), so shapes never depend on the request — zero post-warmup
@@ -129,7 +129,7 @@ class ArchiveQueryEngine:
             _new, report = sk.roll_window(state, cfg)
             return report, tables
 
-        fn = retrace.watch(jax.jit(merge_k), f"archive_merge_x{k}")
+        fn = retrace.jit(merge_k, f"archive_merge_x{k}")
         self._merge_fns[k] = fn
         return fn
 

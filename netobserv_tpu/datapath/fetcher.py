@@ -49,6 +49,10 @@ class EvictedFlows:
         self.nevents = nevents
         self.quic = quic
         self.decode_stats: Optional[dict] = None
+        #: process-wide sequence number MapTracer stamps at the drain (0 =
+        #: not drained by a MapTracer): the id its stages carry in a
+        #: profiler capture, from `evict` to the fold chunks
+        self.eviction = 0
         #: fused-pipeline extra (loader.PackedEviction): resident regions
         #: pre-packed at drain time. The raw arrays above are ALWAYS the
         #: full eviction regardless — a consumer that can't ship the packed

@@ -371,6 +371,14 @@ def report_to_json(report, max_heavy: int = REPORT_HEAVY,
     }
 
 
+def _tiered_decode(state):
+    """`tiered.decode_state` under the watch name of its jitted entry (one
+    function object per process: exporters share jax's trace cache)."""
+    from netobserv_tpu.sketch.tiered import decode_state
+
+    return decode_state(state)
+
+
 class TpuSketchExporter(Exporter):
     name = "tpu-sketch"
     supports_columnar = True
@@ -479,6 +487,12 @@ class TpuSketchExporter(Exporter):
         #: first sampled eviction's trace is finished by the fold that
         #: consumes its rows
         self._pending_trace = None
+        #: eviction sequence numbers (EvictedFlows.eviction) of the rows in
+        #: the pending buffer, oldest and newest: a fold chunk's
+        #: `evictions=<first>-<last>` in a profiler capture
+        self._evictions_first = self._evictions_last = 0
+        #: windows closed by this exporter (`window=<n>` on their stages)
+        self._windows_closed = 0
         # resident pack LANES cost per-lane device key tables and only pay
         # off where parallel dictionary probes actually scale: engage them
         # for an EXPLICIT SKETCH_PACK_THREADS (the operator chose), but an
@@ -683,20 +697,19 @@ class TpuSketchExporter(Exporter):
             # retrace watchdog: every jitted entry point the exporter can
             # dispatch is watched — its first compile is warmup, any later
             # compile alarms (sketch_retraces_total{fn=...})
-            self._ingest = retrace.watch(sk.make_ingest_fn(
+            self._ingest = sk.make_ingest_fn(
                 use_pallas=self._cfg.use_pallas,
                 enable_fanout=self._cfg.enable_fanout,
-                enable_asym=self._cfg.enable_asym), "ingest",
+                enable_asym=self._cfg.enable_asym, name="ingest",
                 tiered=self._tier_form)
             # with_tables unconditionally: the pre-roll table snapshot is
             # one extra output of the same roll executable, and it feeds
             # BOTH the federation delta export and the query plane's
             # per-roll snapshot (/query/frequency needs the CM planes)
             self._with_tables = True
-            self._roll = retrace.watch(
-                sk.make_roll_fn(self._cfg, decay_factor=decay_factor,
-                                with_tables=True),
-                "roll", tiered=self._tier_roll_form)
+            self._roll = sk.make_roll_fn(
+                self._cfg, decay_factor=decay_factor, with_tables=True,
+                name="roll", tiered=self._tier_roll_form)
             self._ring = self._make_single_device_ring(
                 feed, resident_slots, pack_threads, metrics)
         if self._tenancy is not None and self._ckpt is not None:
@@ -1165,8 +1178,9 @@ class TpuSketchExporter(Exporter):
         self._pack_surface = staging.ResidentPackSurface(ring)
         return self._pack_surface
 
-    def _fold_packed_locked(self, packed, trace) -> bool:
-        """Ship a fused-pipeline arena (caller holds the exporter lock).
+    def _fold_packed_locked(self, packed, trace, seq: int) -> bool:
+        """Ship a fused-pipeline arena of eviction `seq` (caller holds the
+        exporter lock).
         True = shipped (the eviction's raw rows are represented; don't
         buffer them). False = discarded (stale epoch / no surface): the
         caller folds the raw arrays instead — an EvictedFlows ALWAYS
@@ -1188,10 +1202,11 @@ class TpuSketchExporter(Exporter):
         if owned:
             trace = tracing.start_trace("fold")
         try:
-            with trace.stage("fold"):
+            with trace.stage("fold", eviction=seq):
                 faultinject.fire("sketch.ingest")
-                self._state = self._ring.fold_packed(self._state, packed,
-                                                     trace=trace)
+                self._state = self._ring.fold_packed(
+                    self._state, packed,
+                    trace=trace.bind(evictions=f"{seq}-{seq}"))
         except staging.StagingWedged as exc:
             # same adoption rule as _fold_events — dispatched segments
             # donated the state; and the surface must invalidate (this
@@ -1275,6 +1290,7 @@ class TpuSketchExporter(Exporter):
         `sampling` field, so the device de-bias keeps every estimate
         unbiased."""
         trace = getattr(evicted, "trace", None)
+        seq = getattr(evicted, "eviction", 0)
         with self._lock:
             packed = getattr(evicted, "packed", None)
             if packed is not None:
@@ -1283,7 +1299,7 @@ class TpuSketchExporter(Exporter):
                 # tests/test_native_pipeline.py); a stale epoch falls
                 # through to the raw path below
                 evicted.packed = None
-                if self._fold_packed_locked(packed, trace):
+                if self._fold_packed_locked(packed, trace, seq):
                     if trace is not None:
                         trace.finish()
                     if self._metrics is not None:
@@ -1313,6 +1329,12 @@ class TpuSketchExporter(Exporter):
                     self._pending_trace = trace  # the next fold finishes it
                 else:
                     trace.finish()  # rare: two sampled evictions in one fold
+            # the fold chunks name the evictions whose rows they carry: the
+            # pending buffer holds a sub-batch tail of the eviction before
+            # (if any) and now this one's rows
+            if not self._pending_buf.n:
+                self._evictions_first = seq
+            self._evictions_last = seq
             self._pending_buf.append(evicted, self._fold_events)
             if time.monotonic() >= self._window_deadline:
                 self._close_window_locked()
@@ -1370,8 +1392,12 @@ class TpuSketchExporter(Exporter):
         self._pending_trace = None
         if trace is None:
             trace = tracing.start_trace("fold")
+        first, last = self._evictions_first, self._evictions_last
+        # every fold takes a batch-aligned prefix that holds all of the
+        # older tail: what stays buffered is the newest eviction's alone
+        self._evictions_first = last
         try:
-            with trace.stage("fold"):
+            with trace.stage("fold", eviction=last):
                 faultinject.fire("sketch.ingest")
                 if self._pack_surface is not None:
                     # ship order must equal dict-mutation order: this raw
@@ -1380,8 +1406,9 @@ class TpuSketchExporter(Exporter):
                     # yet shipped) must not ship afterwards — no-op when
                     # none are outstanding (staging.ResidentPackSurface)
                     self._pack_surface.invalidate_for_raw_fold()
-                self._state = self._ring.fold(self._state, events,
-                                              trace=trace, **feats)
+                self._state = self._ring.fold(
+                    self._state, events,
+                    trace=trace.bind(evictions=f"{first}-{last}"), **feats)
         except staging.StagingWedged as exc:
             # the slot-wait budget tripped at a chunk boundary: the rows
             # not yet packed drop (no dictionary slot was committed for
@@ -1467,7 +1494,12 @@ class TpuSketchExporter(Exporter):
         """Drain pending rows and dispatch the roll, under ONE window trace
         (roll_drain + roll_dispatch spans; the render/sink spans attach when
         the queued report publishes on the timer thread)."""
-        wtrace = tracing.start_trace("window")
+        # `window=<n>` (this exporter's count of closed windows) rides the
+        # handle from here to the sink, so a capture ties a report's render
+        # and delivery to the roll that closed its window
+        self._windows_closed += 1
+        wtrace = tracing.start_trace("window").bind(
+            window=self._windows_closed)
         try:
             with wtrace.stage("roll_drain"):
                 self._drain_pending_locked()
@@ -1609,21 +1641,23 @@ class TpuSketchExporter(Exporter):
         sk = self._sk
         kw = dict(use_pallas=self._cfg.use_pallas, with_token=True,
                   enable_fanout=self._cfg.enable_fanout,
-                  enable_asym=self._cfg.enable_asym)
+                  enable_asym=self._cfg.enable_asym, tiered=self._tier_form)
         if feed == "resident":
             lanes = staging.pick_lanes(self._batch_size, self._lane_threads)
             ladder = self._superbatch
             bpl = self._batch_size // lanes
             caps = flowpack.default_resident_caps(bpl)
             # one fixed-shape jitted entry PER ladder size, every one under
-            # its own retrace watch — a post-warmup compile of any ladder
-            # shape is a live alarm (sketch_retraces_total{fn=..._xk})
+            # its own name and retrace watch — a post-warmup compile of any
+            # ladder shape is a live alarm (sketch_retraces_total{fn=..._xk})
+            # and a device capture reads jit_ingest_resident_lanes_x<k>
             ingests = {
-                k: retrace.watch(sk.make_ingest_resident_lanes_fn(
+                k: sk.make_ingest_resident_lanes_fn(
                     bpl, caps, k * lanes, use_pallas=self._cfg.use_pallas,
                     enable_fanout=self._cfg.enable_fanout,
-                    enable_asym=self._cfg.enable_asym),
-                    f"ingest_resident_lanes_x{k}", tiered=self._tier_form)
+                    enable_asym=self._cfg.enable_asym,
+                    name=f"ingest_resident_lanes_x{k}",
+                    tiered=self._tier_form)
                 for k in ladder}
             return staging.ShardedResidentStagingRing(
                 self._batch_size, 1, ingests,
@@ -1636,21 +1670,17 @@ class TpuSketchExporter(Exporter):
             spill_cap = staging.default_spill_cap(self._batch_size)
             return staging.DenseStagingRing(
                 self._batch_size,
-                retrace.watch(
-                    sk.make_ingest_compact_fn(self._batch_size, spill_cap,
-                                              **kw), "ingest_compact",
-                    tiered=self._tier_form),
+                sk.make_ingest_compact_fn(self._batch_size, spill_cap,
+                                          name="ingest_compact", **kw),
                 spill_cap=spill_cap,
-                ingest_fallback=retrace.watch(
-                    sk.make_ingest_dense_fn(**kw), "ingest_dense",
-                    tiered=self._tier_form),
+                ingest_fallback=sk.make_ingest_dense_fn(
+                    name="ingest_dense", **kw),
                 metrics=metrics, pack_threads=pack_threads)
         if feed != "dense":
             log.warning("unknown SKETCH_FEED %r; using dense", feed)
         return staging.DenseStagingRing(
             self._batch_size,
-            retrace.watch(sk.make_ingest_dense_fn(**kw), "ingest_dense",
-                          tiered=self._tier_form),
+            sk.make_ingest_dense_fn(name="ingest_dense", **kw),
             metrics=metrics, pack_threads=pack_threads)
 
     def _fold(self, records: list[Record]) -> None:
@@ -1980,12 +2010,8 @@ class TpuSketchExporter(Exporter):
         if self._cfg.tiered is None:
             return state
         if self._tiered_decode is None:
-            import jax
-
-            from netobserv_tpu.sketch.tiered import decode_state
-            self._tiered_decode = retrace.watch(jax.jit(decode_state),
-                                                "tiered_decode",
-                                                tiered="decode")
+            self._tiered_decode = retrace.jit(_tiered_decode, "tiered_decode",
+                                              tiered="decode")
         return self._tiered_decode(state)
 
     def _publish_tier_metrics(self, tables, tenant=None) -> None:

@@ -47,6 +47,16 @@ def agent_owner_shard(agent_id: str, n_shards: int) -> int:
     return zlib.crc32(agent_id.encode()) % max(1, n_shards)
 
 
+def _federation_merge(state, tables):
+    """The single-device fold under its own name: ONE function object for
+    every aggregator of the process, so jax's trace cache serves the second
+    one (a per-instance lambda would re-trace inside its first frame's
+    deadline)."""
+    from netobserv_tpu.federation import statemerge
+
+    return statemerge.merge_tables(state, tables)
+
+
 class FederationAggregator:
     """Delta ingest + on-device merge + windowed cluster reports.
 
@@ -109,15 +119,12 @@ class FederationAggregator:
             self._roll = pmerge.make_merge_fn(self._mesh, self._cfg,
                                               with_tables=True)
         else:
-            from netobserv_tpu.federation import statemerge
             self._ndata = 1
             self._state = sk.init_state(self._cfg)
-            self._fold = retrace.watch(
-                jax.jit(statemerge.merge_tables, donate_argnums=(0,)),
-                "federation_merge")
-            self._roll = retrace.watch(
-                sk.make_roll_fn(self._cfg, with_tables=True),
-                "federation_roll")
+            self._fold = retrace.jit(_federation_merge, "federation_merge",
+                                     donate_argnums=(0,))
+            self._roll = sk.make_roll_fn(self._cfg, with_tables=True,
+                                         name="federation_roll")
 
         self._lock = threading.Lock()          # aggregate state + counters
         self._publish_lock = threading.Lock()

@@ -8,6 +8,7 @@ runs at a time.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import queue
 import threading
@@ -23,6 +24,10 @@ from netobserv_tpu.model.record import (
 )
 
 log = logging.getLogger("netobserv_tpu.flow.map_tracer")
+
+#: process-wide eviction sequence number (`eviction=<n>` on the drain's
+#: stages, `EvictedFlows.eviction`): itertools.count is atomic under the GIL
+_EVICTION_SEQ = itertools.count(1)
 
 
 class MapTracer:
@@ -171,20 +176,23 @@ class MapTracer:
     def _evict_locked(self) -> None:
         # flight recorder: a batch trace is born here and rides the evicted
         # batch to the exporter fold (columnar path); un-sampled evictions
-        # get the shared NULL trace — no timestamps, no locks
+        # get the shared NULL trace — no timestamps, no locks. Sampled or
+        # not, the drain's stages carry `eviction=<n>` in a profiler
+        # capture: the number is stamped on the EvictedFlows below and the
+        # exporter's fold chunks name the range of evictions they carry.
         trace = tracing.start_trace("batch")
+        seq = next(_EVICTION_SEQ)
+        drain = trace.bind(eviction=seq)
         t0 = time.perf_counter()
-        with trace.stage("evict"):
-            # bind the sampled trace for the drain's child spans
-            # (decode/merge_percpu/align in the columnar eviction plane);
-            # unsampled drains pay one bool check
-            if trace.sampled:
-                tracing.set_active(trace)
+        with drain.stage("evict"):
+            # bind the drain's handle for its child spans (decode/
+            # merge_percpu/align in the columnar eviction plane): one
+            # thread-local write per drain
+            tracing.set_active(drain)
             try:
                 evicted = self._fetcher.lookup_and_delete()
             finally:
-                if trace.sampled:
-                    tracing.clear_active()
+                tracing.clear_active()
             # purge orphaned auxiliary entries (e.g. DNS never answered)
             purge = getattr(self._fetcher, "purge_stale", None)
             if purge is not None:
@@ -235,6 +243,7 @@ class MapTracer:
         if len(evicted) == 0:
             return  # idle eviction: drop the trace unrecorded (no flows)
         if self._columnar:
+            evicted.eviction = seq
             if trace.sampled:
                 evicted.trace = trace  # the exporter fold finishes it
             try:
@@ -246,7 +255,7 @@ class MapTracer:
                             "(%d flows)", len(evicted))
                 trace.finish()  # never reaches the fold — seal what we have
             return
-        with trace.stage("enrich"):
+        with drain.stage("enrich"):
             namer = self._namer or interface_namer()
             records = records_from_events(
                 evicted.events, clock=self._clock, agent_ip=self._agent_ip,

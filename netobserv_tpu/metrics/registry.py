@@ -220,6 +220,16 @@ class Metrics:
             "controller's backpressure signal)",
             buckets=(.0001, .0005, .001, .005, .01, .05, .1, .5, 1, 5),
             registry=self.registry)
+        self.sketch_pack_seconds = Histogram(
+            p + "sketch_pack_seconds",
+            "Host pack of one fold chunk: wall seconds the folding thread "
+            "spent in the chunk's pack stage (resident_pack / pack — all "
+            "pack lanes together, as the thread waits for them). The "
+            "always-on twin of sketch_slot_wait_seconds: pack seconds per "
+            "record that grow while slot wait stays flat mean the host "
+            "packer, not the device, paces the fold",
+            buckets=(.0001, .0005, .001, .002, .005, .01, .02, .05, .1, .5),
+            registry=self.registry)
         self.sketch_heavy_evictions_total = Counter(
             p + "sketch_heavy_evictions_total",
             "Valid heavy-hitter slot-table occupants evicted by heavier "

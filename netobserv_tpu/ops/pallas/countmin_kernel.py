@@ -124,6 +124,7 @@ def update_two(cm_a: CountMin, cm_b: CountMin, h1: jax.Array, h2: jax.Array,
         out_specs=pl.BlockSpec((2, d, TILE_W), lambda j: (0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((2, d, w), jnp.float32),
         input_output_aliases={0: 0},
+        name="countmin_update_two",
         interpret=interpret,
     )(stacked, idx, vals)
     return (CountMin(counts=new_counts[0].astype(cm_a.counts.dtype)),
@@ -274,6 +275,7 @@ def update_two_tiered(plane_a, plane_b, h1: jax.Array, h2: jax.Array,
             jax.ShapeDtypeStruct((d, idx.shape[1]), jnp.float32),
         ),
         input_output_aliases={0: 0, 1: 1, 2: 2},
+        name="countmin_update_two_tiered",
         interpret=interpret,
     )(base_s, mid_s, top_s, idx, vals)
     est = jnp.min(q[:, :b], axis=0)
@@ -314,6 +316,7 @@ def update(cm: CountMin, h1: jax.Array, h2: jax.Array, values: jax.Array,
         out_specs=pl.BlockSpec((d, TILE_W), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((d, w), jnp.float32),
         input_output_aliases={0: 0},
+        name="countmin_update",
         interpret=interpret,
     )(cm.counts.astype(jnp.float32), idx, vals.reshape(1, -1))
     return CountMin(counts=new_counts)

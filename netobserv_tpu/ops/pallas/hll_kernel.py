@@ -65,6 +65,7 @@ def _fold_flat(regs_flat: jax.Array, idx: jax.Array, rank: jax.Array,
         out_specs=pl.BlockSpec((1, TILE_M), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
         input_output_aliases={0: 0},
+        name="hll_update",
         interpret=interpret,
     )(regs_flat.reshape(1, m), idx.reshape(1, -1), rank.reshape(1, -1))
     return new_regs.reshape(m)

@@ -197,6 +197,7 @@ def update(planes: SignalPlanes, idx: jax.Array, vals: jax.Array,
         out_shape=(jax.ShapeDtypeStruct((N_MAIN, m), jnp.float32),
                    jax.ShapeDtypeStruct((2, AUX_W), jnp.float32)),
         input_output_aliases={0: 0, 1: 1},
+        name="signal_update",
         interpret=interpret,
     )(main, aux, idx.astype(jnp.int32), vals.astype(jnp.float32))
     return SignalPlanes(
@@ -281,6 +282,7 @@ def update_tiered(planes: SignalPlanes, packed: jax.Array, idx: jax.Array,
                    jax.ShapeDtypeStruct((2, AUX_W), jnp.float32),
                    jax.ShapeDtypeStruct((3, n3), jnp.uint8)),
         input_output_aliases={0: 0, 1: 1, 2: 2},
+        name="signal_update_tiered",
         interpret=interpret,
     )(main, aux, pk3, idx.astype(jnp.int32), vals.astype(jnp.float32),
       hll_idx.astype(jnp.int32), hll_rank.astype(jnp.int32))

@@ -106,6 +106,7 @@ def reduce(mslot: jax.Array, target: jax.Array, est: jax.Array, k: int,
         out_shape=(jax.ShapeDtypeStruct((1, k), jnp.float32),
                    jax.ShapeDtypeStruct((1, k), jnp.float32),
                    jax.ShapeDtypeStruct((1, k), jnp.int32)),
+        name="topk_reduce",
         interpret=interpret,
     )(mslot.astype(jnp.int32).reshape(1, -1),
       target.astype(jnp.int32).reshape(1, -1),

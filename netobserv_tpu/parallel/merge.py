@@ -181,11 +181,12 @@ def make_sharded_ingest_fn(mesh: Mesh, cfg: sk.SketchConfig,
         out_specs=(specs, P(DATA_AXIS)) if with_token else specs,
         check_vma=False,
     )
-    # retrace watchdog (utils/retrace.py): the wrapper delegates .lower /
-    # ._cache_size, so the HLO no-collectives checks still introspect it
-    return retrace.watch(
-        jax.jit(shmapped, donate_argnums=(0,) if donate else ()),
-        "sharded_ingest_dense" if dense else "sharded_ingest")
+    # named, jitted and watched in one call (utils/retrace.jit): the
+    # wrapper delegates .lower / ._cache_size, so the HLO no-collectives
+    # checks still introspect it
+    return retrace.jit(
+        shmapped, "sharded_ingest_dense" if dense else "sharded_ingest",
+        donate_argnums=(0,) if donate else ())
 
 
 def init_resident_tables(mesh: Mesh, slot_cap: int,
@@ -245,9 +246,8 @@ def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
         out_specs=(specs, P(DATA_AXIS), P(DATA_AXIS)),
         check_vma=False,
     )
-    return retrace.watch(
-        jax.jit(shmapped, donate_argnums=(0, 1) if donate else ()),
-        watch_name)
+    return retrace.jit(shmapped, watch_name,
+                       donate_argnums=(0, 1) if donate else ())
 
 
 def shard_dense(mesh: Mesh, dense: np.ndarray) -> jax.Array:
@@ -301,9 +301,13 @@ def shard_dense_per_device(mesh: Mesh, flat: np.ndarray) -> jax.Array:
 
 def merge_states(s: sk.SketchState, nsk: int) -> sk.SketchState:
     """Merge per-device partials into a replicated view (call inside shard_map;
-    arrays here are local slices without the data-axis dim)."""
-    cm_b = countmin.CountMin(jax.lax.psum(s.cm_bytes.counts, DATA_AXIS))
-    cm_p = countmin.CountMin(jax.lax.psum(s.cm_pkts.counts, DATA_AXIS))
+    arrays here are local slices without the data-axis dim). Two named
+    scopes tell the roll's collectives apart in a device capture:
+    `merge_allreduce` (psum / pmax of every additive and max-merged plane)
+    and `merge_topk_gather` (the slot tables' all-gather and re-score)."""
+    with jax.named_scope("merge_allreduce"):
+        cm_b = countmin.CountMin(jax.lax.psum(s.cm_bytes.counts, DATA_AXIS))
+        cm_p = countmin.CountMin(jax.lax.psum(s.cm_pkts.counts, DATA_AXIS))
 
     def gather(x):
         # owner-sharded tables hold DISJOINT key sets per sketch shard, so
@@ -313,50 +317,57 @@ def merge_states(s: sk.SketchState, nsk: int) -> sk.SketchState:
             x = jax.lax.all_gather(x, SKETCH_AXIS, axis=0, tiled=True)
         return x
 
-    stacked = jax.tree.map(gather, s.heavy)
-    if nsk > 1:
-        qfn = lambda a, b: countmin.query_sharded(  # noqa: E731
-            cm_b, a, b, SKETCH_AXIS, nsk)
-    else:
-        qfn = None
-    # roll-time reconciliation of the persistent slot tables: duplicate
-    # identities across shards collapse with segmented metadata merges
-    # (prev_counts sum, first_seen min, epoch max) and counts re-score
-    # against the globally merged CM — the one place cross-shard top-K
-    # work happens (steady state stays collective-free)
-    heavy = topk.merge_slot_tables(stacked, cm_b, s.heavy.k, query_fn=qfn)
-    return sk.SketchState(
-        cm_bytes=cm_b, cm_pkts=cm_p, heavy=heavy,
-        hll_src=hll.HLL(jax.lax.pmax(s.hll_src.regs, DATA_AXIS)),
-        hll_per_dst=hll.PerDstHLL(jax.lax.pmax(s.hll_per_dst.regs, DATA_AXIS)),
-        hll_per_src=hll.PerDstHLL(jax.lax.pmax(s.hll_per_src.regs, DATA_AXIS)),
-        hist_rtt=quantile.LogHist(jax.lax.psum(s.hist_rtt.counts, DATA_AXIS)),
-        hist_dns=quantile.LogHist(jax.lax.psum(s.hist_dns.counts, DATA_AXIS)),
-        ddos=ewma.EWMA(mean=s.ddos.mean, var=s.ddos.var,
-                       rate=jax.lax.psum(s.ddos.rate, DATA_AXIS),
-                       windows=s.ddos.windows),
-        # the EWMA baselines (mean/var) are replicated and rolled identically
-        # on every device; only the window rates are true partials
-        syn=ewma.EWMA(mean=s.syn.mean, var=s.syn.var,
-                      rate=jax.lax.psum(s.syn.rate, DATA_AXIS),
-                      windows=s.syn.windows),
-        synack=jax.lax.psum(s.synack, DATA_AXIS),
-        drops_ewma=ewma.EWMA(mean=s.drops_ewma.mean, var=s.drops_ewma.var,
-                             rate=jax.lax.psum(s.drops_ewma.rate, DATA_AXIS),
-                             windows=s.drops_ewma.windows),
-        drop_causes=jax.lax.psum(s.drop_causes, DATA_AXIS),
-        dscp_bytes=jax.lax.psum(s.dscp_bytes, DATA_AXIS),
-        conv_fwd=jax.lax.psum(s.conv_fwd, DATA_AXIS),
-        conv_rev=jax.lax.psum(s.conv_rev, DATA_AXIS),
-        total_records=jax.lax.psum(s.total_records, DATA_AXIS),
-        total_bytes=jax.lax.psum(s.total_bytes, DATA_AXIS),
-        total_drop_bytes=jax.lax.psum(s.total_drop_bytes, DATA_AXIS),
-        total_drop_packets=jax.lax.psum(s.total_drop_packets, DATA_AXIS),
-        quic_records=jax.lax.psum(s.quic_records, DATA_AXIS),
-        nat_records=jax.lax.psum(s.nat_records, DATA_AXIS),
-        heavy_evictions=jax.lax.psum(s.heavy_evictions, DATA_AXIS),
-        window=s.window,
-    )
+    with jax.named_scope("merge_topk_gather"):
+        stacked = jax.tree.map(gather, s.heavy)
+        if nsk > 1:
+            qfn = lambda a, b: countmin.query_sharded(  # noqa: E731
+                cm_b, a, b, SKETCH_AXIS, nsk)
+        else:
+            qfn = None
+        # roll-time reconciliation of the persistent slot tables: duplicate
+        # identities across shards collapse with segmented metadata merges
+        # (prev_counts sum, first_seen min, epoch max) and counts re-score
+        # against the globally merged CM — the one place cross-shard top-K
+        # work happens (steady state stays collective-free)
+        heavy = topk.merge_slot_tables(stacked, cm_b, s.heavy.k,
+                                       query_fn=qfn)
+    with jax.named_scope("merge_allreduce"):
+        return sk.SketchState(
+            cm_bytes=cm_b, cm_pkts=cm_p, heavy=heavy,
+            hll_src=hll.HLL(jax.lax.pmax(s.hll_src.regs, DATA_AXIS)),
+            hll_per_dst=hll.PerDstHLL(
+                jax.lax.pmax(s.hll_per_dst.regs, DATA_AXIS)),
+            hll_per_src=hll.PerDstHLL(
+                jax.lax.pmax(s.hll_per_src.regs, DATA_AXIS)),
+            hist_rtt=quantile.LogHist(
+                jax.lax.psum(s.hist_rtt.counts, DATA_AXIS)),
+            hist_dns=quantile.LogHist(
+                jax.lax.psum(s.hist_dns.counts, DATA_AXIS)),
+            ddos=ewma.EWMA(mean=s.ddos.mean, var=s.ddos.var,
+                           rate=jax.lax.psum(s.ddos.rate, DATA_AXIS),
+                           windows=s.ddos.windows),
+            # the EWMA baselines (mean/var) are replicated and rolled identically
+            # on every device; only the window rates are true partials
+            syn=ewma.EWMA(mean=s.syn.mean, var=s.syn.var,
+                          rate=jax.lax.psum(s.syn.rate, DATA_AXIS),
+                          windows=s.syn.windows),
+            synack=jax.lax.psum(s.synack, DATA_AXIS),
+            drops_ewma=ewma.EWMA(mean=s.drops_ewma.mean, var=s.drops_ewma.var,
+                                 rate=jax.lax.psum(s.drops_ewma.rate, DATA_AXIS),
+                                 windows=s.drops_ewma.windows),
+            drop_causes=jax.lax.psum(s.drop_causes, DATA_AXIS),
+            dscp_bytes=jax.lax.psum(s.dscp_bytes, DATA_AXIS),
+            conv_fwd=jax.lax.psum(s.conv_fwd, DATA_AXIS),
+            conv_rev=jax.lax.psum(s.conv_rev, DATA_AXIS),
+            total_records=jax.lax.psum(s.total_records, DATA_AXIS),
+            total_bytes=jax.lax.psum(s.total_bytes, DATA_AXIS),
+            total_drop_bytes=jax.lax.psum(s.total_drop_bytes, DATA_AXIS),
+            total_drop_packets=jax.lax.psum(s.total_drop_packets, DATA_AXIS),
+            quic_records=jax.lax.psum(s.quic_records, DATA_AXIS),
+            nat_records=jax.lax.psum(s.nat_records, DATA_AXIS),
+            heavy_evictions=jax.lax.psum(s.heavy_evictions, DATA_AXIS),
+            window=s.window,
+        )
 
 
 def make_fold_delta_fn(mesh: Mesh, cfg: sk.SketchConfig,
@@ -402,9 +413,8 @@ def make_fold_delta_fn(mesh: Mesh, cfg: sk.SketchConfig,
         in_specs=(specs, P(), P()),
         out_specs=specs, check_vma=False,
     )
-    return retrace.watch(
-        jax.jit(shmapped, donate_argnums=(0,) if donate else ()),
-        "federation_fold_delta")
+    return retrace.jit(shmapped, "federation_fold_delta",
+                       donate_argnums=(0,) if donate else ())
 
 
 def make_merge_fn(mesh: Mesh, cfg: sk.SketchConfig,
@@ -527,5 +537,4 @@ def make_merge_fn(mesh: Mesh, cfg: sk.SketchConfig,
         local_roll, mesh=mesh, in_specs=(specs,),
         out_specs=out_specs, check_vma=False,
     )
-    return retrace.watch(jax.jit(shmapped, donate_argnums=(0,)),
-                         "sharded_merge")
+    return retrace.jit(shmapped, "sharded_merge", donate_argnums=(0,))

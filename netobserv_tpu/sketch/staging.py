@@ -19,6 +19,7 @@ depth has not been measured on this machine's link).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Callable, Optional
@@ -262,6 +263,31 @@ class _SlotRing:
         self._waits = np.zeros(self.WAIT_WINDOW, np.float64)
         self._wait_i = 0
         self._wait_n = 0
+        #: fold chunks begun by this ring (`chunk=<n>` on their stages)
+        self.chunks = 0
+
+    def _chunk(self, trace, k: int = 1, cont: bool = False):
+        """The stage handle of the fold chunk about to begin: `trace` with
+        `chunk=<n>` (this ring's sequence number), `k=<ladder entry>` and
+        `cont=<0|1>` (a continuation of the rows the chunk before could not
+        take) bound beside whatever the caller bound (`evictions=<a>-<b>`)
+        — the ids its staging_wait / pack / put / ingest_dispatch stages
+        carry in a profiler capture."""
+        self.chunks += 1
+        return trace.bind(chunk=self.chunks, k=k, cont=int(cont))
+
+    @contextlib.contextmanager
+    def _pack_stage(self, chunk, stage: str = "resident_pack"):
+        """One chunk's pack stage, timed into `sketch_pack_seconds` — the
+        wall the folding thread waits for the pack, all lanes together: the
+        always-on twin of the slot wait, for the operator with no
+        profiler."""
+        t0 = time.perf_counter()
+        with chunk.stage(stage):
+            yield
+        if self._metrics is not None:
+            self._metrics.sketch_pack_seconds.observe(
+                time.perf_counter() - t0)
 
     def _record_wait(self, seconds: float) -> None:
         self._waits[self._wait_i] = seconds
@@ -397,34 +423,38 @@ class DenseStagingRing(_SlotRing):
         the new sketch state (async — not blocked on)."""
         trace, owned = self._fold_trace(trace)
         try:
+            chunk = self._chunk(trace)
             try:
-                slot = self._wait_slot(trace)
+                slot = self._wait_slot(chunk)
             except StagingWedged as exc:
                 exc.state = state  # nothing dispatched: caller's own state
                 raise
             feats = dict(extra=extra, dns=dns, drops=drops, xlat=xlat,
                          quic=quic)
             if self.spill_cap is not None:
-                with trace.stage("pack"):
+                with self._pack_stage(chunk, "pack"):
                     buf = flowpack.pack_compact(
                         events, batch_size=self.batch_size,
                         spill_cap=self.spill_cap,
                         out=self._bufs[slot], **feats)
                 if buf is None:
                     return self._fold_dense_fallback(state, events, feats)
-                with trace.stage("ingest_dispatch"):
-                    state, token = self._ingest(state, self._put(buf))
-                self._advance(slot, token)
-                return state
-            with trace.stage("pack"):
-                buf = flowpack.pack_dense_sharded(
-                    events, batch_size=self.batch_size,
-                    threads=self.pack_threads, out=self._bufs[slot], **feats)
-            # ship FLAT: a (B*20,) transfer dodges device-layout padding of
-            # the 20-wide minor dim (the ingest jit reshapes back, fused,
-            # free)
-            with trace.stage("ingest_dispatch"):
-                state, token = self._ingest(state, self._put(buf.reshape(-1)))
+            else:
+                with self._pack_stage(chunk, "pack"):
+                    buf = flowpack.pack_dense_sharded(
+                        events, batch_size=self.batch_size,
+                        threads=self.pack_threads, out=self._bufs[slot],
+                        **feats)
+                # ship FLAT: a (B*20,) transfer dodges device-layout padding
+                # of the 20-wide minor dim (the ingest jit reshapes back,
+                # fused, free)
+                buf = buf.reshape(-1)
+            # host-to-device transfer and jit enqueue are different costs:
+            # one stage each
+            with chunk.stage("put"):
+                dev = self._put(buf)
+            with chunk.stage("ingest_dispatch"):
+                state, token = self._ingest(state, dev)
             self._advance(slot, token)
             return state
         finally:
@@ -611,8 +641,9 @@ class ShardedResidentStagingRing(_SlotRing):
         starts = [0] * nr
         first = True
         while any(starts[i] < len(shard_ev[i]) for i in range(nr)):
+            chunk = self._chunk(trace, k, not first)
             try:
-                slot = self._wait_slot(trace)
+                slot = self._wait_slot(chunk)
             except StagingWedged as exc:
                 # earlier chunks may have dispatched (donating the caller's
                 # state buffers); hand the last valid state to the catcher
@@ -651,7 +682,7 @@ class ShardedResidentStagingRing(_SlotRing):
                 starts[i] += consumed
                 return int(region[2]), resets
 
-            with trace.stage("resident_pack"):
+            with self._pack_stage(chunk):
                 if self.pack_threads > 1 and nr > 1:
                     # per-region dictionaries are independent; the native
                     # pack releases the GIL, so regions pack in true parallel
@@ -679,9 +710,13 @@ class ShardedResidentStagingRing(_SlotRing):
             if not first:
                 self.continuations += 1
             first = False
-            with trace.stage("ingest_dispatch"):
+            # host-to-device transfer and jit enqueue are different costs:
+            # one stage each
+            with chunk.stage("put"):
+                dev = self._put(buf[:ship_words])
+            with chunk.stage("ingest_dispatch"):
                 state, self.key_tables, token = self._ingests[k](
-                    state, self.key_tables, self._put(buf[:ship_words]))
+                    state, self.key_tables, dev)
             self._advance(slot, token)
         return state
 
@@ -702,8 +737,9 @@ class ShardedResidentStagingRing(_SlotRing):
                 nr = self.n_shards * ch.k * self.lanes
                 seg_words = nr * rw
                 for s in range(ch.n_segs):
+                    chunk = self._chunk(trace, ch.k, bool(s))
                     try:
-                        slot = self._wait_slot(trace)
+                        slot = self._wait_slot(chunk)
                     except StagingWedged as exc:
                         # chunks already dispatched donated the caller's
                         # state buffers (the _fold_chunk rule) — hand the
@@ -713,7 +749,7 @@ class ShardedResidentStagingRing(_SlotRing):
                         raise
                     buf = self._bufs[slot]
                     off = ch.arena_off + s * seg_words
-                    with trace.stage("resident_pack"):
+                    with self._pack_stage(chunk):
                         np.copyto(buf[:seg_words],
                                   packed.arena[off:off + seg_words])
                     self.superbatch_folds[ch.k] = (
@@ -726,10 +762,11 @@ class ShardedResidentStagingRing(_SlotRing):
                              .sketch_resident_continuations_total.inc())
                         self._metrics.sketch_superbatch_folds_total.labels(
                             str(ch.k)).inc()
-                    with trace.stage("ingest_dispatch"):
+                    with chunk.stage("put"):
+                        dev = self._put(buf[:seg_words])
+                    with chunk.stage("ingest_dispatch"):
                         state, self.key_tables, token = self._ingests[ch.k](
-                            state, self.key_tables,
-                            self._put(buf[:seg_words]))
+                            state, self.key_tables, dev)
                     self._advance(slot, token)
                 # per-chunk counters the native pack already aggregated
                 self.spill_rows += ch.spills
@@ -888,14 +925,15 @@ class ResidentStagingRing(_SlotRing):
                     self.dict_resets += 1
                     if self._metrics is not None:
                         self._metrics.sketch_resident_dict_epochs_total.inc()
+                chunk = self._chunk(trace, 1, not first)
                 try:
-                    slot = self._wait_slot(trace)
+                    slot = self._wait_slot(chunk)
                 except StagingWedged as exc:
                     # earlier chunks may have dispatched (donating the
                     # caller's state buffers); hand over the valid state
                     exc.state = state
                     raise
-                with trace.stage("resident_pack"):
+                with self._pack_stage(chunk):
                     buf, consumed = flowpack.pack_resident(
                         events, batch_size=self.batch_size, kdict=self.kdict,
                         caps=self.caps, start=start, out=self._bufs[slot],
@@ -914,9 +952,11 @@ class ResidentStagingRing(_SlotRing):
                     self.continuations += 1
                 first = False
                 start += consumed
-                with trace.stage("ingest_dispatch"):
+                with chunk.stage("put"):
+                    dev = self._put(buf)
+                with chunk.stage("ingest_dispatch"):
                     state, self.key_table, token = self._ingest(
-                        state, self.key_table, self._put(buf))
+                        state, self.key_table, dev)
                 self._advance(slot, token)
             return state
         finally:

@@ -34,6 +34,7 @@ from netobserv_tpu.model.columnar import KEY_WORDS, FlowBatch
 from netobserv_tpu.model.flow import TcpFlags
 from netobserv_tpu.ops import countmin, ewma, hashing, hll, quantile, topk
 from netobserv_tpu.sketch import tiered
+from netobserv_tpu.utils import retrace
 
 
 class SketchConfig(NamedTuple):
@@ -405,35 +406,49 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
     valid = arrays["valid"]
     bytes_f = arrays["bytes"]
     pkts = arrays["packets"]
-    samp = arrays.get("sampling")
-    if samp is not None:
-        # de-bias sampled traffic: a 1-in-N sampled flow record stands for N
-        # flows' worth of volume (reference scales at the collector via the
-        # exported Sampling field; sketches must fold the scaled estimate or
-        # heavy-hitter/volume numbers undercount). 0 = unsampled. The
-        # overload controller (sketch/overload.py) leans on exactly this
-        # lane: host-side shedding multiplies its 1-in-N factor into each
-        # surviving row's sampling, so kernel sampling and overload shed
-        # compose multiplicatively and both de-bias HERE — any change to
-        # this factor changes the shed-unbiasedness contract pinned by
-        # tests/test_overload.py.
-        factor = jnp.maximum(samp, 1)
-        bytes_f = bytes_f * factor.astype(jnp.float32)
-        pkts = pkts * factor
+    with jax.named_scope("hash"):
+        samp = arrays.get("sampling")
+        if samp is not None:
+            # de-bias sampled traffic: a 1-in-N sampled flow record stands for N
+            # flows' worth of volume (reference scales at the collector via the
+            # exported Sampling field; sketches must fold the scaled estimate or
+            # heavy-hitter/volume numbers undercount). 0 = unsampled. The
+            # overload controller (sketch/overload.py) leans on exactly this
+            # lane: host-side shedding multiplies its 1-in-N factor into each
+            # surviving row's sampling, so kernel sampling and overload shed
+            # compose multiplicatively and both de-bias HERE — any change to
+            # this factor changes the shed-unbiasedness contract pinned by
+            # tests/test_overload.py.
+            factor = jnp.maximum(samp, 1)
+            bytes_f = bytes_f * factor.astype(jnp.float32)
+            pkts = pkts * factor
 
-    # ONE sweep computes every hash family (flow h1/h2, src bucket, dst
-    # bucket, dst-port fan-out, src-sym): the murmur k-mix per key word is
-    # shared across families instead of five independent base_hashes passes
-    mhash = hashing.base_hashes_multi(words)
-    h1, h2 = mhash.h1, mhash.h2
-    src_h1, src_h2 = mhash.src_h1, mhash.src_h2
-    dst_h1 = mhash.dst_h1
+        # ONE sweep computes every hash family (flow h1/h2, src bucket, dst
+        # bucket, dst-port fan-out, src-sym): the murmur k-mix per key word is
+        # shared across families instead of five independent base_hashes passes
+        mhash = hashing.base_hashes_multi(words)
+        h1, h2 = mhash.h1, mhash.h2
+        src_h1, src_h2 = mhash.src_h1, mhash.src_h2
+        dst_h1 = mhash.dst_h1
 
-    if sketch_axis is None:
-        # tier-interior first: the CM fields here are zero-size
-        # placeholders (whose width trivially tiles) — the walk reads and
-        # promotes the resident tier arrays directly
-        if _tier is not None:
+    # each branch folds the two Count-Min planes and says how the slot
+    # top-K scores against them (`topk_kw`); the walk itself is one call
+    with jax.named_scope("countmin"):
+        if sketch_axis is not None:
+            cm_b = countmin.update_sharded(state.cm_bytes, h1, h2, bytes_f,
+                                           valid, sketch_axis, sketch_shards)
+            cm_p = countmin.update_sharded(state.cm_pkts, h1, h2, pkts,
+                                           valid, sketch_axis, sketch_shards)
+            # collective-free scoring: this shard fully owns its keys'
+            # counters, so its table tracks exactly the keys it owns (the
+            # merge gathers tables across the sketch axis and re-scores
+            # globally)
+            topk_kw = dict(query_fn=lambda a, b: countmin.query_sharded_local(
+                cm_b, a, b, sketch_axis, sketch_shards))
+        elif _tier is not None:
+            # tier-interior: the CM fields here are zero-size placeholders
+            # (whose width trivially tiles) — the walk reads and promotes
+            # the resident tier arrays directly
             from netobserv_tpu.ops.pallas import countmin_kernel
             t = _tier.state.tables
             new_cmb, new_cmp, est = countmin_kernel.update_two_tiered(
@@ -445,11 +460,8 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             # the kernel already gathered the post-fold bytes estimate
             # from its transient wide view — exactly countmin.query of the
             # decode-wrapped form's cm_b
-            heavy, evicted = topk.slot_update(
-                state.heavy, cm_b, words, h1, h2, valid,
-                query_fn=lambda a, b: est,
-                window=state.window,
-                use_pallas=state.heavy.k % 128 == 0)
+            topk_kw = dict(query_fn=lambda a, b: est,
+                           use_pallas=state.heavy.k % 128 == 0)
         else:
             # the Pallas kernel needs the width to tile; silently use the
             # XLA scatter otherwise (static check, resolved at trace time)
@@ -467,241 +479,245 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             # Pallas reduction twin engages with the other kernels
             # (lane-aligned K); the scatter form everywhere else —
             # bit-exact either way (tests/test_pallas_topk.py pins it)
-            heavy, evicted = topk.slot_update(
-                state.heavy, cm_b, words, h1, h2, valid,
-                window=state.window,
+            topk_kw = dict(
                 use_pallas=use_pallas and state.heavy.k % 128 == 0)
-    else:
-        cm_b = countmin.update_sharded(state.cm_bytes, h1, h2, bytes_f, valid,
-                                       sketch_axis, sketch_shards)
-        cm_p = countmin.update_sharded(state.cm_pkts, h1, h2, pkts, valid,
-                                       sketch_axis, sketch_shards)
-        # collective-free scoring: this shard fully owns its keys' counters,
-        # so its table tracks exactly the keys it owns (the merge gathers
-        # tables across the sketch axis and re-scores globally)
+    with jax.named_scope("topk"):
         heavy, evicted = topk.slot_update(
-            state.heavy, cm_b, words, h1, h2, valid,
-            query_fn=lambda a, b: countmin.query_sharded_local(
-                cm_b, a, b, sketch_axis, sketch_shards),
-            window=state.window)
-    if _tier is not None and _tier.fuse_hll:
-        # the global-src bank stays 6-bit packed; the fused signal walk
-        # below folds it and stashes the new packed bank in the hook
-        hll_src = state.hll_src  # zero-size placeholder
-    elif (use_pallas and sketch_axis is None
-            and state.hll_src.regs.shape[0] % 512 == 0):
-        from netobserv_tpu.ops.pallas import hll_kernel
-        hll_src = hll_kernel.update(state.hll_src, src_h1, src_h2, valid)
-    else:
-        hll_src = hll.update(state.hll_src, src_h1, src_h2, valid)
-    per_dst = hll.update_per_dst(state.hll_per_dst, dst_h1, src_h1, src_h2, valid)
-    flags = arrays.get("tcp_flags")
-    if enable_fanout:
-        # port-scan signal: distinct (dst addr, dst port) fan-out per SOURCE
-        # bucket — a scanner touches many; a normal client few. The (dst,
-        # port) hashes come from the shared multi-hash sweep above (seed:
-        # hashing.DSTPORT_FANOUT_SEED). Only INITIATOR-side flows count:
-        # a flow that sent SYN+ACK together (the TcpFlags.SYN_ACK
-        # composite) is a RESPONDER — without the gate a server answering
-        # one NAT'd client churning through hundreds of source ports
-        # sweeps hundreds of distinct (addr, port) pairs and lights the
-        # grid (the nat_churn scenario). Initiators count whether the
-        # handshake completed or not (SYN with or without a later ACK),
-        # so both lone-SYN and full-connect scans fire; flows with no
-        # SYN-side evidence at all (non-TCP rows, mid-capture sessions:
-        # flags without SYN) keep the pre-gate behavior only when they
-        # are not responders.
-        fanout_valid = valid
-        if flags is not None:
-            f32 = flags.astype(jnp.int32)
-            fanout_valid = valid & ((f32 & TcpFlags.SYN_ACK) == 0)
-        per_src = hll.update_per_dst(state.hll_per_src, src_h1, mhash.dp_h1,
-                                     mhash.dp_h2, fanout_valid)
-    else:
-        per_src = state.hll_per_src
-    rtt = arrays["rtt_us"]
-    dns = arrays["dns_latency_us"]
-    gamma = quantile.gamma_for(state.hist_rtt.n_buckets)
-    hist_rtt = quantile.update(state.hist_rtt, rtt, valid & (rtt > 0), gamma)
-    hist_dns = quantile.update(state.hist_dns, dns, valid & (dns > 0), gamma)
-    # --- signal planes (trace-time optional feature columns: a feed
-    # without a column — e.g. the legacy six-array dict — simply skips the
-    # corresponding signal; the fused kernel receives a zero value row
-    # instead, which is bit-identical to skipping) ---
-    # conversation asymmetry hashes BOTH endpoints under one seed so the
-    # pair bucket is direction-invariant (A->B and B->A land together);
-    # the lower endpoint hash defines the canonical "fwd" direction.
-    # src_sym hashes the src words under the dst seed — also exactly the
-    # victim-bucket hash the SYN-ACK side needs.
-    src_sym = mhash.src_sym
-    mass = factor.astype(jnp.float32) if samp is not None else 1.0
-    if flags is not None:  # read above, at the fan-out gate
-        # SYN-flood: half-open attempts (SYN seen, never ACKed — a spoofed
-        # flood leaves one such record per probe) bucket by victim = dst;
-        # SYN-ACK response flows bucket by victim = src (the responder),
-        # using the SAME hash seed so both land in one bucket per victim.
-        # Flag bits ride the dense feed from the datapath's OR-accumulated
-        # tcp_flags (reference exports them per flow, proto/flow.proto:30).
-        f = flags.astype(jnp.int32)
-        half_open = valid & ((f & TcpFlags.SYN) != 0) & \
-            ((f & TcpFlags.ACK) == 0)
-        is_synack = valid & ((f & TcpFlags.SYN_ACK) != 0)
-    dscp = arrays.get("dscp")
-    db = arrays.get("drop_bytes")
-    cause = arrays.get("drop_cause") if db is not None else None
-    tdb, tdp = state.total_drop_bytes, state.total_drop_packets
-    if db is not None:
-        dbf = db.astype(jnp.float32) * mass
-        dpf = arrays["drop_packets"].astype(jnp.float32) * mass
-        tdb = tdb + jnp.sum(jnp.where(valid, dbf, 0.0))
-        tdp = tdp + jnp.sum(jnp.where(valid, dpf, 0.0))
-    if enable_asym:
-        pair_idx = ((src_sym + dst_h1)
-                    & jnp.uint32(state.conv_fwd.shape[0] - 1)).astype(jnp.int32)
-        is_fwd = src_sym < dst_h1
-        # self-pairs (src == dst: hairpin NAT, loopback capture) have no
-        # meaningful direction — both ways would land "fwd" and fire a
-        # false one-way alert every window; exclude them from the signal
-        conv_ok = valid & (src_sym != dst_h1)
-
-    use_signal_kernel = use_pallas and sketch_axis is None
-    if use_signal_kernel:
-        from netobserv_tpu.ops.pallas import signal_kernel
-        planes = signal_kernel.SignalPlanes(
-            ddos_rate=state.ddos.rate, syn_rate=state.syn.rate,
-            drops_rate=state.drops_ewma.rate, synack=state.synack,
-            conv_fwd=state.conv_fwd, conv_rev=state.conv_rev,
-            dscp_bytes=state.dscp_bytes, drop_causes=state.drop_causes)
-        use_signal_kernel = signal_kernel.eligible(planes)
-    if use_signal_kernel:
-        # fused signal-plane fold: all eight scatter targets update in ONE
-        # Pallas batch walk (ops/pallas/signal_kernel.py); absent feature
-        # columns contribute zero-mass rows — bit-identical to skipping
-        m_sig = state.conv_fwd.shape[0]
-        zeros_b = jnp.zeros_like(bytes_f)
-        izeros_b = jnp.zeros(bytes_f.shape, jnp.int32)
-        dst_idx = (dst_h1 & jnp.uint32(m_sig - 1)).astype(jnp.int32)
-        src_idx = (src_sym & jnp.uint32(m_sig - 1)).astype(jnp.int32)
-        v_ddos = jnp.where(valid, bytes_f, 0.0)
-        if flags is not None:
-            v_syn = jnp.where(half_open, mass, 0.0)
-            v_synack = jnp.where(is_synack, mass, 0.0)
-        else:
-            v_syn = v_synack = zeros_b
-        if db is not None:
-            v_drops = jnp.where(valid, dbf, 0.0)
-        else:
-            v_drops = zeros_b
-        if cause is not None:
-            cause_idx = jnp.minimum(cause.astype(jnp.int32),
-                                    N_DROP_CAUSES - 1)
-            v_cause = jnp.where(valid & (dpf > 0), dpf, 0.0)
-        else:
-            cause_idx, v_cause = izeros_b, zeros_b
-        if enable_asym:
-            v_fwd = jnp.where(conv_ok & is_fwd, bytes_f, 0.0)
-            v_rev = jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0)
-        else:
-            pair_idx, v_fwd, v_rev = izeros_b, zeros_b, zeros_b
-        if dscp is not None:
-            dscp_idx = dscp.astype(jnp.int32) & (N_DSCP - 1)
-            v_dscp = jnp.where(valid, bytes_f, 0.0)
-        else:
-            dscp_idx, v_dscp = izeros_b, zeros_b
-        sig_idx = jnp.stack([dst_idx, src_idx, pair_idx, dscp_idx,
-                             cause_idx])
-        sig_vals = jnp.stack([v_ddos, v_syn, v_drops, v_synack, v_fwd,
-                              v_rev, v_dscp, v_cause])
+            state.heavy, cm_b, words, h1, h2, valid, window=state.window,
+            **topk_kw)
+    with jax.named_scope("hll_src"):
         if _tier is not None and _tier.fuse_hll:
-            # tiered megakernel: the same signal fold plus the packed
-            # global-src HLL lane in one walk (idx/rank mirror
-            # hll_kernel.update exactly — max fold, bit-exact)
-            packed = _tier.state.tables.hll_src
-            m_hll = packed.shape[0] // 3 * 4
-            hll_idx = (src_h1 & jnp.uint32(m_hll - 1)).astype(jnp.int32)
-            hll_rank = jnp.where(valid, hll._rank(src_h2), 0)
-            out, new_packed = signal_kernel.update_tiered(
-                planes, packed, sig_idx, sig_vals, hll_idx, hll_rank)
-            _tier.out["hll_src"] = new_packed
+            # the global-src bank stays 6-bit packed; the fused signal walk
+            # below folds it and stashes the new packed bank in the hook
+            hll_src = state.hll_src  # zero-size placeholder
+        elif (use_pallas and sketch_axis is None
+                and state.hll_src.regs.shape[0] % 512 == 0):
+            from netobserv_tpu.ops.pallas import hll_kernel
+            hll_src = hll_kernel.update(state.hll_src, src_h1, src_h2, valid)
         else:
-            out = signal_kernel.update(planes, sig_idx, sig_vals)
-        ddos = state.ddos._replace(rate=out.ddos_rate)
-        syn_state = state.syn._replace(rate=out.syn_rate)
-        drops_state = state.drops_ewma._replace(rate=out.drops_rate)
-        synack_arr = out.synack
-        conv_fwd, conv_rev = out.conv_fwd, out.conv_rev
-        dscp_bytes, drop_causes = out.dscp_bytes, out.drop_causes
-    else:
-        # un-fused scatter chain (CPU / owner-sharded / ineligible shapes)
-        # — the fused kernel above is equivalence-pinned against exactly
-        # this path (tests/test_pallas_signal.py)
-        ddos = ewma.accumulate(state.ddos, dst_h1, bytes_f, valid)
-        if enable_asym:
-            conv_fwd = state.conv_fwd.at[pair_idx].add(
-                jnp.where(conv_ok & is_fwd, bytes_f, 0.0), mode="drop")
-            conv_rev = state.conv_rev.at[pair_idx].add(
-                jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0), mode="drop")
+            hll_src = hll.update(state.hll_src, src_h1, src_h2, valid)
+    with jax.named_scope("hll_grids"):
+        per_dst = hll.update_per_dst(state.hll_per_dst, dst_h1, src_h1,
+                                     src_h2, valid)
+        flags = arrays.get("tcp_flags")
+        if enable_fanout:
+            # port-scan signal: distinct (dst addr, dst port) fan-out per SOURCE
+            # bucket — a scanner touches many; a normal client few. The (dst,
+            # port) hashes come from the shared multi-hash sweep above (seed:
+            # hashing.DSTPORT_FANOUT_SEED). Only INITIATOR-side flows count:
+            # a flow that sent SYN+ACK together (the TcpFlags.SYN_ACK
+            # composite) is a RESPONDER — without the gate a server answering
+            # one NAT'd client churning through hundreds of source ports
+            # sweeps hundreds of distinct (addr, port) pairs and lights the
+            # grid (the nat_churn scenario). Initiators count whether the
+            # handshake completed or not (SYN with or without a later ACK),
+            # so both lone-SYN and full-connect scans fire; flows with no
+            # SYN-side evidence at all (non-TCP rows, mid-capture sessions:
+            # flags without SYN) keep the pre-gate behavior only when they
+            # are not responders.
+            fanout_valid = valid
+            if flags is not None:
+                f32 = flags.astype(jnp.int32)
+                fanout_valid = valid & ((f32 & TcpFlags.SYN_ACK) == 0)
+            per_src = hll.update_per_dst(state.hll_per_src, src_h1, mhash.dp_h1,
+                                         mhash.dp_h2, fanout_valid)
         else:
-            conv_fwd, conv_rev = state.conv_fwd, state.conv_rev
-        syn_state, synack_arr = state.syn, state.synack
-        if flags is not None:
-            syn_state = ewma.accumulate(state.syn, dst_h1,
-                                        jnp.where(half_open, mass, 0.0),
-                                        valid)
-            sa_idx = (src_sym & jnp.uint32(state.synack.shape[0] - 1)
-                      ).astype(jnp.int32)
-            synack_arr = state.synack.at[sa_idx].add(
-                jnp.where(is_synack, mass, 0.0), mode="drop")
-        dscp_bytes = state.dscp_bytes
-        if dscp is not None:
-            dscp_bytes = dscp_bytes.at[
-                dscp.astype(jnp.int32) & (N_DSCP - 1)].add(
-                jnp.where(valid, bytes_f, 0.0), mode="drop")
-        drops_state, drop_causes = state.drops_ewma, state.drop_causes
+            per_src = state.hll_per_src
+    with jax.named_scope("quantile"):
+        rtt = arrays["rtt_us"]
+        dns = arrays["dns_latency_us"]
+        gamma = quantile.gamma_for(state.hist_rtt.n_buckets)
+        hist_rtt = quantile.update(state.hist_rtt, rtt, valid & (rtt > 0),
+                                   gamma)
+        hist_dns = quantile.update(state.hist_dns, dns, valid & (dns > 0),
+                                   gamma)
+    with jax.named_scope("signals"):
+        # --- signal planes (trace-time optional feature columns: a feed
+        # without a column — e.g. the legacy six-array dict — simply skips the
+        # corresponding signal; the fused kernel receives a zero value row
+        # instead, which is bit-identical to skipping) ---
+        # conversation asymmetry hashes BOTH endpoints under one seed so the
+        # pair bucket is direction-invariant (A->B and B->A land together);
+        # the lower endpoint hash defines the canonical "fwd" direction.
+        # src_sym hashes the src words under the dst seed — also exactly the
+        # victim-bucket hash the SYN-ACK side needs.
+        src_sym = mhash.src_sym
+        mass = factor.astype(jnp.float32) if samp is not None else 1.0
+        if flags is not None:  # read above, at the fan-out gate
+            # SYN-flood: half-open attempts (SYN seen, never ACKed — a spoofed
+            # flood leaves one such record per probe) bucket by victim = dst;
+            # SYN-ACK response flows bucket by victim = src (the responder),
+            # using the SAME hash seed so both land in one bucket per victim.
+            # Flag bits ride the dense feed from the datapath's OR-accumulated
+            # tcp_flags (reference exports them per flow, proto/flow.proto:30).
+            f = flags.astype(jnp.int32)
+            half_open = valid & ((f & TcpFlags.SYN) != 0) & \
+                ((f & TcpFlags.ACK) == 0)
+            is_synack = valid & ((f & TcpFlags.SYN_ACK) != 0)
+        dscp = arrays.get("dscp")
+        db = arrays.get("drop_bytes")
+        cause = arrays.get("drop_cause") if db is not None else None
+        tdb, tdp = state.total_drop_bytes, state.total_drop_packets
         if db is not None:
-            drops_state = ewma.accumulate(state.drops_ewma, dst_h1, dbf,
-                                          valid)
-        if cause is not None:
-            ci = jnp.minimum(cause.astype(jnp.int32), N_DROP_CAUSES - 1)
-            drop_causes = drop_causes.at[ci].add(
-                jnp.where(valid & (dpf > 0), dpf, 0.0), mode="drop")
-    mk = arrays.get("markers")
-    quic_rec, nat_rec = state.quic_records, state.nat_records
-    if mk is not None:
-        mki = mk.astype(jnp.int32)
-        quic_rec = quic_rec + jnp.sum(
-            (valid & ((mki & 1) != 0)).astype(jnp.float32))
-        nat_rec = nat_rec + jnp.sum(
-            (valid & ((mki & 2) != 0)).astype(jnp.float32))
+            dbf = db.astype(jnp.float32) * mass
+            dpf = arrays["drop_packets"].astype(jnp.float32) * mass
+            tdb = tdb + jnp.sum(jnp.where(valid, dbf, 0.0))
+            tdp = tdp + jnp.sum(jnp.where(valid, dpf, 0.0))
+        if enable_asym:
+            pair_idx = ((src_sym + dst_h1)
+                        & jnp.uint32(state.conv_fwd.shape[0] - 1)
+                        ).astype(jnp.int32)
+            is_fwd = src_sym < dst_h1
+            # self-pairs (src == dst: hairpin NAT, loopback capture) have no
+            # meaningful direction — both ways would land "fwd" and fire a
+            # false one-way alert every window; exclude them from the signal
+            conv_ok = valid & (src_sym != dst_h1)
 
-    return SketchState(
-        cm_bytes=cm_b, cm_pkts=cm_p, heavy=heavy, hll_src=hll_src,
-        hll_per_dst=per_dst, hll_per_src=per_src, hist_rtt=hist_rtt,
-        hist_dns=hist_dns, ddos=ddos,
-        syn=syn_state, synack=synack_arr, drops_ewma=drops_state,
-        drop_causes=drop_causes, dscp_bytes=dscp_bytes,
-        conv_fwd=conv_fwd, conv_rev=conv_rev,
-        total_records=state.total_records + jnp.sum(valid.astype(jnp.float32)),
-        total_bytes=state.total_bytes + jnp.sum(
-            jnp.where(valid, bytes_f, 0.0)),
-        total_drop_bytes=tdb, total_drop_packets=tdp,
-        quic_records=quic_rec, nat_records=nat_rec,
-        heavy_evictions=state.heavy_evictions + evicted,
-        window=state.window,
-    )
+        use_signal_kernel = use_pallas and sketch_axis is None
+        if use_signal_kernel:
+            from netobserv_tpu.ops.pallas import signal_kernel
+            planes = signal_kernel.SignalPlanes(
+                ddos_rate=state.ddos.rate, syn_rate=state.syn.rate,
+                drops_rate=state.drops_ewma.rate, synack=state.synack,
+                conv_fwd=state.conv_fwd, conv_rev=state.conv_rev,
+                dscp_bytes=state.dscp_bytes, drop_causes=state.drop_causes)
+            use_signal_kernel = signal_kernel.eligible(planes)
+        if use_signal_kernel:
+            # fused signal-plane fold: all eight scatter targets update in ONE
+            # Pallas batch walk (ops/pallas/signal_kernel.py); absent feature
+            # columns contribute zero-mass rows — bit-identical to skipping
+            m_sig = state.conv_fwd.shape[0]
+            zeros_b = jnp.zeros_like(bytes_f)
+            izeros_b = jnp.zeros(bytes_f.shape, jnp.int32)
+            dst_idx = (dst_h1 & jnp.uint32(m_sig - 1)).astype(jnp.int32)
+            src_idx = (src_sym & jnp.uint32(m_sig - 1)).astype(jnp.int32)
+            v_ddos = jnp.where(valid, bytes_f, 0.0)
+            if flags is not None:
+                v_syn = jnp.where(half_open, mass, 0.0)
+                v_synack = jnp.where(is_synack, mass, 0.0)
+            else:
+                v_syn = v_synack = zeros_b
+            if db is not None:
+                v_drops = jnp.where(valid, dbf, 0.0)
+            else:
+                v_drops = zeros_b
+            if cause is not None:
+                cause_idx = jnp.minimum(cause.astype(jnp.int32),
+                                        N_DROP_CAUSES - 1)
+                v_cause = jnp.where(valid & (dpf > 0), dpf, 0.0)
+            else:
+                cause_idx, v_cause = izeros_b, zeros_b
+            if enable_asym:
+                v_fwd = jnp.where(conv_ok & is_fwd, bytes_f, 0.0)
+                v_rev = jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0)
+            else:
+                pair_idx, v_fwd, v_rev = izeros_b, zeros_b, zeros_b
+            if dscp is not None:
+                dscp_idx = dscp.astype(jnp.int32) & (N_DSCP - 1)
+                v_dscp = jnp.where(valid, bytes_f, 0.0)
+            else:
+                dscp_idx, v_dscp = izeros_b, zeros_b
+            sig_idx = jnp.stack([dst_idx, src_idx, pair_idx, dscp_idx,
+                                 cause_idx])
+            sig_vals = jnp.stack([v_ddos, v_syn, v_drops, v_synack, v_fwd,
+                                  v_rev, v_dscp, v_cause])
+            if _tier is not None and _tier.fuse_hll:
+                # tiered megakernel: the same signal fold plus the packed
+                # global-src HLL lane in one walk (idx/rank mirror
+                # hll_kernel.update exactly — max fold, bit-exact)
+                packed = _tier.state.tables.hll_src
+                m_hll = packed.shape[0] // 3 * 4
+                hll_idx = (src_h1 & jnp.uint32(m_hll - 1)).astype(jnp.int32)
+                hll_rank = jnp.where(valid, hll._rank(src_h2), 0)
+                out, new_packed = signal_kernel.update_tiered(
+                    planes, packed, sig_idx, sig_vals, hll_idx, hll_rank)
+                _tier.out["hll_src"] = new_packed
+            else:
+                out = signal_kernel.update(planes, sig_idx, sig_vals)
+            ddos = state.ddos._replace(rate=out.ddos_rate)
+            syn_state = state.syn._replace(rate=out.syn_rate)
+            drops_state = state.drops_ewma._replace(rate=out.drops_rate)
+            synack_arr = out.synack
+            conv_fwd, conv_rev = out.conv_fwd, out.conv_rev
+            dscp_bytes, drop_causes = out.dscp_bytes, out.drop_causes
+        else:
+            # un-fused scatter chain (CPU / owner-sharded / ineligible shapes)
+            # — the fused kernel above is equivalence-pinned against exactly
+            # this path (tests/test_pallas_signal.py)
+            ddos = ewma.accumulate(state.ddos, dst_h1, bytes_f, valid)
+            if enable_asym:
+                conv_fwd = state.conv_fwd.at[pair_idx].add(
+                    jnp.where(conv_ok & is_fwd, bytes_f, 0.0), mode="drop")
+                conv_rev = state.conv_rev.at[pair_idx].add(
+                    jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0), mode="drop")
+            else:
+                conv_fwd, conv_rev = state.conv_fwd, state.conv_rev
+            syn_state, synack_arr = state.syn, state.synack
+            if flags is not None:
+                syn_state = ewma.accumulate(state.syn, dst_h1,
+                                            jnp.where(half_open, mass, 0.0),
+                                            valid)
+                sa_idx = (src_sym & jnp.uint32(state.synack.shape[0] - 1)
+                          ).astype(jnp.int32)
+                synack_arr = state.synack.at[sa_idx].add(
+                    jnp.where(is_synack, mass, 0.0), mode="drop")
+            dscp_bytes = state.dscp_bytes
+            if dscp is not None:
+                dscp_bytes = dscp_bytes.at[
+                    dscp.astype(jnp.int32) & (N_DSCP - 1)].add(
+                    jnp.where(valid, bytes_f, 0.0), mode="drop")
+            drops_state, drop_causes = state.drops_ewma, state.drop_causes
+            if db is not None:
+                drops_state = ewma.accumulate(state.drops_ewma, dst_h1, dbf,
+                                              valid)
+            if cause is not None:
+                ci = jnp.minimum(cause.astype(jnp.int32), N_DROP_CAUSES - 1)
+                drop_causes = drop_causes.at[ci].add(
+                    jnp.where(valid & (dpf > 0), dpf, 0.0), mode="drop")
+    with jax.named_scope("totals"):
+        mk = arrays.get("markers")
+        quic_rec, nat_rec = state.quic_records, state.nat_records
+        if mk is not None:
+            mki = mk.astype(jnp.int32)
+            quic_rec = quic_rec + jnp.sum(
+                (valid & ((mki & 1) != 0)).astype(jnp.float32))
+            nat_rec = nat_rec + jnp.sum(
+                (valid & ((mki & 2) != 0)).astype(jnp.float32))
+
+        return SketchState(
+            cm_bytes=cm_b, cm_pkts=cm_p, heavy=heavy, hll_src=hll_src,
+            hll_per_dst=per_dst, hll_per_src=per_src, hist_rtt=hist_rtt,
+            hist_dns=hist_dns, ddos=ddos,
+            syn=syn_state, synack=synack_arr, drops_ewma=drops_state,
+            drop_causes=drop_causes, dscp_bytes=dscp_bytes,
+            conv_fwd=conv_fwd, conv_rev=conv_rev,
+            total_records=state.total_records + jnp.sum(
+                valid.astype(jnp.float32)),
+            total_bytes=state.total_bytes + jnp.sum(
+                jnp.where(valid, bytes_f, 0.0)),
+            total_drop_bytes=tdb, total_drop_packets=tdp,
+            quic_records=quic_rec, nat_records=nat_rec,
+            heavy_evictions=state.heavy_evictions + evicted,
+            window=state.window,
+        )
 
 
 def make_ingest_fn(donate: bool = True,
                    use_pallas: bool | None = None,
                    enable_fanout: bool = True,
                    enable_asym: bool = True,
-                   tier_interior: bool | None = None):
-    """Jitted ingest; donates the state buffers so updates are in-place on HBM."""
+                   tier_interior: bool | None = None,
+                   name: str = "ingest", tiered: str | None = None):
+    """Jitted ingest; donates the state buffers so updates are in-place on
+    HBM. Like every factory here it goes through `retrace.jit`: `name` is
+    the watch name AND the XLA module's (`jit_<name>`); `tiered` is the
+    registry's fold-form attribution (`retrace.watch`)."""
     fn = lambda s, a: ingest(s, a, use_pallas=use_pallas,  # noqa: E731
                              enable_fanout=enable_fanout,
                              enable_asym=enable_asym,
                              tier_interior=tier_interior)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return retrace.jit(fn, name, tiered=tiered,
+                       donate_argnums=(0,) if donate else ())
 
 
 COMPACT_WORDS = 10  # must equal flowpack.COMPACT_WORDS (layout twin)
@@ -752,7 +768,9 @@ def make_ingest_compact_fn(batch_size: int, spill_cap: int,
                            use_pallas: bool | None = None,
                            with_token: bool = False,
                            enable_fanout: bool = True,
-                           enable_asym: bool = True):
+                           enable_asym: bool = True,
+                           name: str = "ingest_compact",
+                           tiered: str | None = None):
     """Jitted `(state, flat compact feed) -> state` (see compact_to_arrays /
     flowpack.pack_compact). `with_token` as in make_ingest_dense_fn."""
     def fn(s, flat):
@@ -760,7 +778,8 @@ def make_ingest_compact_fn(batch_size: int, spill_cap: int,
         s = ingest(s, arrays, use_pallas=use_pallas,
                    enable_fanout=enable_fanout, enable_asym=enable_asym)
         return (s, flat[:1]) if with_token else s
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return retrace.jit(fn, name, tiered=tiered,
+                       donate_argnums=(0,) if donate else ())
 
 
 RESIDENT_HDR = 4   # layout twins of flowpack.cc fp_pack_resident
@@ -788,7 +807,8 @@ def resident_to_arrays(flat: jax.Array, key_table: jax.Array,
     for the ordinary ingest: all the row widening happens in HBM, the
     transfer link only ever saw ~15 bytes/record (byte budget in
     docs/tpu_sketch.md)."""
-    return _resident_region_arrays(flat, key_table, batch_size, caps)
+    with jax.named_scope("resident_decode"):
+        return _resident_region_arrays(flat, key_table, batch_size, caps)
 
 
 def _region_nk(flat: jax.Array, batch_size: int, caps,
@@ -920,6 +940,12 @@ def resident_lane_arrays(flat: jax.Array, key_tables: jax.Array,
     within-region "new keys land before hot rows reference them" ordering
     is preserved because all scatters precede all gathers and lanes are
     row-disjoint."""
+    with jax.named_scope("resident_decode"):
+        return _resident_lane_arrays(flat, key_tables, batch_per_lane, caps,
+                                     n_lanes)
+
+
+def _resident_lane_arrays(flat, key_tables, batch_per_lane, caps, n_lanes):
     words = _resident_region_words(batch_per_lane, caps)
     regions = [flat[i * words:(i + 1) * words] for i in range(n_lanes)]
     slot_cap = key_tables.shape[-2]
@@ -946,7 +972,9 @@ def make_ingest_resident_lanes_fn(batch_per_lane: int, caps, n_lanes: int,
                                   donate: bool = True,
                                   use_pallas: bool | None = None,
                                   enable_fanout: bool = True,
-                                  enable_asym: bool = True):
+                                  enable_asym: bool = True,
+                                  name: str = "ingest_resident_lanes",
+                                  tiered: str | None = None):
     """Jitted `(state, key_tables, flat) -> (state, key_tables, token)` for
     the LANE-SHARDED resident feed on one device: `flat` concatenates
     `n_lanes` independent resident regions, each packed by its own host
@@ -960,7 +988,8 @@ def make_ingest_resident_lanes_fn(batch_per_lane: int, caps, n_lanes: int,
         s = ingest(s, arrays, use_pallas=use_pallas,
                    enable_fanout=enable_fanout, enable_asym=enable_asym)
         return s, tables, flat[:1]
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+    return retrace.jit(fn, name, tiered=tiered,
+                       donate_argnums=(0, 1) if donate else ())
 
 
 def make_ingest_resident_fn(batch_size: int, caps,
@@ -968,7 +997,9 @@ def make_ingest_resident_fn(batch_size: int, caps,
                             use_pallas: bool | None = None,
                             with_token: bool = False,
                             enable_fanout: bool = True,
-                            enable_asym: bool = True):
+                            enable_asym: bool = True,
+                            name: str = "ingest_resident",
+                            tiered: str | None = None):
     """Jitted `(state, key_table, flat resident feed) -> (state, key_table
     [, token])` — the lowest-bytes-per-record host feed (see
     resident_to_arrays / flowpack.pack_resident). The key table is threaded
@@ -979,14 +1010,17 @@ def make_ingest_resident_fn(batch_size: int, caps,
         s = ingest(s, arrays, use_pallas=use_pallas,
                    enable_fanout=enable_fanout, enable_asym=enable_asym)
         return (s, table, flat[:1]) if with_token else (s, table)
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+    return retrace.jit(fn, name, tiered=tiered,
+                       donate_argnums=(0, 1) if donate else ())
 
 
 def make_ingest_dense_fn(donate: bool = True,
                          use_pallas: bool | None = None,
                          with_token: bool = False,
                          enable_fanout: bool = True,
-                         enable_asym: bool = True):
+                         enable_asym: bool = True,
+                         name: str = "ingest_dense",
+                         tiered: str | None = None):
     """Jitted `(state, dense (B,20)u32) -> state` — the single-transfer host
     feed path (see dense_to_arrays / flowpack.pack_dense).
 
@@ -1004,7 +1038,8 @@ def make_ingest_dense_fn(donate: bool = True,
                                  use_pallas=use_pallas,
                                  enable_fanout=enable_fanout,
                                  enable_asym=enable_asym)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return retrace.jit(fn, name, tiered=tiered,
+                       donate_argnums=(0,) if donate else ())
 
 
 def decay_state(state: SketchState, factor: float) -> SketchState:
@@ -1059,6 +1094,11 @@ def roll_window(state: SketchState, cfg: SketchConfig,
                 ) -> tuple[SketchState, WindowReport]:
     """Close the current window: emit a report, roll EWMA baselines, and
     reset (or decay) the windowed sketch state while keeping the baselines."""
+    with jax.named_scope("roll"):
+        return _roll_window(state, cfg, reset_sketches, decay_factor)
+
+
+def _roll_window(state, cfg, reset_sketches, decay_factor):
     if isinstance(state, tiered.TieredState):
         # the decode-to-wide step folded into the existing roll executable:
         # the report and (via state_tables) the delta wire / query snapshot
@@ -1068,8 +1108,8 @@ def roll_window(state: SketchState, cfg: SketchConfig,
         # compound it every window): reset encodes fresh zeros (exact),
         # decay scales the tier arrays elementwise, keep leaves them
         # verbatim.
-        new_wide, report = roll_window(tiered.decode_state(state), cfg,
-                                       reset_sketches, decay_factor)
+        new_wide, report = _roll_window(tiered.decode_state(state), cfg,
+                                        reset_sketches, decay_factor)
         if decay_factor is not None:
             new_state = tiered.decay_encode(state, new_wide, decay_factor)
         elif reset_sketches:
@@ -1199,15 +1239,15 @@ def state_tables(state: SketchState) -> dict[str, jax.Array]:
 
 def make_roll_fn(cfg: SketchConfig, reset_sketches: bool = True,
                  decay_factor: float | None = None,
-                 with_tables: bool = False):
+                 with_tables: bool = False,
+                 name: str = "roll", tiered: str | None = None):
     """Jitted window roll. `with_tables=True` additionally returns the
     PRE-roll mergeable table snapshot (`state_tables`) for the federation
     delta export — one extra output of the same executable, so a due window
     still dispatches exactly one device program."""
-    if with_tables:
-        def fn(s):
-            new_state, report = roll_window(s, cfg, reset_sketches,
-                                            decay_factor)
+    def fn(s):
+        new_state, report = roll_window(s, cfg, reset_sketches, decay_factor)
+        if with_tables:
             return new_state, report, state_tables(s)
-        return jax.jit(fn)
-    return jax.jit(lambda s: roll_window(s, cfg, reset_sketches, decay_factor))
+        return new_state, report
+    return retrace.jit(fn, name, tiered=tiered)

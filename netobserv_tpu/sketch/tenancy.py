@@ -124,9 +124,8 @@ class TenantStack(_SlotRing):
         # donation is load-bearing: the stacked state is N x the resident
         # footprint, and an undonated vmapped fold copies all of it per
         # dispatch (measured 10x+ slower at N=64)
-        self._ingest = retrace.watch(
-            jax.jit(ingest_fn, donate_argnums=(0,)), "tenant_ingest",
-            tenants=n_tenants)
+        self._ingest = retrace.jit(ingest_fn, "tenant_ingest",
+                                   tenants=n_tenants, donate_argnums=(0,))
 
         def roll_one(s):
             # mirrors make_roll_fn(with_tables=True): the report and the
@@ -135,8 +134,8 @@ class TenantStack(_SlotRing):
                                                decay_factor)
             return new_state, report, sk.state_tables(s)
 
-        self._roll = retrace.watch(
-            jax.jit(jax.vmap(roll_one)), "tenant_roll", tenants=n_tenants)
+        self._roll = retrace.jit(jax.vmap(roll_one), "tenant_roll",
+                                 tenants=n_tenants)
         if metrics is not None:
             metrics.sketch_tenants_active.set(n_tenants)
 
@@ -225,7 +224,8 @@ class TenantStack(_SlotRing):
         """One stacked fold: copy every tenant's fill prefix into a ship
         slot (zero-padding the tail — invalid rows are the fold identity),
         device_put, dispatch the vmapped ingest, advance the token ring."""
-        slot = self._wait_slot(trace)
+        chunk = self._chunk(trace)
+        slot = self._wait_slot(chunk)
         buf = self._bufs[slot]
         for t in range(self.n_tenants):
             f = self._fill[t] * DENSE_WORDS
@@ -233,8 +233,10 @@ class TenantStack(_SlotRing):
                 buf[t, :f] = self._fillbuf[t].reshape(-1)[:f]
             buf[t, f:] = 0
             self._fill[t] = 0
-        with trace.stage("ingest_dispatch"):
-            state, token = self._ingest(state, self._put(buf))
+        with chunk.stage("put"):
+            dev = self._put(buf)
+        with chunk.stage("ingest_dispatch"):
+            state, token = self._ingest(state, dev)
         self._advance(slot, token)
         self.folds += 1
         if self._metrics is not None:

@@ -5,9 +5,18 @@ data-dependent shapes under jit — is enforced by tests but was never
 *watched* in production, where a retrace is a multi-second ingest stall and
 an unbounded compile-cache leak. This module turns it into an alarm:
 
-- every jitted entry point the pipeline constructs is wrapped with
-  :func:`watch` (``exporter/tpu_sketch.py`` for the single-device fns,
-  ``parallel/merge.py`` for the sharded ones);
+- every jitted entry point the pipeline constructs is made by :func:`jit`,
+  the ONE seam that names, jits and watches (the factories of
+  ``sketch/state.py``, ``parallel/merge.py``, ``sketch/tenancy.py``): the
+  watch name is the function's ``__name__``, so the XLA module is
+  ``jit_<watch name>`` and a device capture names every program the way
+  ``/debug/executables`` does;
+- every call of a watched entry opens the profiler annotation
+  ``netobserv:dispatch`` with ``fn=<watch name>`` and ``call=<n>`` (its
+  per-executable sequence number, ``Watched.calls``): the k-th run of
+  module ``jit_<fn>`` in a capture is the run of dispatch ``call0 + k``
+  (``utils.tracing.annotate`` — the profiler's no-op while no session
+  runs);
 - a process-wide ``jax.monitoring`` listener counts XLA *lowerings*
   (``/jax/core/compile/jaxpr_to_mlir_module_duration``) and attributes each
   to the watched entry point currently executing on that thread (jit traces
@@ -50,6 +59,8 @@ import threading
 import time
 import weakref
 from typing import Any, Callable, Optional
+
+from netobserv_tpu.utils import tracing
 
 log = logging.getLogger("netobserv_tpu.retrace")
 
@@ -161,7 +172,8 @@ class Watched:
         _tls.args = args
         t0 = time.perf_counter()
         try:
-            return self._fn(*args, **kwargs)
+            with tracing.annotate("dispatch", fn=self.name, call=self.calls):
+                return self._fn(*args, **kwargs)
         except Exception as exc:
             if self.calls <= self.warmup_calls:
                 # a first call that fails is a lowering or compile refusal
@@ -283,6 +295,20 @@ def watch(fn: Callable, name: str,
     return w
 
 
+def jit(fn: Callable, name: str, *, tenants: Optional[int] = None,
+        tiered: Optional[str] = None, **jit_kwargs) -> Callable:
+    """Name, jit and watch one entry point — in that order, so the name the
+    registry reports (``/debug/executables``, ``sketch_retraces_total{fn}``)
+    is the name XLA gives the module (``jit_<name>``) and a device capture
+    needs no inference to tell the programs apart. `jit_kwargs` go to
+    ``jax.jit``; the rest to :func:`watch`."""
+    import jax
+
+    fn.__name__ = fn.__qualname__ = name
+    return watch(jax.jit(fn, **jit_kwargs), name, tenants=tenants,
+                 tiered=tiered)
+
+
 def watched() -> list[Watched]:
     """Every live wrapper (the registry rows' owners — `lower` one with its
     `last_avals` to inspect the executable it dispatched)."""
@@ -294,17 +320,6 @@ def set_metrics(metrics) -> None:
     retraces (sketch_retraces_total{fn=...})."""
     global _metrics
     _metrics = metrics
-
-
-def configure(enabled: Optional[bool] = None,
-              warmup_calls: Optional[int] = None) -> None:
-    """Test/ops hook: toggle the watchdog or change the default warmup
-    window for subsequently watched functions."""
-    global _enabled, _default_warmup
-    if enabled is not None:
-        _enabled = enabled
-    if warmup_calls is not None:
-        _default_warmup = warmup_calls
 
 
 def snapshot() -> list[dict]:

@@ -15,6 +15,24 @@ The inter-span *gaps* are as load-bearing as the spans: the time between the
 evicted/export queue wait — the first thing to grow when the exporter falls
 behind.
 
+Two recorders share every stage boundary:
+
+- **the profiler's** — every ``stage()`` opens a
+  ``jax.profiler.TraceAnnotation`` named ``netobserv:<stage>``, sampled or
+  not, carrying the stage's ids as arguments (``eviction``, ``evictions``,
+  ``chunk``, ``k``, ``cont``, ``window``; ``fn``/``call`` on the
+  ``dispatch`` annotation ``utils.retrace`` opens through
+  :func:`annotate`). While no ``jax.profiler`` session runs the annotation
+  is the profiler's own no-op (one flag test in C++; about a microsecond
+  of Python per stage, measured on the v5e host in PR 25); in a capture the
+  spans lie on the device trace's clock, and the ids tie an eviction to the
+  fold chunks that carry its rows and a dispatch to its module run. There
+  is no switch and no environment variable. ``jax`` is never imported from
+  here: a process that has not loaded it (an agent with ``EXPORT=grpc``)
+  gets the shared :data:`NULL_SPAN` instead.
+- **the flight recorder** — sampled by ``TRACE_SAMPLE``, below. Sampled
+  spans carry the same ids (``/debug/traces`` renders them per stage).
+
 Sampling and the zero-cost contract:
 
 - ``TRACE_SAMPLE`` (env, float in [0, 1], default 0/unset = disabled) is the
@@ -24,11 +42,10 @@ Sampling and the zero-cost contract:
   pipeline's periodic call pattern cannot alias one kind out of the
   sample).
 - Disabled (the default), :func:`start_trace` is one module-bool check
-  returning the shared :data:`NULL_TRACE`, whose ``stage()`` returns the
-  shared :data:`NULL_SPAN` context manager — no locks, no timestamps, no
-  allocations anywhere on the hot path (the same discipline as
-  ``utils.faultinject``; pinned by tests/test_tracing.py and the
-  ``bench.py --host-only`` A/B in docs/observability.md).
+  returning the shared :data:`NULL_TRACE`, whose ``stage()`` opens the
+  profiler annotation and nothing else — no ``Trace``, no recorder entry,
+  no lock, no timestamp (the same discipline as ``utils.faultinject``;
+  pinned by tests/test_tracing.py).
 - Unsampled calls while enabled cost one int increment + one modulo.
 
 ``TRACE_RING`` (env, default 64) bounds how many completed traces the
@@ -52,6 +69,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -61,12 +79,13 @@ __all__ = [
     "NULL_SPAN", "NULL_TRACE", "Trace", "FlightRecorder", "TraceContext",
     "start_trace", "configure", "set_metrics", "snapshot", "enabled",
     "set_active", "clear_active", "active_trace",
-    "context_of", "continue_trace", "group",
+    "context_of", "continue_trace", "group", "annotate",
 ]
 
 
 class _NullSpan:
-    """Shared no-op context manager handed out whenever tracing is off."""
+    """Shared no-op context manager: what a stage is in a process that has
+    not loaded jax (no profiler to annotate for)."""
 
     __slots__ = ()
 
@@ -79,15 +98,62 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+#: prefix of every annotation this module opens in a profiler capture
+ANNOTATION_PREFIX = "netobserv:"
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def annotate(stage: str, **ids):
+    """The profiler's span for one stage boundary: a context manager that
+    is ``jax.profiler.TraceAnnotation("netobserv:<stage>", **ids)`` — the
+    profiler's own no-op while no session runs — or :data:`NULL_SPAN` in a
+    process that has not imported jax (this module never imports it).
+    Per drain, per fold chunk, per window, per dispatch: never per record."""
+    global _annotation
+    cls = _annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return NULL_SPAN
+        from jax.profiler import TraceAnnotation as cls
+        _annotation = cls
+    return cls(ANNOTATION_PREFIX + stage, **ids)
+
+
+class _Bound:
+    """A trace handle whose every stage carries `ids` besides its own (the
+    drain thread's ``eviction=<n>``, a fold's ``evictions=<a>-<b>``, a
+    window's ``window=<n>``): what ``trace.bind(**ids)`` returns, for
+    handing to callees that open stages without knowing the ids. Everything
+    else (``trace_id``, ``kind``, ...) reads through to the trace."""
+
+    __slots__ = ("_trace", "_ids")
+
+    def __init__(self, trace, ids: dict):
+        self._trace = trace
+        self._ids = ids
+
+    def stage(self, name: str, **ids):
+        return self._trace.stage(name, **{**self._ids, **ids})
+
+    def bind(self, **ids):
+        return _Bound(self._trace, {**self._ids, **ids})
+
+    def __getattr__(self, item: str):     # sampled, finish, trace_id, ...
+        return getattr(self._trace, item)
+
 
 class _NullTrace:
-    """Shared do-nothing trace: every un-sampled batch carries this."""
+    """Shared do-nothing trace: every un-sampled batch carries this. Its
+    stages are profiler annotations only."""
 
     __slots__ = ()
     sampled = False
 
-    def stage(self, name: str):
-        return NULL_SPAN
+    def stage(self, name: str, **ids):
+        return annotate(name, **ids)
+
+    def bind(self, **ids):
+        return _Bound(self, ids)
 
     def finish(self) -> None:
         pass
@@ -110,33 +176,43 @@ class TraceContext(NamedTuple):
 
 
 class _Span:
-    __slots__ = ("stage", "t0", "t1", "thread")
+    __slots__ = ("stage", "t0", "t1", "thread", "ids")
 
-    def __init__(self, stage: str, t0: float, t1: float, thread: str):
+    def __init__(self, stage: str, t0: float, t1: float, thread: str,
+                 ids: dict):
         self.stage = stage
         self.t0 = t0
         self.t1 = t1
         self.thread = thread
+        self.ids = ids
 
 
 class _SpanCtx:
-    """Context manager recording one stage span onto its trace (records on
+    """Context manager recording one stage span onto its trace(s) — several
+    for a :class:`TraceGroup` — under ONE profiler annotation (records on
     exit even when the stage raised — a failed stage's duration is evidence,
     not noise)."""
 
-    __slots__ = ("_trace", "_stage", "_t0")
+    __slots__ = ("_traces", "_stage", "_ids", "_t0", "_ann")
 
-    def __init__(self, trace: "Trace", stage: str):
-        self._trace = trace
+    def __init__(self, traces: tuple, stage: str, ids: dict):
+        self._traces = traces
         self._stage = stage
+        self._ids = ids
         self._t0 = 0.0
+        self._ann = NULL_SPAN
 
     def __enter__(self):
+        self._ann = annotate(self._stage, **self._ids)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._trace._add(self._stage, self._t0, time.perf_counter())
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        for t in self._traces:
+            t._add(self._stage, self._t0, t1, self._ids)
         return False
 
 
@@ -166,14 +242,17 @@ class Trace:
         self._lock = threading.Lock()
         self._done = False
 
-    def stage(self, name: str) -> _SpanCtx:
-        return _SpanCtx(self, name)
+    def stage(self, name: str, **ids) -> _SpanCtx:
+        return _SpanCtx((self,), name, ids)
 
-    def _add(self, stage: str, t0: float, t1: float) -> None:
+    def bind(self, **ids) -> _Bound:
+        return _Bound(self, ids)
+
+    def _add(self, stage: str, t0: float, t1: float, ids: dict) -> None:
         with self._lock:
             if not self._done:
                 self.spans.append(_Span(
-                    stage, t0, t1, threading.current_thread().name))
+                    stage, t0, t1, threading.current_thread().name, ids))
 
     def finish(self) -> None:
         """Seal the trace and hand it to the flight recorder (idempotent —
@@ -208,6 +287,10 @@ class Trace:
                 # overlapped across threads; reported raw, not clipped)
                 "gap_ms": (round((s.t0 - prev_t1) * 1e3, 3)
                            if prev_t1 is not None else 0.0),
+                # what caused the span: eviction / evictions / chunk / k /
+                # cont / window — the same arguments its profiler
+                # annotation carries
+                **({"ids": dict(s.ids)} if s.ids else {}),
             })
             prev_t1 = s.t1
         total = (spans[-1].t1 - spans[0].t0) if spans else 0.0
@@ -224,25 +307,6 @@ class Trace:
         return out
 
 
-class _GroupSpan:
-    """Context manager fanning one stage span out to several traces."""
-
-    __slots__ = ("_ctxs",)
-
-    def __init__(self, ctxs: list):
-        self._ctxs = ctxs
-
-    def __enter__(self):
-        for c in self._ctxs:
-            c.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        for c in self._ctxs:
-            c.__exit__(*exc)
-        return False
-
-
 class TraceGroup:
     """Several sampled traces sharing the same spans — the aggregator's
     window close, where one roll/publish serves every agent trace continued
@@ -256,8 +320,11 @@ class TraceGroup:
     def __init__(self, traces: list):
         self.traces = traces
 
-    def stage(self, name: str) -> _GroupSpan:
-        return _GroupSpan([t.stage(name) for t in self.traces])
+    def stage(self, name: str, **ids) -> _SpanCtx:
+        return _SpanCtx(tuple(self.traces), name, ids)
+
+    def bind(self, **ids) -> _Bound:
+        return _Bound(self, ids)
 
     def finish(self) -> None:
         for t in self.traces:
@@ -401,15 +468,16 @@ def continue_trace(ctx, kind: str = "batch"):
 
 # Per-thread active trace: lets a deep callee (the kernel drain inside
 # BpfmanFetcher.lookup_and_delete) attach child spans to the trace born in
-# map_tracer WITHOUT widening the FlowFetcher protocol. Only SAMPLED traces
-# are ever bound (map_tracer gates on trace.sampled), so the disabled path
-# pays nothing for the binding; the callee's active_trace() lookup is one
-# thread-local getattr PER DRAIN, never per record.
+# map_tracer WITHOUT widening the FlowFetcher protocol. map_tracer binds the
+# drain's handle (the batch trace or the shared null one, with the drain's
+# eviction=<n>) so the callee's stages carry the id in a capture whether or
+# not the drain is sampled: one thread-local write and one getattr PER
+# DRAIN, never per record.
 _active = threading.local()
 
 
 def set_active(trace) -> None:
-    """Bind `trace` as the calling thread's active trace (sampled only)."""
+    """Bind `trace` as the calling thread's active trace."""
     _active.trace = trace
 
 

@@ -132,15 +132,13 @@ def test_zero_postwarmup_retraces_across_folds_and_rolls():
     """Slot maintenance lives inside the watched ingest/roll executables:
     a stream of folds, rolls and refresh-style re-rolls must compile each
     entry exactly once (the fixed-shape invariant — counted through the
-    retrace.watch wrappers the exporter mounts)."""
+    retrace.jit wrappers the factories return)."""
     from netobserv_tpu.sketch import state as sk
-    from netobserv_tpu.utils import retrace
 
     cfg = sk.SketchConfig(cm_width=1 << 10, topk=64, persrc_buckets=64,
                           perdst_buckets=64, ewma_buckets=128)
-    ing = retrace.watch(sk.make_ingest_fn(donate=False), "topk_t_ingest")
-    roll = retrace.watch(sk.make_roll_fn(cfg, with_tables=True),
-                         "topk_t_roll")
+    ing = sk.make_ingest_fn(donate=False, name="topk_t_ingest")
+    roll = sk.make_roll_fn(cfg, with_tables=True, name="topk_t_roll")
     rng = np.random.default_rng(3)
     universe = rng.integers(0, 2**32, (100, KW), dtype=np.uint32)
     s = sk.init_state(cfg)

@@ -287,9 +287,7 @@ def test_tiered_pallas_and_scatter_forms_bit_exact():
 def test_zero_post_warmup_retraces():
     """Fixed shapes everywhere: promotion changes values, never shapes —
     the jitted tiered ingest compiles once and never again."""
-    from netobserv_tpu.utils import retrace
-
-    fn = retrace.watch(sk.make_ingest_fn(donate=False), "tiered_ingest_t")
+    fn = sk.make_ingest_fn(donate=False, name="tiered_ingest_t")
     s = sk.init_state(SMALL_CFG._replace(tiered=SMALL_TIERS))
     for i in range(4):
         s = fn(s, _dev(_batch(128, seed=i, max_bytes=90_000)))
@@ -563,15 +561,13 @@ def test_interior_zero_retraces_across_superbatch_ladder(spec):
     jit PER ladder size, each compiling exactly once (promotion changes
     values, never shapes) — and each watched entry carries the
     tiered=interior attribution /debug/executables reads."""
-    from netobserv_tpu.utils import retrace
-
     cfg = _interior_cfg(spec)
     assert sk.tiered_fold_form(cfg._replace(use_pallas=True)) == "interior"
     s = sk.init_state(cfg)
     for k in (1, 2, 4):
-        fn = retrace.watch(
-            sk.make_ingest_fn(donate=False, use_pallas=True),
-            f"tiered_interior_x{k}", tiered="interior")
+        fn = sk.make_ingest_fn(donate=False, use_pallas=True,
+                               name=f"tiered_interior_x{k}",
+                               tiered="interior")
         for i in range(3):
             s = fn(s, _dev(_batch(64 * k, seed=i, max_bytes=9000)))
         jax.block_until_ready(jax.tree.leaves(s))

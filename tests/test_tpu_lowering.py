@@ -4,10 +4,16 @@ platform (`jax.default_backend` patched, so the auto gates take their TPU
 branch) and must hold the Mosaic calls its gate promised. Lowering builds the
 Mosaic module only — block-shape refusals surface here, in seconds, instead
 of on the chip budget; VMEM, layouts and values are `chip_smoke.py`'s.
+
+The same lowerings pin what a device capture reads (utils/retrace.jit,
+sketch/state.py scopes): every watched entry lowers to the module
+`jit_<watch name>`, the fold's ops carry one named scope per sketch update in
+their metadata, and each Pallas call its kernel's name.
 """
 
 import glob
 import os
+import re
 import subprocess
 import sys
 from unittest import mock
@@ -30,11 +36,50 @@ CFG = sk.SketchConfig()
 BATCH = 8192
 
 
-def mosaic_calls(fn, *args) -> int:
-    """Cross-lower `fn(*args)` for the TPU and count its Mosaic kernels."""
+def lowered(fn, *args, debug_info: bool = False) -> str:
+    """`fn(*args)` cross-lowered for the TPU, as StableHLO text."""
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=debug_info)
+
+
+def mosaic_calls(fn, *args) -> int:
+    """Cross-lower `fn(*args)` for the TPU and count its Mosaic kernels;
+    the module must carry the entry's watch name."""
+    text = lowered(fn, *args)
+    assert f"module @jit_{fn.name} " in text, (fn.name, text[:200])
     return text.count("tpu_custom_call")
+
+
+def scopes_of(text: str) -> set:
+    """The named scopes in the op metadata of a debug-info lowering: of each
+    `loc("<a>/<b>/<primitive>")`, the first component that is no transform
+    wrapper such as `jit(main)` — how a device capture attributes an op."""
+    found = set()
+    for m in re.finditer(r'loc\("([^"]+)"', text):
+        for part in m.group(1).split("/")[:-1]:
+            if part and not re.fullmatch(r"\w+\(.*\)", part):
+                found.add(part)
+                break
+    return found
+
+
+#: sketch/state.py's one-level scopes inside every ingest executable
+FOLD_SCOPES = {"hash", "countmin", "topk", "hll_src", "hll_grids",
+               "quantile", "signals", "totals"}
+
+
+def resident_ladder_entry(k: int, cfg=CFG, lanes: int = 8):
+    """(fn, args) of the exporter's single-device ladder entry x<k>."""
+    bpl = BATCH // lanes
+    caps = flowpack.default_resident_caps(bpl)
+    fn = sk.make_ingest_resident_lanes_fn(
+        bpl, caps, k * lanes, name=f"ingest_resident_lanes_x{k}")
+    tables = jax.ShapeDtypeStruct((4 * lanes, 1 << 18, sk.KEY_WORDS),
+                                  jnp.uint32)
+    flat = jax.ShapeDtypeStruct(
+        (k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
+    return fn, (state_shapes(cfg), tables, flat)
 
 
 def state_shapes(cfg=CFG):
@@ -59,15 +104,10 @@ def test_single_chip_ingest_lowers_with_five_mosaic_calls():
 def test_resident_ladder_entry_lowers_with_five_mosaic_calls(k, cfg):
     """What SKETCH_TIERED=true alone dispatches is this ladder over a tiered
     state, in the decode form: the same five kernels."""
-    lanes = 8  # what an 8+-core host resolves (config.resolved_pack_threads)
-    bpl = BATCH // lanes
-    caps = flowpack.default_resident_caps(bpl)
-    fn = sk.make_ingest_resident_lanes_fn(bpl, caps, k * lanes)
-    tables = jax.ShapeDtypeStruct((4 * lanes, 1 << 18, sk.KEY_WORDS),
-                                  jnp.uint32)
-    flat = jax.ShapeDtypeStruct(
-        (k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
-    assert mosaic_calls(fn, state_shapes(cfg), tables, flat) == MOSAIC_CALLS
+    # 8 lanes: what an 8+-core host resolves (config.resolved_pack_threads)
+    fn, args = resident_ladder_entry(k, cfg)
+    assert fn.name == f"ingest_resident_lanes_x{k}"
+    assert mosaic_calls(fn, *args) == MOSAIC_CALLS
 
 
 def test_sharded_dense_ingest_lowers_with_five_mosaic_calls():
@@ -99,6 +139,82 @@ def test_tenant_stack_ingest_lowers_with_five_mosaic_calls():
     state = jax.eval_shape(lambda: tenancy.init_stacked_state(CFG, n))
     dense = jax.ShapeDtypeStruct((n, BATCH * sk.DENSE_WORDS), jnp.uint32)
     assert mosaic_calls(stack._ingest, state, dense) == MOSAIC_CALLS
+
+
+# --- what a device capture reads: module names, scopes, kernel names --------
+
+def test_fold_ops_carry_one_scope_per_sketch_update_and_kernels_their_names():
+    fn, args = resident_ladder_entry(1)
+    text = lowered(fn, *args, debug_info=True)
+    assert "module @jit_ingest_resident_lanes_x1 " in text
+    assert scopes_of(text) >= FOLD_SCOPES | {"resident_decode"}
+    # nowhere deeper than one level: no scope nests in another
+    for a in FOLD_SCOPES | {"resident_decode"}:
+        for b in FOLD_SCOPES | {"resident_decode"}:
+            assert f"/{a}/{b}/" not in text, (a, b)
+    kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert kernels == {"countmin_update_two", "hll_update", "topk_reduce",
+                       "signal_update"}
+
+
+@pytest.mark.parametrize("with_tables", [False, True])
+def test_roll_lowers_named_and_scoped(with_tables):
+    fn = sk.make_roll_fn(CFG, with_tables=with_tables)
+    text = lowered(fn, state_shapes(), debug_info=True)
+    assert "module @jit_roll " in text
+    assert "roll" in scopes_of(text)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_sharded_resident_ladder_entry_lowers_named_and_scoped(k):
+    """The mesh cell's ingests: `jit_sharded_ingest_resident_x<k>`, the
+    same scopes inside the shard_map body."""
+    mesh = make_mesh(MeshSpec(data=4), devices=jax.devices()[:4])
+    lanes = 2
+    bpl = BATCH // (4 * lanes)
+    caps = flowpack.default_resident_caps(bpl)
+    name = f"sharded_ingest_resident_x{k}"
+    fn = pmerge.make_sharded_ingest_resident_fn(
+        mesh, CFG, bpl, caps, lanes=k * lanes, watch_name=name)
+    dist = jax.eval_shape(lambda: pmerge.init_dist_state(CFG, mesh))
+    tables = jax.ShapeDtypeStruct((4, 4 * lanes, 1 << 18, sk.KEY_WORDS),
+                                  jnp.uint32)
+    flat = jax.ShapeDtypeStruct(
+        (4 * k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
+    text = lowered(fn, dist, tables, flat, debug_info=True)
+    assert f"module @jit_{name} " in text
+    assert scopes_of(text) >= FOLD_SCOPES | {"resident_decode"}
+    assert text.count("tpu_custom_call") == MOSAIC_CALLS
+
+
+def test_sharded_merge_lowers_named_with_its_collectives_scoped():
+    mesh = make_mesh(MeshSpec(data=4), devices=jax.devices()[:4])
+    fn = pmerge.make_merge_fn(mesh, CFG, with_tables=True)
+    dist = jax.eval_shape(lambda: pmerge.init_dist_state(CFG, mesh))
+    text = lowered(fn, dist, debug_info=True)
+    assert "module @jit_sharded_merge " in text
+    assert {"merge_allreduce", "merge_topk_gather"} <= scopes_of(text)
+    # every collective of the roll lies under one of the two scopes
+    locs = dict(re.findall(r'(#loc\d+) = loc\("([^"]+)"', text))
+    seen = 0
+    for line in text.splitlines():
+        m = re.search(r'stablehlo\.(all_reduce|all_gather).*loc\((#loc\d+)\)$',
+                      line)
+        if m:
+            seen += 1
+            assert re.search(r"(^|/)merge_(allreduce|topk_gather)/",
+                             locs[m.group(2)]), (m.group(1), locs[m.group(2)])
+    assert seen
+
+
+def test_tenant_roll_and_fold_delta_lower_named():
+    n = 4
+    stack = tenancy.TenantStack(n, CFG, BATCH)
+    state = jax.eval_shape(lambda: tenancy.init_stacked_state(CFG, n))
+    assert "module @jit_tenant_roll " in lowered(stack._roll, state)
+    dense = jax.ShapeDtypeStruct((n, BATCH * sk.DENSE_WORDS), jnp.uint32)
+    assert "module @jit_tenant_ingest " in lowered(stack._ingest, state,
+                                                   dense)
 
 
 # --- compile cache placement (utils/platform.enable_compile_cache) ---------

@@ -2,9 +2,13 @@
 
 Pins the tentpole contracts:
 
-- tracing disabled (TRACE_SAMPLE unset) is a TRUE no-op: start_trace returns
-  the one shared null trace, whose stage() returns the one shared null span
-  — no per-call allocations, no timestamps, no recorder traffic;
+- tracing disabled (TRACE_SAMPLE unset) records nothing: start_trace returns
+  the one shared null trace, whose stage() opens the profiler's annotation
+  `netobserv:<stage>` (the profiler's own no-op while no session runs) and
+  nothing else — no Trace, no recorder entry, no lock, no timestamp;
+- under a jax.profiler session the same stages lie on the profiler's clock
+  with the ids that chain an eviction to its fold chunks and a chunk to its
+  dispatch (eviction / evictions / chunk / k / cont / fn / call);
 - sampled traces capture per-stage durations and inter-stage queue-wait
   gaps, newest-first in the fixed-size ring;
 - the batch journey (evict -> queue -> fold -> pack -> ingest dispatch) and
@@ -48,22 +52,46 @@ SMALL_CFG_KW = dict(cm_width=1 << 12, topk=256, hll_precision=8,
 
 # --- null-path contract ----------------------------------------------------
 
-def test_disabled_is_shared_null_objects():
+def test_disabled_is_the_shared_null_trace_and_records_nothing(monkeypatch):
     tracing.configure(sample=0.0)
     t1 = tracing.start_trace("batch")
     t2 = tracing.start_trace("window")
     assert t1 is tracing.NULL_TRACE and t2 is tracing.NULL_TRACE
     assert not t1.sampled
-    # stage() hands out the one shared null context manager: no per-call
-    # allocation, no timestamps
-    s1 = t1.stage("evict")
-    s2 = t1.stage("fold")
-    assert s1 is tracing.NULL_SPAN and s2 is tracing.NULL_SPAN
-    with s1:
-        pass
-    t1.finish()
+    # without sampling a stage is the profiler's annotation and nothing of
+    # the flight recorder's: no Trace and no span object, no lock, no
+    # timestamp (the recorder's clock and lock raise if touched)
+    monkeypatch.setattr(tracing.time, "perf_counter",
+                        lambda: pytest.fail("a timestamp without sampling"))
+    monkeypatch.setattr(tracing.threading, "Lock",
+                        lambda: pytest.fail("a lock without sampling"))
+    for handle in (t1, t1.bind(eviction=7)):
+        s1 = handle.stage("evict")
+        s2 = handle.stage("fold", eviction=7)
+        for span in (s1, s2):
+            assert not isinstance(span, (tracing._SpanCtx, tracing.Trace))
+            with span:
+                pass
+        handle.finish()
     assert len(tracing.recorder) == 0
     assert not tracing.enabled()
+
+
+def test_a_stage_is_the_null_span_until_the_process_loads_jax():
+    """`utils.tracing` never imports jax: an agent with EXPORT=grpc has no
+    profiler to annotate for, and importing the drain must not load one."""
+    code = ("import sys\n"
+            "import netobserv_tpu.flow.map_tracer\n"
+            "from netobserv_tpu.utils import tracing\n"
+            "span = tracing.NULL_TRACE.stage('evict', eviction=1)\n"
+            "assert span is tracing.NULL_SPAN, span\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    import subprocess
+    import sys
+
+    got = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert got.returncode == 0, got.stderr
 
 
 def test_null_trace_survives_every_pipeline_verb():
@@ -130,8 +158,8 @@ def test_active_trace_binding():
 def test_evict_child_spans_ride_the_batch_trace():
     """A fetcher reading tracing.active_trace() inside lookup_and_delete
     (the BpfmanFetcher eviction plane) lands its child spans on the SAME
-    sampled trace map_tracer started — and with sampling off, the whole
-    path stays on the shared null objects."""
+    sampled trace map_tracer started, each with the drain's `eviction` id
+    — and with sampling off, the drain's handle is the unsampled one."""
     import queue
 
     from netobserv_tpu.datapath.fetcher import FakeFetcher
@@ -141,7 +169,7 @@ def test_evict_child_spans_ride_the_batch_trace():
     class SpanningFetcher(FakeFetcher):
         def lookup_and_delete(self):
             trace = tracing.active_trace()
-            self.saw_null = trace is tracing.NULL_TRACE
+            self.saw_unsampled = not trace.sampled
             with trace.stage("decode"):
                 pass
             with trace.stage("merge_percpu"):
@@ -162,15 +190,18 @@ def test_evict_child_spans_ride_the_batch_trace():
 
     tracing.configure(sample=1.0, capacity=8)
     f, evicted = run_once()
-    assert not f.saw_null
+    assert not f.saw_unsampled
     # the columnar path leaves the open trace riding the EvictedFlows for
     # the exporter fold — the drain's child spans are already on it,
     # alongside map_tracer's own evict span
     stages = {s.stage for s in evicted.trace.spans}
     assert {"evict", "decode", "merge_percpu", "align"} <= stages
+    assert evicted.eviction > 0
+    assert {s.ids.get("eviction") for s in evicted.trace.spans} == {
+        evicted.eviction}
     tracing.configure(sample=0.0)
     f2, evicted2 = run_once()
-    assert f2.saw_null  # unsampled drains never see a live trace
+    assert f2.saw_unsampled  # unsampled drains never see a live trace
     assert not hasattr(evicted2, "trace")
 
 
@@ -505,8 +536,7 @@ def test_retrace_watchdog_on_real_ingest_changed_batch_shape():
     retrace.set_metrics(m)
     cfg = sk.SketchConfig(**SMALL_CFG_KW)
     state = sk.init_state(cfg)
-    ingest = retrace.watch(
-        sk.make_ingest_dense_fn(donate=False), "ingest_dense_test")
+    ingest = sk.make_ingest_dense_fn(donate=False, name="ingest_dense_test")
     rng = np.random.default_rng(3)
 
     def dense(n):
@@ -563,3 +593,193 @@ def test_exporter_full_cycle_stays_retrace_silent():
     finally:
         exp.close()
     assert retrace.total_retraces() == before
+
+
+# --- the stages on the profiler's clock, with ids --------------------------
+
+def _resident_exporter(metrics=None):
+    from netobserv_tpu.exporter.tpu_sketch import TpuSketchExporter
+    from netobserv_tpu.sketch.state import SketchConfig
+
+    return TpuSketchExporter(batch_size=512, window_s=600.0,
+                             sketch_cfg=SketchConfig(**SMALL_CFG_KW),
+                             sink=lambda obj: None, metrics=metrics)
+
+
+def _served_path():
+    """(fetcher, queue, MapTracer) feeding 1,300-flow evictions: two full
+    512-row batches fold as each arrives, the tail rides to the next."""
+    import queue
+
+    from netobserv_tpu.datapath.fetcher import FakeFetcher
+    from netobserv_tpu.flow.map_tracer import MapTracer
+
+    out: queue.Queue = queue.Queue()
+    fetcher = FakeFetcher()
+    return fetcher, out, MapTracer(fetcher, out, columnar=True)
+
+
+def _drain_and_export(fetcher, tracer, out, exp, n: int) -> list:
+    """`n` evictions MapTracer -> TpuSketchExporter; their sequence
+    numbers."""
+    from tests.test_pipeline import make_events
+
+    seqs = []
+    for i in range(n):
+        fetcher.inject_events(make_events(1300, sport0=1000 + 37 * i))
+        tracer._evict_once()
+        evicted = out.get_nowait()
+        seqs.append(evicted.eviction)
+        exp.export_evicted(evicted)
+    return seqs
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """One jax.profiler session (CPU backend) round two 1,300-flow evictions
+    through MapTracer -> TpuSketchExporter and the window's close: every
+    `netobserv:` annotation on the host plane as (stage, ids, start, end)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracing.configure(sample=0.0)
+    exp = _resident_exporter()
+    fetcher, out, tracer = _served_path()
+    where = str(tmp_path_factory.mktemp("capture"))
+    try:
+        _drain_and_export(fetcher, tracer, out, exp, 1)     # compiles x1
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(where, profiler_options=opts)
+        try:
+            seqs = _drain_and_export(fetcher, tracer, out, exp, 2)
+            exp.flush()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        exp.close()
+    path = glob.glob(where + "/plugins/profile/*/*.xplane.pb")[0]
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(tracing.ANNOTATION_PREFIX):
+                    found.append((e.name[len(tracing.ANNOTATION_PREFIX):],
+                                  dict(e.stats), e.start_ns,
+                                  e.start_ns + e.duration_ns))
+    return {"seqs": seqs, "spans": sorted(found, key=lambda s: s[2]),
+            "recorded": len(tracing.recorder)}
+
+
+def _of(capture, stage):
+    return [s for s in capture["spans"] if s[0] == stage]
+
+
+def test_capture_holds_every_served_path_stage(capture):
+    stages = {s[0] for s in capture["spans"]}
+    assert {"evict", "fold", "resident_pack", "put", "ingest_dispatch",
+            "dispatch", "roll_drain", "roll_dispatch", "report_render",
+            "query_snapshot", "report_sink"} <= stages
+    # the profiler's spans need no sampling, and sample nothing
+    assert capture["recorded"] == 0
+
+
+def test_capture_eviction_ids_chain_evict_to_fold_to_chunks(capture):
+    seqs = capture["seqs"]
+    assert [s[1]["eviction"] for s in _of(capture, "evict")] == seqs
+    folds = _of(capture, "fold")
+    assert folds and {f[1]["eviction"] for f in folds} <= set(seqs)
+    for fold in folds:
+        # a fold starts after the evict of the eviction it names ended:
+        # the difference is the export queue wait
+        evict = next(e for e in _of(capture, "evict")
+                     if e[1]["eviction"] == fold[1]["eviction"])
+        assert fold[2] >= evict[3]
+    held = set()
+    for stage in ("resident_pack", "put", "ingest_dispatch"):
+        for s in _of(capture, stage):
+            first, last = (int(x) for x in s[1]["evictions"].split("-"))
+            assert first <= last and last in seqs
+            held.update(range(first, last + 1))
+            assert s[1]["k"] == 1 and s[1]["cont"] in (0, 1)
+    assert set(seqs) <= held    # every eviction's rows ride some chunk
+
+
+def test_capture_chunk_ids_chain_pack_put_dispatch_to_the_jit_call(capture):
+    packs, puts = _of(capture, "resident_pack"), _of(capture, "put")
+    sends = _of(capture, "ingest_dispatch")
+    chunks = [s[1]["chunk"] for s in packs]
+    assert chunks == sorted(set(chunks)) and len(chunks) >= 2
+    assert [s[1]["chunk"] for s in puts] == chunks
+    assert [s[1]["chunk"] for s in sends] == chunks
+    # conftest's 8 virtual devices make the exporter build its mesh: the
+    # entry is sharded_ingest_resident_x1 there, ingest_resident_lanes_x1
+    # on one device
+    calls = [d for d in _of(capture, "dispatch") if "ingest" in d[1]["fn"]]
+    assert {d[1]["fn"] for d in calls} <= {"sharded_ingest_resident_x1",
+                                           "ingest_resident_lanes_x1"}
+    assert len({d[1]["fn"] for d in calls}) == 1
+    # one watched call inside each ingest_dispatch, numbered in order
+    assert len(calls) == len(sends)
+    for send, call in zip(sends, calls):
+        assert send[2] <= call[2] and call[3] <= send[3]
+    numbers = [c[1]["call"] for c in calls]
+    assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
+    for pack, put, send in zip(packs, puts, sends):
+        assert pack[3] <= put[2] and put[3] <= send[2]
+
+
+def test_capture_window_ids_ride_from_the_roll_to_the_sink(capture):
+    windows = {s[0]: s[1]["window"] for s in capture["spans"]
+               if "window" in s[1]}
+    assert set(windows) >= {"roll_drain", "roll_dispatch", "report_render",
+                            "query_snapshot", "report_sink"}
+    assert len(set(windows.values())) == 1
+    roll = [d for d in _of(capture, "dispatch")
+            if d[1]["fn"] in ("roll", "sharded_merge")]
+    assert len(roll) == 1
+
+
+def test_no_session_no_sampling_leaves_no_trace_and_no_stage_seconds():
+    from netobserv_tpu.server import start_debug_server
+
+    tracing.configure(sample=0.0)
+    m = Metrics()
+    tracing.set_metrics(m)
+    exp = _resident_exporter(metrics=m)
+    fetcher, out, tracer = _served_path()
+    try:
+        _drain_and_export(fetcher, tracer, out, exp, 2)
+        exp.flush()
+        chunks = exp._ring.chunks
+    finally:
+        exp.close()
+    srv = start_debug_server("127.0.0.1:0")
+    try:
+        _, _, body = _get(srv, "/debug/traces")
+    finally:
+        srv.shutdown()
+    served = json.loads(body)
+    assert served["traces"] == [] and not served["sampling_enabled"]
+    text = generate_latest(m.registry).decode()
+    assert "ebpf_agent_stage_seconds_count" not in text
+    # the always-on pack timer, once per fold chunk
+    assert chunks >= 2
+    assert f"ebpf_agent_sketch_pack_seconds_count {float(chunks)}" in text
+
+
+def test_sampled_spans_carry_the_ids_their_annotations_do():
+    tracing.configure(sample=1.0, capacity=8)
+    t = tracing.start_trace("batch")
+    with t.bind(eviction=5).stage("evict"):
+        pass
+    with t.stage("fold", eviction=5):
+        with t.bind(evictions="4-5").stage("put", chunk=9, k=2, cont=0):
+            pass
+    t.finish()
+    stages = {s["stage"]: s.get("ids") for s in tracing.snapshot()[0]["stages"]}
+    assert stages == {"evict": {"eviction": 5}, "fold": {"eviction": 5},
+                      "put": {"evictions": "4-5", "chunk": 9, "k": 2,
+                              "cont": 0}}
