@@ -1,15 +1,33 @@
-"""Count-Min fold as a Pallas kernel: scatter-add -> tiled one-hot matmul.
+"""Count-Min fold as a Pallas kernel: scatter-add -> factored one-hot matmul.
 
-For each width tile of TILE_W counters, the kernel walks the batch in chunks,
-builds the one-hot membership matrix (chunk x TILE_W) in VMEM, and contracts
-it with the value vector on the MXU — so the per-batch cost is a dense
-d * B * W multiply-accumulate instead of B random HBM touches. FLOPs at the
-default config (d=4, B=8192, W=65536): ~4.3 GFLOP/batch, well under a chip's
-headroom at the target ingest rate.
+A column index is written idx = hi * LO + lo (LO = 256 lanes, HI = W / LO)
+and the counters are viewed as [planes, d, HI, LO], the row-major split of
+[planes, d, W]. For one depth row and one chunk of C records the scatter's
+sum is then ONE matmul on the MXU,
+
+    counts[p, r] += A_p . Bm^T     A_p[h, b] = (hi_b == h) * val_p[b]  [HI, C]
+                                   Bm[l, b]  = (lo_b == l)             [LO, C]
+
+of height planes * HI (the planes stacked, sharing one Bm), contraction C,
+width LO. Both operands are built with the record axis along the lanes, as
+the index and value rows arrive, so nothing is relaid out.
+
+Costs, as counts (d = 4, W = 65,536, an x4 fold's B = 33,792 rows, 34,816
+after padding to CHUNK_B): the VPU builds d * B * (2*HI + LO) = 1.1e8
+membership cells (one compare a cell of A and of Bm, one select a plane and
+part); the MXU makes 2 * d * B * W = 1.8e10 multiply-accumulates in bf16
+passes — three, because the value row is split by hand into three bf16-exact
+parts (an f32 has 24 significant bits, a bf16 eight) and the 0/1 of a one-hot
+is exact in bf16, so every product is exact and the f32 accumulation adds
+whole values: integer sums below 2^24 equal the scatter twin's bit for bit
+(`chip_smoke.py`'s pallas_vs_scatter leg); HBM moves the planes once each way.
+A plain one-hot over the whole width would build d * B * W = 9.1e9 cells for
+the same sums. The MACs grow with W, so at widths of 2^20 and more, where HI
+is tiled over the grid and every tile walks the whole batch, the XLA scatter
+is the cheaper form (docs/tpu_sketch.md).
 
 The counters are donated (input_output_aliases) so the fold is in-place in
-HBM. Falls back transparently: callers use `countmin.update` unless
-SKETCH_USE_PALLAS is set.
+HBM. Callers use `countmin.update` unless `state.ingest`'s gate picks this.
 """
 
 from __future__ import annotations
@@ -24,9 +42,14 @@ from netobserv_tpu.ops import hashing
 from netobserv_tpu.ops.countmin import CountMin
 from netobserv_tpu.ops.pallas import tier_tiles
 
+#: the low digit of a column index: one counter row of the [HI, LO] view
+LO = 256
+LO_BITS = LO.bit_length() - 1
+#: a counters block [planes, d, HI tile, LO] stays at or under this in VMEM
+BLOCK_BYTES = 2 << 20
 TILE_W = 512
 CHUNK_B = 1024
-#: contraction precision of every one-hot matmul in ops/pallas. The MXU's
+#: contraction precision of the f32 one-hot matmuls in ops/pallas. The MXU's
 #: default for f32 operands is ONE bf16 pass, which rounds each value to 8
 #: mantissa bits before it is added (seen on the v5e, PR 21: byte sums off by
 #: up to 159 per counter, in both directions — a Count-Min that can
@@ -36,97 +59,98 @@ CHUNK_B = 1024
 EXACT = jax.lax.Precision.HIGHEST
 
 
-def _fold_kernel(counts_ref, idx_ref, vals_ref, out_ref, *, depth: int,
-                 n_chunks: int):
-    j = pl.program_id(0)
-    base = j * TILE_W
-    lanes = base + jax.lax.broadcasted_iota(jnp.int32, (1, TILE_W), 1)
+def _bf16_parts(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """f32 `x` as three f32 arrays, each exact in bf16, that sum to `x`."""
+    def head(v):
+        return v.astype(jnp.bfloat16).astype(jnp.float32)
+    hi = head(x)
+    mid = head(x - hi)
+    return hi, mid, x - hi - mid
 
-    def chunk_body(i, acc):
-        sl = pl.dslice(i * CHUNK_B, CHUNK_B)
-        vals = vals_ref[:, sl]                       # [1, CHUNK_B]
-        new_rows = []
+
+def _fold_kernel(counts_ref, idx_ref, vals_ref, out_ref, *, n_chunks: int):
+    """The factored walk, for any number of planes: counts/out
+    [P, d, HI tile, LO] f32, idx [d, B] i32, vals [P, B] f32. A grid step
+    owns one HI tile and walks the whole batch; rows whose hi digit lies in
+    another tile match no row of A and add nothing."""
+    planes, depth, ht, _ = out_ref.shape
+    hi_rows = (pl.program_id(0) * ht
+               + jax.lax.broadcasted_iota(jnp.int32, (ht, CHUNK_B), 0))
+    lo_rows = jax.lax.broadcasted_iota(jnp.int32, (LO, CHUNK_B), 0)
+    out_ref[...] = counts_ref[...]
+
+    def chunk_body(i, carry):
+        sl = pl.ds(pl.multiple_of(i * CHUNK_B, CHUNK_B), CHUNK_B)
+        parts = _bf16_parts(vals_ref[:, sl])             # 3 x [P, CHUNK_B]
         for r in range(depth):  # static unroll over sketch depth
-            idx = idx_ref[r, sl].reshape(CHUNK_B, 1)
-            onehot = (idx == lanes).astype(jnp.float32)  # [CHUNK_B, TILE_W]
-            contrib = jnp.dot(vals, onehot, precision=EXACT,
-                              preferred_element_type=jnp.float32)
-            new_rows.append(acc[r] + contrib[0])
-        return jnp.stack(new_rows)
+            idx = idx_ref[pl.ds(r, 1), sl]               # [1, CHUNK_B]
+            member = (idx >> LO_BITS) == hi_rows         # [HT, CHUNK_B]
+            bm = ((idx & (LO - 1)) == lo_rows).astype(jnp.bfloat16)
+            contrib = 0.0
+            for part in parts:
+                a = jnp.concatenate(
+                    [jnp.where(member, part[p:p + 1], 0.0)
+                     for p in range(planes)]).astype(jnp.bfloat16)
+                contrib += jax.lax.dot_general(          # A . Bm^T
+                    a, bm, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [P*HT, LO]
+            for p in range(planes):
+                out_ref[p, r] += contrib[p * ht:(p + 1) * ht]
+        return carry
 
-    acc = counts_ref[...]
-    acc = jax.lax.fori_loop(0, n_chunks, chunk_body, acc)
-    out_ref[...] = acc
+    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
 
 
-def _fold2_kernel(counts_ref, idx_ref, vals_ref, out_ref, *, depth: int,
-                  n_chunks: int):
-    """Fused dual-plane fold: the one-hot membership matrix is built ONCE
-    per (depth row, chunk) and contracted with BOTH value planes stacked as
-    a (2, CHUNK_B) LHS — halving the dominant VPU compare cost vs two
-    single-plane passes and doubling MXU row utilization."""
-    j = pl.program_id(0)
-    base = j * TILE_W
-    lanes = base + jax.lax.broadcasted_iota(jnp.int32, (1, TILE_W), 1)
-
-    def chunk_body(i, acc):
-        sl = pl.dslice(i * CHUNK_B, CHUNK_B)
-        vals = vals_ref[:, sl]                       # [2, CHUNK_B]
-        new_rows = []
-        for r in range(depth):  # static unroll over sketch depth
-            idx = idx_ref[r, sl].reshape(CHUNK_B, 1)
-            onehot = (idx == lanes).astype(jnp.float32)  # [CHUNK_B, TILE_W]
-            contrib = jnp.dot(vals, onehot, precision=EXACT,
-                              preferred_element_type=jnp.float32)  # [2, W]
-            new_rows.append(acc[:, r] + contrib)
-        return jnp.stack(new_rows, axis=1)           # [2, d, TILE_W]
-
-    acc = counts_ref[...]
-    acc = jax.lax.fori_loop(0, n_chunks, chunk_body, acc)
-    out_ref[...] = acc
+def _fold(planes: tuple[jax.Array, ...], h1: jax.Array, h2: jax.Array,
+          values: tuple[jax.Array, ...], valid: jax.Array, name: str,
+          interpret: bool | None) -> jax.Array:
+    """`planes` ([d, W] each) stacked to [P, d, W] f32, plus the scatter-add
+    of each plane's `values` row ([B], masked by `valid`) at
+    hashing.row_indices(h1, h2)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n, (d, w) = len(planes), planes[0].shape
+    assert w % TILE_W == 0, f"width {w} must be a multiple of {TILE_W}"
+    pad = (-h1.shape[0]) % CHUNK_B
+    if pad:  # padding rows carry h2 = 1 and value 0, like invalid ones
+        h1 = jnp.pad(h1, (0, pad))
+        h2 = jnp.pad(h2, (0, pad), constant_values=1)
+        valid = jnp.pad(valid, (0, pad))
+        values = tuple(jnp.pad(v, (0, pad)) for v in values)
+    idx = hashing.row_indices(h1, h2, d, w).astype(jnp.int32)  # [d, B]
+    vals = jnp.stack([jnp.where(valid, v, 0).astype(jnp.float32)
+                      for v in values])                        # [P, B]
+    bp = idx.shape[1]
+    hi = w // LO
+    fit = max(8, BLOCK_BYTES // (n * d * LO * 4))
+    ht = min(hi, 1 << (fit.bit_length() - 1))  # a power of two, as hi is
+    block = pl.BlockSpec((n, d, ht, LO), lambda j: (0, 0, j, 0))
+    new_counts = pl.pallas_call(
+        functools.partial(_fold_kernel, n_chunks=bp // CHUNK_B),
+        grid=(hi // ht,),
+        in_specs=[
+            block,
+            pl.BlockSpec((d, bp), lambda j: (0, 0)),   # all indices
+            pl.BlockSpec((n, bp), lambda j: (0, 0)),   # all values
+        ],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((n, d, hi, LO), jnp.float32),
+        input_output_aliases={0: 0},
+        name=name,
+        interpret=interpret,
+    )(jnp.stack([c.astype(jnp.float32) for c in planes]).reshape(
+        n, d, hi, LO), idx, vals)
+    return new_counts.reshape(n, d, w)
 
 
 def update_two(cm_a: CountMin, cm_b: CountMin, h1: jax.Array, h2: jax.Array,
                vals_a: jax.Array, vals_b: jax.Array, valid: jax.Array,
                interpret: bool | None = None) -> tuple[CountMin, CountMin]:
     """Fused drop-in for countmin.update_two: both planes (bytes, packets)
-    fold in ONE kernel sharing hash indices and one-hot construction."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    d, w = cm_a.counts.shape
-    assert cm_b.counts.shape == (d, w)
-    assert w % TILE_W == 0, f"width {w} must be a multiple of {TILE_W}"
-    b = h1.shape[0]
-    pad = (-b) % CHUNK_B
-    if pad:
-        h1 = jnp.pad(h1, (0, pad))
-        h2 = jnp.pad(h2, (0, pad), constant_values=1)
-        vals_a = jnp.pad(vals_a, (0, pad))
-        vals_b = jnp.pad(vals_b, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
-    idx = hashing.row_indices(h1, h2, d, w).astype(jnp.int32)  # [d, B]
-    vals = jnp.stack([
-        jnp.where(valid, vals_a, 0).astype(jnp.float32),
-        jnp.where(valid, vals_b, 0).astype(jnp.float32)])      # [2, B]
-    stacked = jnp.stack([cm_a.counts.astype(jnp.float32),
-                         cm_b.counts.astype(jnp.float32)])     # [2, d, w]
-    n_chunks = idx.shape[1] // CHUNK_B
-
-    kernel = functools.partial(_fold2_kernel, depth=d, n_chunks=n_chunks)
-    new_counts = pl.pallas_call(
-        kernel,
-        grid=(w // TILE_W,),
-        in_specs=[
-            pl.BlockSpec((2, d, TILE_W), lambda j: (0, 0, j)),
-            pl.BlockSpec((d, idx.shape[1]), lambda j: (0, 0)),
-            pl.BlockSpec((2, idx.shape[1]), lambda j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, d, TILE_W), lambda j: (0, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((2, d, w), jnp.float32),
-        input_output_aliases={0: 0},
-        name="countmin_update_two",
-        interpret=interpret,
-    )(stacked, idx, vals)
+    fold in ONE kernel sharing hash indices and the lo one-hot."""
+    assert cm_b.counts.shape == cm_a.counts.shape
+    new_counts = _fold((cm_a.counts, cm_b.counts), h1, h2, (vals_a, vals_b),
+                       valid, "countmin_update_two", interpret)
     return (CountMin(counts=new_counts[0].astype(cm_a.counts.dtype)),
             CountMin(counts=new_counts[1].astype(cm_b.counts.dtype)))
 
@@ -136,7 +160,7 @@ def _tier2_kernel(base_ref, mid_ref, top_ref, idx_ref, vals_ref,
                   n_chunks: int, mid_group: int, top_group: int,
                   units: tuple[int, int]):
     """Tier-interior dual-plane fold: decode the narrow tier tiles to a
-    wide f32 view IN VMEM, run the exact `_fold2_kernel` chunk walk on it,
+    wide f32 view IN VMEM, run a width-tiled one-hot chunk walk on it,
     then promote the per-fold delta back into the tiers — the wide array
     never exists in HBM. A second walk gathers the post-fold bytes-plane
     estimate per record (q_out accumulates across width tiles; each index
@@ -159,7 +183,7 @@ def _tier2_kernel(base_ref, mid_ref, top_ref, idx_ref, vals_ref,
                                units[p])
         for p in range(2)])                    # [2, d, T] f32 wide view
 
-    def chunk_body(i, acc):  # _fold2_kernel's walk, acc seeded from dec
+    def chunk_body(i, acc):  # acc seeded from dec
         sl = pl.dslice(i * CHUNK_B, CHUNK_B)
         vals = vals_ref[:, sl]                       # [2, CHUNK_B]
         new_rows = []
@@ -285,38 +309,10 @@ def update_two_tiered(plane_a, plane_b, h1: jax.Array, h2: jax.Array,
 
 def update(cm: CountMin, h1: jax.Array, h2: jax.Array, values: jax.Array,
            valid: jax.Array, interpret: bool | None = None) -> CountMin:
-    """Drop-in replacement for countmin.update (float32 sketches).
+    """Drop-in replacement for countmin.update (float32 sketches): the
+    one-plane case of :func:`update_two`'s walk.
 
     `interpret` defaults to True off-TPU so the kernel is testable on the
     CPU mesh; on TPU it compiles through Mosaic."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    d, w = cm.counts.shape
-    assert w % TILE_W == 0, f"width {w} must be a multiple of {TILE_W}"
-    b = h1.shape[0]
-    pad = (-b) % CHUNK_B
-    if pad:
-        h1 = jnp.pad(h1, (0, pad))
-        h2 = jnp.pad(h2, (0, pad), constant_values=1)
-        values = jnp.pad(values, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
-    idx = hashing.row_indices(h1, h2, d, w).astype(jnp.int32)  # [d, B]
-    vals = jnp.where(valid, values, 0).astype(jnp.float32)
-    n_chunks = idx.shape[1] // CHUNK_B
-
-    kernel = functools.partial(_fold_kernel, depth=d, n_chunks=n_chunks)
-    new_counts = pl.pallas_call(
-        kernel,
-        grid=(w // TILE_W,),
-        in_specs=[
-            pl.BlockSpec((d, TILE_W), lambda j: (0, j)),   # counts tile
-            pl.BlockSpec((d, idx.shape[1]), lambda j: (0, 0)),  # all indices
-            pl.BlockSpec((1, idx.shape[1]), lambda j: (0, 0)),  # all values
-        ],
-        out_specs=pl.BlockSpec((d, TILE_W), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((d, w), jnp.float32),
-        input_output_aliases={0: 0},
-        name="countmin_update",
-        interpret=interpret,
-    )(cm.counts.astype(jnp.float32), idx, vals.reshape(1, -1))
-    return CountMin(counts=new_counts)
+    return CountMin(counts=_fold((cm.counts,), h1, h2, (values,), valid,
+                                 "countmin_update", interpret)[0])

@@ -1,9 +1,13 @@
 """Pallas kernel equivalence vs the XLA scatter implementation (interpret mode
 on the CPU mesh; the same kernel compiles through Mosaic on TPU)."""
 
+from unittest import mock
+
 import numpy as np
+import pytest
 
 import tests.conftest  # noqa: F401
+import jax
 import jax.numpy as jnp
 
 from netobserv_tpu.ops import countmin, hashing
@@ -38,6 +42,102 @@ def test_pallas_countmin_accumulates_across_calls():
         cm = countmin_kernel.update(cm, h1, h2, vals, valid, interpret=True)
     est = countmin.query(cm, h1, h2)
     assert float(jnp.min(est)) >= 3.0
+
+
+def _cm_inputs(seed, b, *, vmax=1500, valid_share=1.0, one_column=False):
+    """Hashes, two integer-valued value rows and a validity mask for `b`
+    records; `one_column` gives every record the same key, so every row of a
+    chunk lands on one counter per depth row."""
+    rng = np.random.default_rng(seed)
+    words = rng.integers(0, 2**32, (1 if one_column else b, KW),
+                         dtype=np.uint32)
+    words = jnp.asarray(np.broadcast_to(words, (b, KW)))
+    h1, h2 = hashing.base_hashes(words)
+    va = jnp.asarray(rng.integers(0, vmax + 1, b).astype(np.float32))
+    vb = jnp.asarray(rng.integers(0, 12, b).astype(np.float32))
+    valid = jnp.asarray(rng.random(b) < valid_share)
+    return h1, h2, va, vb, valid
+
+
+def _cm_folds(planes, width, inputs, *, calls=1, depth=4):
+    """(kernel counts, scatter counts), each a list of `planes` arrays, after
+    `calls` successive folds of `inputs`; the kernel folds are jitted with
+    the planes donated, as the ingest executables hold them."""
+    h1, h2, va, vb, valid = inputs
+    if planes == 2:
+        def kern(a, b):
+            return countmin_kernel.update_two(a, b, h1, h2, va, vb, valid,
+                                              interpret=True)
+
+        def scat(a, b):
+            return countmin.update_two(a, b, h1, h2, va, vb, valid)
+    else:
+        def kern(a):
+            return (countmin_kernel.update(a, h1, h2, va, valid,
+                                           interpret=True),)
+
+        def scat(a):
+            return (countmin.update(a, h1, h2, va, valid),)
+    kern = jax.jit(kern, donate_argnums=tuple(range(planes)))
+    got, want = (tuple(countmin.init(depth, width) for _ in range(planes))
+                 for _ in range(2))
+    for _ in range(calls):
+        got, want = kern(*got), scat(*want)
+    return ([np.asarray(c.counts) for c in got],
+            [np.asarray(c.counts) for c in want])
+
+
+#: width, records, _cm_inputs' keywords, _cm_folds' keywords
+CM_CASES = {
+    "w16k-x4-batch": (16384, 33792, {}, {}),
+    "w64k-x4-batch-invalid-rows": (65536, 33792, dict(valid_share=0.9), {}),
+    "w64k-ragged-batch": (65536, 3000, dict(valid_share=0.97), {}),
+    "w64k-values-to-2^20": (65536, 2048, dict(vmax=1 << 20), {}),
+    "w16k-every-row-one-column": (16384, 2048,
+                                  dict(vmax=4000, one_column=True), {}),
+    "w256k-several-hi-tiles": (1 << 18, 1500, dict(valid_share=0.9), {}),
+    "w16k-two-calls-donated": (16384, 3000, {}, dict(calls=2)),
+    "w64k-depth-3": (65536, 1024, {}, dict(depth=3)),
+}
+
+
+@pytest.mark.parametrize("planes", [2, 1], ids=["update_two", "update"])
+@pytest.mark.parametrize("case", CM_CASES)
+def test_pallas_countmin_equals_scatter(case, planes):
+    """The factored contraction adds whole values: integer sums below 2^24
+    come out EQUAL to the scatter twin's, whatever the width (one HI tile or
+    several), the batch (whole chunks or ragged), the duplicates in a chunk
+    or the size of a value."""
+    width, b, input_kw, fold_kw = CM_CASES[case]
+    got, want = _cm_folds(planes, width, _cm_inputs(31, b, **input_kw),
+                          **fold_kw)
+    assert max(float(w.max()) for w in want) < 2 ** 24
+    assert any(w.any() for w in want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_pallas_countmin_tiles_hi_when_the_planes_outgrow_a_block():
+    """What `several-hi-tiles` rests on: past BLOCK_BYTES the grid has more
+    than one step, each a whole power-of-two share of HI."""
+    seen = []
+    real = countmin_kernel.pl.pallas_call
+
+    def spy(kernel, *, grid, **kw):
+        seen.append((grid, kw["in_specs"][0].block_shape))
+        return real(kernel, grid=grid, **kw)
+
+    h1, h2, va, vb, valid = _cm_inputs(32, 1024)
+    with mock.patch.object(countmin_kernel.pl, "pallas_call", spy):
+        for width in (65536, 1 << 18):
+            countmin_kernel.update_two(
+                countmin.init(4, width), countmin.init(4, width), h1, h2,
+                va, vb, valid, interpret=True)
+        countmin_kernel.update(countmin.init(4, 1 << 18), h1, h2, va, valid,
+                               interpret=True)
+    lo = countmin_kernel.LO
+    assert seen == [((1,), (2, 4, 256, lo)), ((4,), (2, 4, 256, lo)),
+                    ((2,), (1, 4, 512, lo))]
 
 
 def test_pallas_hll_matches_xla_scatter():
