@@ -15,6 +15,7 @@ default — feed it to Kafka/gRPC by passing a different sink).
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import logging
 import sys
@@ -487,10 +488,6 @@ class TpuSketchExporter(Exporter):
         #: first sampled eviction's trace is finished by the fold that
         #: consumes its rows
         self._pending_trace = None
-        #: eviction sequence numbers (EvictedFlows.eviction) of the rows in
-        #: the pending buffer, oldest and newest: a fold chunk's
-        #: `evictions=<first>-<last>` in a profiler capture
-        self._evictions_first = self._evictions_last = 0
         #: windows closed by this exporter (`window=<n>` on their stages)
         self._windows_closed = 0
         # resident pack LANES cost per-lane device key tables and only pay
@@ -730,6 +727,11 @@ class TpuSketchExporter(Exporter):
         self._pending_buf = staging.PendingEventBuffer(
             self._batch_size, getattr(self._ring, "superbatch_max", 1),
             metrics=metrics)
+        #: the resident ladder ring can hand back the rows a chunk's regions
+        #: could not take; the pending buffer keeps them for the next chunk
+        #: (the other rings consume every row they are offered)
+        self._carry_ring = isinstance(
+            self._ring, staging.ShardedResidentStagingRing)
         # overload control plane (sketch/overload.py): admission control at
         # the export_evicted seam. Disabled (the default), _overload is None
         # and the shed path is one is-None check — bit-identical to the
@@ -1295,7 +1297,8 @@ class TpuSketchExporter(Exporter):
             packed = getattr(evicted, "packed", None)
             if packed is not None:
                 # fused-pipeline arena riding the eviction: ship it in
-                # place of the raw arrays (bit-exact the same fold —
+                # place of the raw arrays (the same rows, packed by
+                # flowpack.cc's own continuation schedule —
                 # tests/test_native_pipeline.py); a stale epoch falls
                 # through to the raw path below
                 evicted.packed = None
@@ -1329,12 +1332,6 @@ class TpuSketchExporter(Exporter):
                     self._pending_trace = trace  # the next fold finishes it
                 else:
                     trace.finish()  # rare: two sampled evictions in one fold
-            # the fold chunks name the evictions whose rows they carry: the
-            # pending buffer holds a sub-batch tail of the eviction before
-            # (if any) and now this one's rows
-            if not self._pending_buf.n:
-                self._evictions_first = seq
-            self._evictions_last = seq
             self._pending_buf.append(evicted, self._fold_events)
             if time.monotonic() >= self._window_deadline:
                 self._close_window_locked()
@@ -1382,9 +1379,18 @@ class TpuSketchExporter(Exporter):
                 return  # close() (or the supervisor) owns the leftovers
             time.sleep(0.005)
 
-    def _fold_events(self, events, feats) -> None:
+    def _fold_events(self, events, feats, finish: bool = False):
+        """The pending buffer's fold callback. On the resident ladder ring
+        a steady fold dispatches each chunk once and returns the row ranges
+        its regions left, which the buffer keeps for the next fold
+        (`ShardedResidentStagingRing.fold(carry=True)`); with `finish` —
+        whatever must end with an empty buffer: roll, flush, close — every
+        row is consumed on return. Rows are counted where they are
+        consumed."""
         t0 = time.perf_counter()
         n = len(events)
+        carry = self._carry_ring and not finish
+        left = None
         # batch trace continuity: the sampled eviction trace riding the
         # pending buffer (or a fold-local sample when none) — the gap from
         # its evict span to this fold span IS the export queue wait
@@ -1392,10 +1398,10 @@ class TpuSketchExporter(Exporter):
         self._pending_trace = None
         if trace is None:
             trace = tracing.start_trace("fold")
-        first, last = self._evictions_first, self._evictions_last
-        # every fold takes a batch-aligned prefix that holds all of the
-        # older tail: what stays buffered is the newest eviction's alone
-        self._evictions_first = last
+        # the fold chunks name the evictions whose rows they carry: the
+        # buffer knows them by row (an older eviction's left rows and tail
+        # ride at the front of a later eviction's fold)
+        first, last = self._pending_buf.evictions
         try:
             with trace.stage("fold", eviction=last):
                 faultinject.fire("sketch.ingest")
@@ -1406,9 +1412,15 @@ class TpuSketchExporter(Exporter):
                     # yet shipped) must not ship afterwards — no-op when
                     # none are outstanding (staging.ResidentPackSurface)
                     self._pack_surface.invalidate_for_raw_fold()
-                self._state = self._ring.fold(
-                    self._state, events,
-                    trace=trace.bind(evictions=f"{first}-{last}"), **feats)
+                chunks = trace.bind(evictions=f"{first}-{last}")
+                if carry:
+                    self._state, left = self._ring.fold(
+                        self._state, events, trace=chunks, carry=True,
+                        **feats)
+                    n -= sum(hi - lo for lo, hi in left)
+                else:
+                    self._state = self._ring.fold(
+                        self._state, events, trace=chunks, **feats)
         except staging.StagingWedged as exc:
             # the slot-wait budget tripped at a chunk boundary: the rows
             # not yet packed drop (no dictionary slot was committed for
@@ -1425,12 +1437,12 @@ class TpuSketchExporter(Exporter):
             if self._metrics is not None:
                 self._metrics.sketch_ingest_errors_total.inc()
                 self._metrics.count_error("tpu-sketch-ingest")
-            return
+            return None
         except Exception as exc:
             # graceful degradation: a device error loses THIS batch (counted)
             # instead of poisoning the exporter thread / window timer
             self._count_ingest_error(n, exc)
-            return
+            return None
         finally:
             trace.finish()
             if self._overload is not None:
@@ -1442,6 +1454,7 @@ class TpuSketchExporter(Exporter):
             self._metrics.sketch_records_total.inc(n)
             self._metrics.sketch_ingest_seconds.observe(
                 time.perf_counter() - t0)
+        return left
 
     def _count_ingest_error(self, n: int, exc: Exception) -> None:
         log.error("sketch ingest failed (batch of %d dropped): %s", n, exc)
@@ -1475,7 +1488,8 @@ class TpuSketchExporter(Exporter):
         if self._pending:
             self._fold(self._pending)
             self._pending = []
-        self._pending_buf.flush_to(self._fold_events)
+        self._pending_buf.flush_to(
+            functools.partial(self._fold_events, finish=True))
         if self._tenancy is not None:
             # ship any partially-filled tenant buffers as one last stacked
             # fold — a roll (or refresh) must never strand routed rows

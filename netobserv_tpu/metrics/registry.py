@@ -164,7 +164,16 @@ class Metrics:
             p + "sketch_resident_continuations_total",
             "Extra resident-feed chunks shipped because a side lane filled "
             "(sustained high rates mean the caps are undersized for this "
-            "traffic mix)", registry=self.registry)
+            "traffic mix). The exporter's steady folds carry such rows "
+            "instead (sketch_resident_carried_rows_total); this counts the "
+            "chunks of folds that must finish: roll, flush, close",
+            registry=self.registry)
+        self.sketch_resident_carried_rows_total = Counter(
+            p + "sketch_resident_carried_rows_total",
+            "Rows a resident chunk could not take because a region's side "
+            "lane filled, kept in the pending buffer to ride the next chunk "
+            "instead of a continuation chunk of their own",
+            registry=self.registry)
         self.sketch_resident_dict_epochs_total = Counter(
             p + "sketch_resident_dict_epochs_total",
             "Resident key-dictionary epoch rolls (dictionary reached "

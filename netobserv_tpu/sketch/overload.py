@@ -152,7 +152,8 @@ class OverloadController:
         into each surviving row's ``sampling`` field (0 = unsampled counts
         as 1, matching the device de-bias; kernel sampling composes
         multiplicatively). Returns ``evicted`` untouched at factor 1;
-        otherwise a thinned EvictedFlows carrying the same trace."""
+        otherwise a thinned EvictedFlows carrying the same trace and
+        eviction id."""
         if self.shed == 1:
             return evicted
         n = len(evicted.events)
@@ -183,6 +184,7 @@ class OverloadController:
             feats[name] = col[keep[:len(col)]]
         thinned = EvictedFlows(events, **feats)
         thinned.decode_stats = evicted.decode_stats
+        thinned.eviction = getattr(evicted, "eviction", 0)
         trace = getattr(evicted, "trace", None)
         if trace is not None:
             thinned.trace = trace

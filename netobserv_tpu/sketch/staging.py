@@ -64,6 +64,22 @@ def pick_lanes(per_unit: int, want: int) -> int:
     return lanes
 
 
+def _raw(a: np.ndarray) -> np.ndarray:
+    """`a`'s rows as opaque bytes. numpy assigns a structured array field
+    by field — a 144-byte event row costs 12 times a memmove, an
+    overlapping slide 50 times — and a void view of the same rows as one
+    block."""
+    return a.view(f"V{a.dtype.itemsize}")
+
+
+def _copy_records(dst: np.ndarray, src: np.ndarray) -> None:
+    """dst[:] = src, as one block copy where the two share a dtype."""
+    if src.dtype == dst.dtype:
+        _raw(dst)[:] = _raw(src)
+    else:
+        dst[:] = src
+
+
 class PendingEventBuffer:
     """Preallocated rolling accumulator for queued evictions — the
     zero-concat fold path. The exporter used to `np.concatenate` every
@@ -83,6 +99,17 @@ class PendingEventBuffer:
     measured to CONCENTRATE slot waits into multi-second export stalls on
     a device slower than the feed — tests/test_roll_nonblocking.py).
 
+    Between folds the buffer holds, in arrival order, the rows that
+    earlier folds LEFT ahead of the newest eviction's sub-batch tail. A
+    fold callback may return the row ranges it did not consume (the
+    resident ring's `fold(carry=True)`: each region's suffix behind a full
+    side lane); those rows keep their feature-lane rows and ride the next
+    fold that is dispatched anyway. `append` folds again while what is
+    held makes a batch, so less than one batch waits for the next
+    eviction, left rows included. A callback that returns None consumed
+    everything, so `flush_to` with such a callback (the exporter's roll /
+    flush / close) leaves the buffer empty.
+
     Feature-lane semantics match the old `_concat_feature`: a lane is
     passed to the fold iff ANY eviction in the current batch carried it,
     with zeroed rows standing in for evictions that lacked it (`_live`
@@ -93,11 +120,11 @@ class PendingEventBuffer:
     eviction plane always builds them that way — `decode_eviction`), its
     batch-aligned PREFIX folds straight from zero-copy VIEWS of the
     eviction's own arrays — the resident pack lanes read the drain-decode
-    output directly, skipping this buffer's copy entirely; only the
-    sub-batch tail is copied in. Fold semantics are identical (the gate
-    guarantees the zero-pad contract is moot for aligned lanes), pinned by
-    tests/test_staging_direct.py. `direct_rows` counts the bypassing rows
-    (`sketch_direct_fold_rows_total`)."""
+    output directly, skipping this buffer's copy entirely; only the rows
+    those folds left and the sub-batch tail are copied in. Fold semantics
+    are identical (the gate guarantees the zero-pad contract is moot for
+    aligned lanes), pinned by tests/test_staging_direct.py. `direct_rows`
+    counts the bypassing rows (`sketch_direct_fold_rows_total`)."""
 
     LANES = (("extra", binfmt.EXTRA_REC_DTYPE),
              ("dns", binfmt.DNS_REC_DTYPE),
@@ -114,6 +141,13 @@ class PendingEventBuffer:
         self._lanes = {name: np.zeros(self.capacity, dt)
                        for name, dt in self.LANES}
         self._live = {name: False for name, _ in self.LANES}
+        #: per buffered row, the sequence number of the eviction it came
+        #: with (`EvictedFlows.eviction`) — nondecreasing, the rows being
+        #: in arrival order
+        self._seq = np.zeros(self.capacity, np.int64)
+        #: (oldest, newest) eviction among the rows of the fold on offer:
+        #: set before every fold callback, for the ids on its stages
+        self.evictions = (0, 0)
         self._metrics = metrics
         #: rows folded directly from eviction views (no buffer copy)
         self.direct_rows = 0
@@ -137,15 +171,19 @@ class PendingEventBuffer:
         buffered — as one coalesced batch-aligned prefix (the ladder ring
         dispatches it as a single superbatch), keeping any sub-batch tail
         buffered for the next eviction. The fold must consume its views
-        before returning (both ring pack paths copy synchronously).
+        before returning (both ring pack paths copy synchronously); it
+        returns None, or the ascending `(lo, hi)` row ranges of `events`
+        that it left, which stay buffered ahead of the tail.
 
         An eviction meeting the direct-to-lane gate (empty buffer,
         batch-aligned prefix, aligned lanes) folds that prefix zero-copy
         from its own arrays — in capacity-sized chunks, so a fold is
         never LARGER than the copy path could have produced (the dense/
         compact rings do not chunk internally; only the resident ladder
-        ring does) — and the sub-batch tail takes the copy path below."""
+        ring does) — and the rows those folds left and the sub-batch tail
+        take the copy path below."""
         ev = evicted.events
+        seq = getattr(evicted, "eviction", 0)
         off = 0
         if self.n == 0 and len(ev) >= self.batch_size \
                 and self._lanes_aligned(evicted, len(ev)):
@@ -157,30 +195,44 @@ class PendingEventBuffer:
                     col = getattr(evicted, name, None)
                     feats[name] = (col[off:off + take]
                                    if col is not None and len(col) else None)
+                self.evictions = (seq, seq)
                 try:
-                    fold(ev[off:off + take], feats)
+                    left = fold(ev[off:off + take], feats) or ()
                 except BaseException:
                     # a raising fold drops ITS chunk (counted upstream)
                     # like _fold_prefix — the rest still buffers, and the
                     # dropped rows never count as routed-direct
                     self._copy_in(evicted, off + take, fold)
                     raise
+                direct = take
+                for lo, hi in left:
+                    self._copy_rows(evicted, off + lo, off + hi, fold)
+                    direct -= hi - lo
                 off += take
-                self.direct_rows += take
+                self.direct_rows += direct
                 if self._metrics is not None:
-                    self._metrics.sketch_direct_fold_rows_total.inc(take)
-            if off == len(ev):
-                return
+                    self._metrics.sketch_direct_fold_rows_total.inc(direct)
         self._copy_in(evicted, off, fold)
 
     def _copy_in(self, evicted, off: int, fold: Callable) -> None:
         """The copy path: buffer `evicted`'s rows from `off` on, folding
-        full batches as they fill."""
+        full batches as they fill — again while what the folds left still
+        makes a batch, so that less than one batch waits for the next
+        eviction, as before rows could be left."""
+        self._copy_rows(evicted, off, len(evicted.events), fold)
+        while self.n >= self.batch_size:
+            self._fold_prefix(fold, self.n - self.n % self.batch_size)
+
+    def _copy_rows(self, evicted, off: int, end: int, fold: Callable) -> None:
+        """Buffer `evicted`'s rows [off, end) behind what is held, folding
+        the whole buffer whenever it fills."""
         ev = evicted.events
-        while off < len(ev):
-            take = min(len(ev) - off, self.capacity - self.n)
+        seq = getattr(evicted, "eviction", 0)
+        while off < end:
+            take = min(end - off, self.capacity - self.n)
             lo, hi = self.n, self.n + take
-            self.events[lo:hi] = ev[off:off + take]
+            _copy_records(self.events[lo:hi], ev[off:off + take])
+            self._seq[lo:hi] = seq
             for name, _ in self.LANES:
                 col = getattr(evicted, name, None)
                 lane = self._lanes[name]
@@ -189,7 +241,7 @@ class PendingEventBuffer:
                         lane[:lo] = 0  # earlier evictions lacked this lane
                         self._live[name] = True
                     c = col[off:off + take]
-                    lane[lo:lo + len(c)] = c
+                    _copy_records(lane[lo:lo + len(c)], c)
                     lane[lo + len(c):hi] = 0  # short lane: zero-pad its tail
                 elif self._live[name]:
                     lane[lo:hi] = 0
@@ -197,46 +249,46 @@ class PendingEventBuffer:
             off += take
             if self.n == self.capacity:
                 self.flush_to(fold)
-        full = self.n - self.n % self.batch_size
-        if full:
-            self._fold_prefix(fold, full)
 
     def flush_to(self, fold: Callable) -> None:
-        """Fold whatever is buffered (a partial batch pads downstream) and
-        reset; no-op when empty."""
-        if not self.n:
-            return
-        n = self.n
-        feats = {name: (self._lanes[name][:n] if self._live[name] else None)
-                 for name, _ in self.LANES}
-        # reset BEFORE folding: a fold that raises must not leave the rows
-        # queued for a re-fold (the exporter counts the batch as dropped)
-        self.n = 0
-        for name, _ in self.LANES:
-            self._live[name] = False
-        fold(self.events[:n], feats)
+        """Fold whatever is buffered (a partial batch pads downstream);
+        no-op when empty. What the fold leaves stays buffered; a fold that
+        raises must not leave its rows queued for a re-fold (the exporter
+        counts the batch as dropped)."""
+        if self.n:
+            self._fold_prefix(fold, self.n)
 
     def _fold_prefix(self, fold: Callable, rows: int) -> None:
-        """Fold the batch-aligned `rows` prefix and slide the sub-batch
-        tail to the front. The fold consumes its views synchronously, so
-        the tail move happens after it returns; a RAISING fold still drops
-        the prefix (counted upstream) and keeps the tail."""
+        """Fold the `rows` prefix and slide what it left, then the tail
+        behind it, to the front. The fold consumes its views synchronously,
+        so the rows move after it returns; a RAISING fold still drops the
+        prefix (counted upstream) and keeps the tail."""
         n = self.n
         feats = {name: (self._lanes[name][:rows] if self._live[name]
                         else None) for name, _ in self.LANES}
+        self.evictions = (int(self._seq[0]), int(self._seq[rows - 1]))
+        left = None
         try:
-            fold(self.events[:rows], feats)
+            left = fold(self.events[:rows], feats)
+            if left and sum(hi - lo for lo, hi in left) >= rows:
+                # the callers above fold until the rows fit: a fold that
+                # takes none would spin the export thread for ever
+                left = None
+                raise RuntimeError("fold consumed none of its rows")
         finally:
-            tail = n - rows
-            if tail:
-                self.events[:tail] = self.events[rows:n]
-                for name, _ in self.LANES:
-                    if self._live[name]:
-                        self._lanes[name][:tail] = self._lanes[name][rows:n]
-            else:
+            arrays = [_raw(self.events), self._seq] + [
+                _raw(self._lanes[name]) for name, _ in self.LANES
+                if self._live[name]]
+            kept = 0
+            for lo, hi in (*(left or ()), (rows, n)):
+                if lo != kept:
+                    for a in arrays:
+                        a[kept:kept + hi - lo] = a[lo:hi]
+                kept += hi - lo
+            if not kept:
                 for name, _ in self.LANES:
                     self._live[name] = False
-            self.n = tail
+            self.n = kept
 
 
 class _SlotRing:
@@ -522,6 +574,16 @@ class ShardedResidentStagingRing(_SlotRing):
     and per-(shard, ladder-position, lane) dictionaries, so a region's
     dictionary <-> device-table pairing is stable across ladder sizes.
 
+    Rows a chunk cannot take: a region stops packing where one of its side
+    lanes (new keys, spill, DNS, drops) fills. `fold()` ships what the
+    regions took and then either packs the regions' remainders into
+    CONTINUATION chunks of the same shape until none is left (the default:
+    every row consumed on return), or — `carry=True`, the exporter's steady
+    path — returns the remainders to the caller, whose `PendingEventBuffer`
+    offers them again at the front of the next fold. Either way every row
+    reaches a region's dictionary in stream order, so the schedule is a
+    pure function of the row stream (the multi-process rule above holds).
+
     `ingest`: `{k: (dist_state, key_tables, flat) -> (dist_state,
     key_tables, token)}` for every ladder entry (a bare callable means
     `{1: fn}`). `key_tables` must carry `superbatch_max * lanes` rows per
@@ -566,6 +628,9 @@ class ShardedResidentStagingRing(_SlotRing):
             raise ValueError(f"no ingest fn for ladder entries {missing}")
         self._put = put
         self.continuations = 0
+        #: rows a carry fold handed back to ride a later chunk (mirrors
+        #: sketch_resident_carried_rows_total)
+        self.carried_rows = 0
         self.dict_resets = 0
         self.spill_rows = 0
         #: dispatch counts by superbatch size (mirrors
@@ -593,19 +658,29 @@ class ShardedResidentStagingRing(_SlotRing):
         return sorted(self._available)
 
     def fold(self, state, events, extra=None, dns=None, drops=None,
-             xlat=None, quic=None, trace=None):
+             xlat=None, quic=None, trace=None, carry: bool = False):
         """Pack `events` (split over the regions, possibly in several
         chunks) into free ring slots, ship and ingest each; returns the new
         dist state (async — not blocked on). Row counts beyond one batch
-        dispatch as the largest fitting superbatch ladder entries."""
+        dispatch as the largest fitting superbatch ladder entries.
+
+        A region stops packing where one of its side lanes fills. By
+        default the rows behind that point fold in continuation chunks, so
+        every row is consumed on return. With `carry` each chunk is
+        dispatched ONCE and the return is `(state, left)`: the ascending
+        `(lo, hi)` row ranges of `events` that no region took, for a
+        caller that offers them again at the front of its next fold
+        (`PendingEventBuffer`) — a continuation chunk costs a full-shape
+        run for the few regions that stopped."""
         n = len(events)
         if n == 0:
-            return state
+            return (state, []) if carry else state
         trace, owned = self._fold_trace(trace)
         try:
             feats = dict(extra=extra, dns=dns, drops=drops, xlat=xlat,
                          quic=quic)
             start = 0
+            left = []
             while start < n:
                 remaining = n - start
                 k = max((x for x in self.ladder
@@ -616,17 +691,21 @@ class ShardedResidentStagingRing(_SlotRing):
                     name: (v[start:start + take]
                            if v is not None and len(v) else None)
                     for name, v in feats.items()}
-                state = self._fold_chunk(state, events[start:start + take],
-                                         chunk_feats, k, trace)
+                state, chunk_left = self._fold_chunk(
+                    state, events[start:start + take], chunk_feats, k, trace,
+                    carry)
+                left += [(start + lo, start + hi) for lo, hi in chunk_left]
                 start += take
-            return state
+            return (state, left) if carry else state
         finally:
             if owned:
                 trace.finish()
 
-    def _fold_chunk(self, state, events, feats, k: int, trace):
+    def _fold_chunk(self, state, events, feats, k: int, trace, carry: bool):
         """Pack and dispatch ONE k-superbatch chunk (<= k * batch_size rows)
-        through the k ladder entry."""
+        through the k ladder entry, again for what its regions could not
+        take until none is left — or, with `carry`, once. Returns the state
+        and the row ranges left (empty without `carry`)."""
         n = len(events)
         nr = self.n_shards * k * self.lanes
         kl = k * self.lanes
@@ -718,18 +797,32 @@ class ShardedResidentStagingRing(_SlotRing):
                 state, self.key_tables, token = self._ingests[k](
                     state, self.key_tables, dev)
             self._advance(slot, token)
-        return state
+            if carry:
+                break
+        left = [(bounds[i] + starts[i], bounds[i + 1]) for i in range(nr)
+                if starts[i] < len(shard_ev[i])]
+        if left:
+            rows = sum(hi - lo for lo, hi in left)
+            self.carried_rows += rows
+            if self._metrics is not None:
+                self._metrics.sketch_resident_carried_rows_total.inc(rows)
+        return state, left
 
     def fold_packed(self, state, packed, trace=None):
         """Ship PRE-PACKED resident regions (the fused native pipeline's
         arena — loader's fp_drain_to_resident ran the pack stage at drain
         time with this ring's own dictionaries). SCHEDULING ONLY: the arena
-        is bit-exact what _fold_chunk would have packed for the same rows
-        (tests/test_native_pipeline.py), so this path only replaces the
-        per-region python pack loop with one memcpy per segment; counters
-        and metrics advance exactly as _fold_chunk would have. The caller
-        (exporter) holds the ResidentPackSurface lock and has already
-        checked the pack epoch."""
+        holds the segments of `flowpack.cc`'s own schedule — every chunk
+        finished by continuation segments, as `fold()` without `carry`
+        packs the same rows (tests/test_native_pipeline.py pins it against
+        a replica of that schedule) — so this path only replaces the
+        per-region python pack loop with one memcpy per segment and counts
+        its segments as that loop counts its chunks. It is NOT the
+        exporter's steady schedule: there a chunk is dispatched once and
+        the rows it left ride the next (`fold(carry=True)`), so the two
+        consume the same rows in a different order. The caller (exporter)
+        holds the ResidentPackSurface lock and has already checked the
+        pack epoch."""
         trace, owned = self._fold_trace(trace)
         try:
             rw = self._region_words

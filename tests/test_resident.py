@@ -409,3 +409,108 @@ def test_zero_resident_region_masks_garbage_exactly():
     np.testing.assert_array_equal(np.asarray(t_g), np.asarray(t_z))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), s_g, s_z)
+
+
+def _lane_ring(lanes, caps=None, slot_cap=1 << 12):
+    import jax
+
+    from netobserv_tpu.sketch import state as sk
+    from netobserv_tpu.sketch.staging import ShardedResidentStagingRing
+
+    bpl = B // lanes
+    caps = caps or flowpack.default_resident_caps(bpl)
+    ring = ShardedResidentStagingRing(
+        B, 1, sk.make_ingest_resident_lanes_fn(bpl, caps, lanes),
+        key_tables=jax.device_put(sk.init_key_tables(lanes, slot_cap)),
+        put=jax.device_put, caps=caps, slot_cap=slot_cap,
+        pack_threads=lanes, lanes=lanes)
+    for buf in ring._bufs:
+        buf[:] = 0      # np.empty: make the slot images comparable
+    return ring
+
+
+@needs_jax
+def test_carry_fold_leaves_region_suffixes_and_loses_no_row():
+    """`fold(carry=True)` dispatches a chunk ONCE and hands back each
+    region's suffix behind its full lane; offered again (as the pending
+    buffer offers them, in arrival order ahead of newer rows) every row is
+    folded exactly once: the state equals the dense ingest's, and no
+    continuation chunk was shipped."""
+    import jax
+
+    from netobserv_tpu.sketch import state as sk
+
+    caps = flowpack.ResidentCaps(dns=8, drop=8, nk=64, spill=2)
+    feed = make_feed(n_batches=3, n_distinct=100)
+    for events, _ in feed:
+        events["stats"]["packets"][len(events) // 2:] = 0x900  # spill rows
+    ring = _lane_ring(2, caps=caps)
+    s = sk.init_state(sk.SketchConfig())
+    # the rows waiting for a fold, and their lane rows: what an earlier
+    # fold left, then the next eviction behind it
+    held_ev = np.zeros(0, binfmt.FLOW_EVENT_DTYPE)
+    held = {k: v[:0] for k, v in feed[0][1].items()}
+    arrivals = list(feed)
+    folds = 0
+    while arrivals or len(held_ev):
+        if arrivals:
+            events, feats = arrivals.pop(0)
+            held_ev = np.concatenate([held_ev, events])
+            held = {k: np.concatenate([held[k], feats[k]]) for k in held}
+        n = min(len(held_ev), B)
+        s, left = ring.fold(s, held_ev[:n], carry=True,
+                            **{k: v[:n] for k, v in held.items()})
+        folds += 1
+        assert folds < 400, "a carry fold consumes at least a row"
+        assert left == sorted(left)
+        assert all(0 <= lo < hi <= n for lo, hi in left)
+        rows = np.concatenate([np.arange(lo, hi) for lo, hi in left]
+                              + [np.arange(n, len(held_ev))]).astype(int)
+        held_ev = held_ev[rows]
+        held = {k: v[rows] for k, v in held.items()}
+    ring.drain()
+    assert ring.continuations == 0 and ring.carried_rows > 0
+    assert sum(ring.superbatch_folds.values()) == folds == ring.chunks
+
+    dense_fn = sk.make_ingest_dense_fn(with_token=True)
+    s_d = sk.init_state(sk.SketchConfig())
+    for events, feats in feed:
+        db = flowpack.pack_dense(events, batch_size=B, **feats)
+        s_d, _ = dense_fn(s_d, jax.device_put(db.reshape(-1)))
+    jax.block_until_ready(s_d)
+    _assert_exact_signals_match(s, s_d)
+    assert float(s.total_records) == sum(len(e) for e, _ in feed)
+
+
+@needs_jax
+def test_carry_fold_with_nothing_left_is_the_plain_fold():
+    """A chunk whose regions take all their rows: `carry` changes nothing —
+    the same slot images, the same dispatches, the same state and tables."""
+    import jax
+
+    from netobserv_tpu.sketch import state as sk
+
+    feed = make_feed(n_batches=5, n_distinct=250, v6_every=23)
+    rings, states = [], []
+    for carry in (False, True):
+        ring = _lane_ring(4)
+        s = sk.init_state(sk.SketchConfig())
+        for events, feats in feed:
+            if carry:
+                s, left = ring.fold(s, events, carry=True, **feats)
+                assert left == []
+            else:
+                s = ring.fold(s, events, **feats)
+        ring.drain()
+        rings.append(ring)
+        states.append(jax.block_until_ready(s))
+    plain, carried = rings
+    assert carried.carried_rows == 0 == carried.continuations
+    assert carried.superbatch_folds == plain.superbatch_folds
+    assert carried.chunks == plain.chunks == len(feed)
+    for a, b in zip(plain._bufs, carried._bufs):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(np.asarray(plain.key_tables),
+                                  np.asarray(carried.key_tables))
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), states[0], states[1])

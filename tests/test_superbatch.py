@@ -293,3 +293,187 @@ def test_pending_buffer_coalesces_arrivals_keeps_tails():
     buf.append(EvictedFlows(make_events(300, seed=3)),
                lambda e, f: got.append(len(e)))
     assert got == [256] and len(buf) == 44
+
+
+# --- rows a chunk cannot take ride the next chunk ---------------------------
+
+#: side lanes small enough that nearly every region stops early: new keys
+#: beyond 4 and rows needing the spill lane beyond 2 a region are left
+TIGHT_CAPS = flowpack.ResidentCaps(dns=8, drop=8, nk=4, spill=2)
+
+RING_FORMS = {"one_shard_eight_lanes": (1, 8), "four_shards_two_lanes": (4, 2)}
+
+
+@pytest.fixture(params=sorted(RING_FORMS))
+def tight_exporter(request, monkeypatch):
+    """A factory of exporters whose resident ring has TIGHT_CAPS, in the
+    two forms the served path runs: one device with 8 pack lanes, and a
+    4-shard mesh with 2 lanes a shard (32 regions an x4 chunk either way).
+    `carry=False` gives the finish-everything form: every fold consumes
+    all it is offered, through continuation chunks."""
+    from netobserv_tpu.exporter.tpu_sketch import TpuSketchExporter
+
+    shards, lanes = RING_FORMS[request.param]
+    monkeypatch.setattr(flowpack, "default_resident_caps",
+                        lambda batch: TIGHT_CAPS)
+    if shards == 1:
+        real_devices = jax.devices
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a, **k: real_devices(*a, **k)[:1])
+    made = []
+
+    def make(carry=True, **kw):
+        exp = TpuSketchExporter(
+            batch_size=B, window_s=3600, sketch_cfg=CFG, pack_threads=8,
+            superbatch=(1, 2, 4), mesh_shape="" if shards == 1 else "4",
+            **kw)
+        made.append(exp)
+        exp.warm_superbatch_ladder(block=True)
+        ring = exp._ring
+        assert (ring.n_shards, ring.lanes, ring.caps) == (
+            shards, lanes, TIGHT_CAPS)
+        exp._carry_ring = carry
+        return exp
+
+    yield make
+    for exp in made:
+        exp.close()
+
+
+def mixed_evictions():
+    """Ragged evictions, some with every feature lane, some with one, some
+    with none — so a lane goes live while older left rows wait."""
+    out = []
+    for i in range(9):
+        n = (700, 97, 1100, 301)[i % 4]
+        ev = make_events(n, seed=60 + i)
+        feats = make_feats(n, seed=80 + i)
+        if i % 3 == 0:
+            out.append(EvictedFlows(ev))
+        elif i % 3 == 1:
+            out.append(EvictedFlows(ev, **feats))
+        else:
+            out.append(EvictedFlows(ev, drops=feats["drops"]))
+        out[-1].eviction = i + 1
+    return out
+
+
+def sorted_report(rep):
+    rep = dict(rep)
+    rep.pop("TimestampMs")
+    rep["HeavyHitters"] = sorted(rep["HeavyHitters"],
+                                 key=lambda h: sorted(h.items()))
+    return rep
+
+
+def test_carried_rows_fold_once_and_match_the_finishing_form(tight_exporter):
+    """The same stream through the carrying exporter and through the
+    finish-everything form ends, after flush(), in the same merged window
+    report; every row handed is folded exactly once; the steady folds
+    shipped no continuation chunk."""
+    from netobserv_tpu.metrics.registry import Metrics, MetricsSettings
+
+    handed = sum(len(e) for e in mixed_evictions())
+    reports, rings = {}, {}
+    for carry in (True, False):
+        got = []
+        metrics = Metrics(MetricsSettings())
+        exp = tight_exporter(carry=carry, sink=got.append, metrics=metrics)
+        for ev in mixed_evictions():
+            exp.export_evicted(ev)
+            # less than a batch waits for the next eviction, left rows
+            # included (chip_smoke.py counts on it to tell a window's end)
+            assert len(exp._pending_buf) < B
+        steady_continuations = exp._ring.continuations
+        assert (len(exp._pending_buf) > 0) or not carry
+        exp.flush()
+        assert len(exp._pending_buf) == 0
+        assert metrics.sketch_records_total._value.get() == handed
+        assert (metrics.sketch_resident_carried_rows_total._value.get()
+                == exp._ring.carried_rows)
+        assert got[0]["Records"] == handed
+        reports[carry] = sorted_report(got[0])
+        rings[carry] = (exp._ring, steady_continuations)
+    assert reports[True] == reports[False]
+    ring, steady = rings[True]
+    assert ring.carried_rows > 0 and steady == 0
+    plain, _ = rings[False]
+    assert plain.carried_rows == 0 and plain.continuations > 0
+    # each dispatch of the carrying form loads every region again: fewer
+    # dispatches for the same rows
+    assert (sum(ring.superbatch_folds.values())
+            < sum(plain.superbatch_folds.values()))
+
+
+def test_roll_flush_and_close_leave_nothing_carried(tight_exporter):
+    got = []
+    exp = tight_exporter(sink=got.append)
+    evs = mixed_evictions()
+    for ev in evs[:3]:
+        exp.export_evicted(ev)
+    assert len(exp._pending_buf) > 0
+    with exp._lock:
+        exp._close_window_locked()              # a roll
+    assert len(exp._pending_buf) == 0
+    assert not any(exp._pending_buf._live.values())
+    for ev in evs[3:6]:
+        exp.export_evicted(ev)
+    assert len(exp._pending_buf) > 0
+    exp.flush()
+    assert len(exp._pending_buf) == 0
+    for ev in evs[6:]:
+        exp.export_evicted(ev)
+    assert len(exp._pending_buf) > 0
+    exp.close()
+    assert len(exp._pending_buf) == 0
+    # every window published exactly the rows handed while it was open
+    assert [r["Records"] for r in got] == [
+        sum(len(e) for e in evs[lo:lo + 3]) for lo in (0, 3, 6)]
+
+
+def test_wedged_slot_mid_carry_drops_one_fold_and_adopts_the_state(
+        tight_exporter):
+    """The slot-wait budget trips on the SECOND chunk of a fold that holds
+    carried rows: the fold's rows drop (at most what the finishing form
+    drops: the fold on offer), the exporter adopts the state the first
+    chunk left (its own was donated), nothing stale stays buffered, and
+    the feed goes on."""
+    from netobserv_tpu.metrics.registry import Metrics, MetricsSettings
+
+    class NeverReady:
+        def is_ready(self):
+            return False
+
+    metrics = Metrics(MetricsSettings())
+    exp = tight_exporter(metrics=metrics, shed_watermark=1e9,
+                         shed_slot_budget_s=0.1)
+    ring = exp._ring
+    exp.export_evicted(EvictedFlows(make_events(B + 40, seed=1)))
+    held = len(exp._pending_buf)
+    assert held > 40                            # carried rows and the tail
+    folded = metrics.sketch_records_total._value.get()
+    assert folded == B + 40 - held
+    pre = exp._state
+    # 3 batches arrive: with what is held, a 3-batch prefix folds as an x2
+    # chunk and an x1 chunk; the x1 chunk's slot never frees
+    wedged = (ring._slot + 1) % len(ring._tokens)
+    real = ring._tokens[wedged]
+    ring._tokens[wedged] = NeverReady()
+    try:
+        exp.export_evicted(EvictedFlows(make_events(3 * B, seed=2)))
+    finally:
+        ring._tokens[wedged] = real
+    assert exp._state is not pre
+    assert metrics.sketch_ingest_errors_total._value.get() == 1
+    assert metrics.sketch_records_total._value.get() == folded
+    assert len(exp._pending_buf) == held        # the new tail alone
+    got = []
+    exp._sink = got.append
+    exp.export_evicted(EvictedFlows(make_events(B, seed=3)))
+    exp.flush()
+    # the report counts what the device folded: everything handed but the
+    # 3 batches the wedged fold was offered, of which the x2 chunk's
+    # consumed rows did reach the device
+    offered = 3 * B
+    lost = (B + 40 + 3 * B + B) - got[0]["Records"]
+    assert 0 < lost <= offered
