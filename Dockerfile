@@ -17,7 +17,7 @@ RUN pip install --no-cache-dir "jax[tpu]" numpy grpcio protobuf \
 WORKDIR /app
 COPY netobserv_tpu ./netobserv_tpu
 COPY proto ./proto
-COPY bench.py __graft_entry__.py ./
+COPY __graft_entry__.py ./
 COPY --from=bpf-build /src/libflowpack.so \
      ./netobserv_tpu/datapath/native/build/libflowpack.so
 COPY --from=bpf-build /src/build/flowpath.bpf.o* \

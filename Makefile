@@ -1,10 +1,10 @@
 # netobserv_tpu build/test entry points (reference analog: the Go Makefile's
-# compile / gen-bpf / gen-protobuf / test / bench targets).
+# compile / gen-bpf / gen-protobuf / test targets).
 
 PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: all test test-cpu bench gen-protobuf native bpf verify-maps lint perftest bytecode-image \
+.PHONY: all test test-cpu gen-protobuf native bpf verify-maps lint perftest bytecode-image \
         dryrun smoke smoke-chip clean
 
 all: native gen-protobuf
@@ -16,98 +16,9 @@ test:
 test-cpu:
 	$(CPU_ENV) $(PY) -m pytest tests/ -x -q
 
-# needs a TPU (exits non-zero without one). The bench-* targets below ask
-# for the CPU by name: they give counts, bytes and correctness, and their
-# rates are the CPU's own — printed under cpu_* names, never a chip's
-bench:
-	$(PY) bench.py
-
-bench-cpu:
-	JAX_PLATFORMS=cpu $(PY) bench.py
-
 # the quickest proof that the system still starts on the chip
 smoke-chip:
 	$(PY) chip_smoke.py
-
-# host path only (~15s): pack/transfer/fold rates, pack-thread scaling,
-# roll-stall — the per-PR CI artifact (no device ingest loop, no oracle)
-bench-host:
-	JAX_PLATFORMS=cpu $(PY) bench.py --host-only
-
-# same run at 1% trace sampling: the flight-recorder overhead A/B
-# (docs/observability.md "Overhead budget"; compare host_fold_ms_p50 /
-# host_path_sustained against the bench-host artifact)
-bench-host-traced:
-	TRACE_SAMPLE=0.01 JAX_PLATFORMS=cpu $(PY) bench.py --host-only
-
-# per-stage breakdown on the CPU (~60s): ingest ablations (signals/asym/
-# fanout on/off), superbatch ladder 1x/2x/4x. The Pallas arms need a TPU
-# and do not run here
-bench-device:
-	JAX_PLATFORMS=cpu $(PY) bench.py --device-only
-
-# eviction-plane decode rates (~10s, jax-free path): columnar
-# decode/merge/align vs the per-key idiom on synthetic multi-CPU drains —
-# the per-PR CI artifact for the userspace eviction half
-bench-evict:
-	JAX_PLATFORMS=cpu $(PY) bench.py --evict-only
-
-# fused one-call host pipeline (~10s, jax-free path): fp_drain_to_resident
-# vs the python island chain on identical injected drains — per-stage
-# drain/merge/join/pack split + GIL-interference probe — the non-gating
-# CI artifact for the native eviction pipeline (docs/architecture.md
-# "Eviction plane")
-bench-native:
-	JAX_PLATFORMS=cpu $(PY) bench.py --native-only
-
-# persistent-slot top-K ablation (~60s, CPU-friendly): slot-table vs the
-# legacy concat+re-score update — cost (CM-only arm attributes the
-# table's share) and top-N recall vs exact truth at 10k/100k distinct
-# keys — the non-gating CI artifact for the device-resident heavy-hitter
-# plane (docs/tpu_sketch.md "Persistent-slot heavy-hitter plane")
-bench-topk:
-	JAX_PLATFORMS=cpu $(PY) bench.py --topk-only
-
-# tiered counter planes (~60s, CPU-friendly): tiered-vs-wide resident
-# sketch memory — batch-walk rate, per-table bytes (the sketch_memory
-# block), tier occupancy/promotion counts, heavy-hitter recall@100 vs the
-# exact oracle — the non-gating CI artifact for the self-adjusting sketch
-# memory plane (docs/tpu_sketch.md "Tiered counter planes")
-bench-tiered:
-	JAX_PLATFORMS=cpu $(PY) bench.py --tiered-only
-
-# multi-tenant stacked sketch plane (~2-4 min, CPU-friendly): the
-# one-dispatch-folds-every-tenant amortization ladder (N=1/8/64 tenants,
-# small per-tenant batches) vs N sequential single-tenant dispatches of
-# the same rows, plus per-tenant top-K recall through the production
-# router — the non-gating CI artifact for SKETCH_TENANTS
-# (docs/architecture.md "Multi-tenant sketch planes")
-bench-tenants:
-	JAX_PLATFORMS=cpu $(PY) bench.py --tenants-only
-
-# sketch warehouse (~60s, CPU-friendly): per-window write amplification,
-# raw-vs-compacted segment bytes, range-merge rate per ladder k, range
-# top-K recall vs the union oracle — the non-gating CI artifact for the
-# archive plane (docs/architecture.md "Sketch warehouse")
-bench-archive:
-	JAX_PLATFORMS=cpu $(PY) bench.py --archive-only
-
-# overload control plane (~15s): overdriven synthetic feed against a
-# fault-slowed fold — sustained admitted rate, AIMD shed-factor
-# trajectory, heavy-hitter recall under shed vs unshed — the per-PR CI
-# artifact for the shedding seam (docs/architecture.md
-# "Overload & backpressure")
-bench-overload:
-	JAX_PLATFORMS=cpu $(PY) bench.py --overload-only
-
-# adversarial scenario zoo (~90s): every netobserv_tpu/scenarios pcap
-# replayed through a full in-process agent and graded END TO END through
-# the live /query/* routes — top-K recall, alarm fire/quiet directions,
-# victim naming, HLL cardinality error, CM error-bar honesty — the
-# per-PR CI artifact for detection QUALITY (docs/architecture.md
-# "Query plane")
-bench-scenarios:
-	JAX_PLATFORMS=cpu $(PY) bench.py --scenarios
 
 gen-protobuf:
 	protoc --python_out=netobserv_tpu/pb -I proto proto/flow.proto proto/packet.proto
@@ -169,9 +80,6 @@ clean:
 	rm -rf netobserv_tpu/datapath/native/build
 	find . -name __pycache__ -type d -exec rm -rf {} +
 
-bench-micro:
-	$(PY) benchmarks/micro_bench.py
-
 gen-docs:
 	$(PY) scripts/gen_config_docs.py
 
@@ -179,8 +87,3 @@ gen-docs:
 accuracy:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	  $(PY) scripts/accuracy_sweep.py
-
-# host-path + per-stage profiles of whatever device JAX finds
-profile:
-	$(PY) benchmarks/host_path_profile.py
-	$(PY) benchmarks/ingest_stage_profile.py

@@ -595,7 +595,7 @@ class AgentConfig:  # noqa: PLR0902 - deliberately wide, mirrors reference
 
     def parsed_superbatch_ladder(self) -> tuple:
         """SKETCH_SUPERBATCH as a sorted, deduplicated int tuple — the ONE
-        parse of the ladder spec (exporter and bench both use it)."""
+        parse of the ladder spec (validate() and the exporter both use it)."""
         try:
             ladder = tuple(sorted({int(tok) for tok in
                                    self.sketch_superbatch.split(",") if tok}))

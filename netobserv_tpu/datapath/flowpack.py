@@ -111,7 +111,7 @@ def resident_buf_len(batch_size: int, caps: ResidentCaps) -> int:
 def zero_resident_region(out: np.ndarray, batch_size: int,
                          caps: ResidentCaps) -> None:
     """Mask a resident region as EMPTY by zeroing only the words the device
-    unpack (`sketch.state.resident_to_arrays`) reads as validity gates:
+    unpack (`sketch.state.resident_lane_arrays`) reads as validity gates:
     hot-row word 0 (valid bit + slot + rtt code), the sparse dns/drop lanes
     (their entries scatter by embedded row index), new-key word 0 (defined
     bit) and spill word 14 (valid). Every other word of an invalid row is
@@ -592,7 +592,7 @@ def pack_resident(events_raw: bytes | np.ndarray,
                   ) -> tuple[np.ndarray, int]:
     """Raw flow-event buffer -> the resident feed (layout pinned in
     flowpack.cc fp_pack_resident; device unpack is
-    sketch.state.resident_to_arrays). Packs events[start:] until the hot or
+    sketch.state.resident_lane_arrays). Packs events[start:] until the hot or
     spill lane fills; returns (buffer, rows_consumed) — partial packing
     with continuation (the caller ships the prefix and calls again with the
     next start), so the dictionary and the device key table learn
@@ -1021,7 +1021,7 @@ class NativePipe:
     """Handle on one fp_drain_to_resident pipeline over a fixed set of maps.
     `maps` is [(fd, kind, value_size, n_cpus, max_entries)] with map 0 the
     aggregation map (kind "stats", n_cpus 1); fd < 0 makes a map injected
-    (set_drained) for tests and bench. `lanes` fans the per-map drain+merge
+    (set_drained) for tests. `lanes` fans the per-map drain+merge
     over that many native worker threads (GIL released for the whole call)."""
 
     def __init__(self, maps: list, lanes: int = 1):

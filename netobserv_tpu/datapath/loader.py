@@ -75,7 +75,7 @@ _FEATURE_MAPS = [
 # decodes as whole arrays straight from the batch-syscall buffers, per-CPU
 # partials merge as one native/columnar pass per feature map, and key
 # alignment is a void-view sort/searchsorted join — no per-record Python
-# anywhere. bench.py --evict-only drives decode_eviction directly.
+# anywhere.
 # ---------------------------------------------------------------------------
 
 _KEY_SIZE = binfmt.FLOW_KEY_DTYPE.itemsize

@@ -280,7 +280,7 @@ int fp_pack_compact(const uint8_t *events, size_t n,
 // steady-state records ship as THREE words instead of ten (the transfer
 // link, not compute, bounds the host path; see docs/tpu_sketch.md byte
 // budget). Flat buffer layout (must match sketch/state.py
-// resident_to_arrays and flowpack.py pack_resident):
+// resident_lane_arrays and flowpack.py pack_resident):
 //   [0..3]    header: w0 default sampling, w1 n_newkey, w2 n_spill,
 //             w3 n_dns | n_drop << 16   (w1..w3 diagnostic only)
 //   hot lane    batch_size * 3 words:
@@ -1098,7 +1098,7 @@ void fp_pipe_free(void *h) {
 
 void fp_buf_free(void *ptr) { free(ptr); }
 
-// Test/bench injection for fd < 0 maps: pre-load one drain's (keys, vals)
+// Test injection for fd < 0 maps: pre-load one drain's (keys, vals)
 // as if the batched syscall had produced them. vals layout is the kernel's:
 // n rows x n_cpus images x value_size bytes, contiguous.
 int fp_pipe_set_drained(void *h, uint32_t idx, const uint8_t *keys,

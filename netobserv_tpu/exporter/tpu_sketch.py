@@ -579,7 +579,7 @@ class TpuSketchExporter(Exporter):
             self._tiered_degraded = True
             self._cfg = self._cfg._replace(tiered=None)
         #: which tiered fold form this backend engages ("interior" |
-        #: "decode" | None) — the /debug/executables + bench attribution
+        #: "decode" | None) — the /debug/executables attribution
         #: for every watched ingest/roll entry (one program each, never
         #: hidden variants). Rolls always ride the wide decode.
         self._tier_form = sk.tiered_fold_form(self._cfg)
@@ -695,9 +695,7 @@ class TpuSketchExporter(Exporter):
             # dispatch is watched — its first compile is warmup, any later
             # compile alarms (sketch_retraces_total{fn=...})
             self._ingest = sk.make_ingest_fn(
-                use_pallas=self._cfg.use_pallas,
-                enable_fanout=self._cfg.enable_fanout,
-                enable_asym=self._cfg.enable_asym, name="ingest",
+                use_pallas=self._cfg.use_pallas, name="ingest",
                 tiered=self._tier_form)
             # with_tables unconditionally: the pre-roll table snapshot is
             # one extra output of the same roll executable, and it feeds
@@ -1654,8 +1652,7 @@ class TpuSketchExporter(Exporter):
 
         sk = self._sk
         kw = dict(use_pallas=self._cfg.use_pallas, with_token=True,
-                  enable_fanout=self._cfg.enable_fanout,
-                  enable_asym=self._cfg.enable_asym, tiered=self._tier_form)
+                  tiered=self._tier_form)
         if feed == "resident":
             lanes = staging.pick_lanes(self._batch_size, self._lane_threads)
             ladder = self._superbatch
@@ -1668,8 +1665,6 @@ class TpuSketchExporter(Exporter):
             ingests = {
                 k: sk.make_ingest_resident_lanes_fn(
                     bpl, caps, k * lanes, use_pallas=self._cfg.use_pallas,
-                    enable_fanout=self._cfg.enable_fanout,
-                    enable_asym=self._cfg.enable_asym,
                     name=f"ingest_resident_lanes_x{k}",
                     tiered=self._tier_form)
                 for k in ladder}

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from netobserv_tpu.ops.hll import HLL, PerDstHLL, _rank
+from netobserv_tpu.ops.hll import HLL, _rank
 
 TILE_M = 512
 CHUNK_B = 2048
@@ -39,9 +39,10 @@ def _fold_kernel(regs_ref, idx_ref, rank_ref, out_ref, *, n_chunks: int):
 
 def _fold_flat(regs_flat: jax.Array, idx: jax.Array, rank: jax.Array,
                interpret: bool) -> jax.Array:
-    """Shared one-hot max fold over a FLAT register array of any
-    TILE_M-aligned size (the global HLL and, via bucket*m + reg flat
-    indexing, the per-dst/per-src grids)."""
+    """One-hot max fold over a FLAT register array of any TILE_M-aligned
+    size. The per-dst/per-src grids stay on the XLA scatter: a one-hot fold
+    pays D*m lane-compares per RECORD (4096x64 = 262K, 16x the global
+    HLL's) where the scatter touches O(1) cells per record."""
     m = regs_flat.shape[0]
     assert m % TILE_M == 0, f"m={m} must be a multiple of {TILE_M}"
     pad = (-idx.shape[0]) % CHUNK_B
@@ -81,23 +82,3 @@ def update(hll: HLL, h1: jax.Array, h2: jax.Array, valid: jax.Array,
     rank = jnp.where(valid, _rank(h2), 0)
     return HLL(regs=_fold_flat(hll.regs, idx, rank, interpret))
 
-
-def update_per_dst(s, dst_h: jax.Array, src_h1: jax.Array,
-                   src_h2: jax.Array, valid: jax.Array,
-                   interpret: bool | None = None):
-    """Drop-in replacement for hll.update_per_dst: the (bucket, register)
-    grid folds as ONE flat register array of D*m lanes (cell index =
-    bucket*m + reg). NOTE the roofline before wiring this in: the one-hot
-    fold pays D*m lane-compares per RECORD (e.g. 4096x64 = 262K — 16x the
-    global HLL's), while the XLA scatter pays O(1) touches per record
-    regardless of grid size; benchmarks/ingest_stage_profile.py carries the
-    A/B (docs/tpu_sketch.md records the verdict)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    dbuckets, m = s.regs.shape
-    di = (dst_h & jnp.uint32(dbuckets - 1)).astype(jnp.int32)
-    ri = (src_h1 & jnp.uint32(m - 1)).astype(jnp.int32)
-    idx = di * m + ri
-    rank = jnp.where(valid, _rank(src_h2), 0)
-    flat = _fold_flat(s.regs.reshape(dbuckets * m), idx, rank, interpret)
-    return PerDstHLL(regs=flat.reshape(dbuckets, m))

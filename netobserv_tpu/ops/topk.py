@@ -1,23 +1,13 @@
-"""Fixed-K heavy-hitter tables maintained entirely on device.
+"""Fixed-K heavy-hitter table maintained entirely on device.
 
-Two generations live here:
-
-- **SlotTable** (the production plane since ISSUE 13): a SpaceSaving-style
-  d-way set-associative slot table whose rows keep STABLE identity across
-  batch folds and across window rolls. Candidate maintenance happens in the
-  per-batch update path (`slot_update`, with a fused Pallas reduction twin in
-  `ops/pallas/topk_kernel.py`), so a window roll ships a READY top-K with
-  per-slot churn metadata (`counts`, `prev_counts`, `first_seen`, `epoch`) —
-  no host post-pass. Counts are Count-Min point estimates, so the CM error
-  bound (count <= true + e/w * N with prob 1-e^-d) carries over verbatim.
-
-- **TopK** (the legacy concat+re-score path): after the CM fold every batch
-  key is a candidate; candidates and the current table are re-scored by CM
-  point query, deduplicated, and the top K survive via `lax.top_k`. Slot
-  identity is NOT stable across folds (rows reshuffle on every update), so
-  there is nothing to diff across windows. Kept as the pinned baseline for
-  `bench.py --topk-only` and as the exact-sort `_select`/`merge_stacked`
-  oracle the slot-table merge is graded against.
+**SlotTable**: a SpaceSaving-style d-way set-associative slot table whose
+rows keep STABLE identity across batch folds and across window rolls.
+Candidate maintenance happens in the per-batch update path (`slot_update`,
+with a fused Pallas reduction twin in `ops/pallas/topk_kernel.py`), so a
+window roll ships a READY top-K with per-slot churn metadata (`counts`,
+`prev_counts`, `first_seen`, `epoch`) — no host post-pass. Counts are
+Count-Min point estimates, so the CM error bound (count <= true + e/w * N
+with prob 1-e^-d) carries over verbatim.
 
 Everything is fixed-shape — no heaps, no dynamic growth — so it jits and
 shards cleanly (reference analog being replaced: the Go map in
@@ -35,131 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from netobserv_tpu.ops import countmin, hashing
-
-
-class TopK(NamedTuple):
-    words: jax.Array   # uint32[K, W] — packed key material
-    h1: jax.Array      # uint32[K]
-    h2: jax.Array      # uint32[K]
-    counts: jax.Array  # float32[K] — CM-estimated totals, -1 for empty slots
-    valid: jax.Array   # bool[K]
-
-    @property
-    def k(self) -> int:
-        return self.words.shape[0]
-
-
-def init(k: int = 1024, key_words: int = 10) -> TopK:
-    return TopK(
-        words=jnp.zeros((k, key_words), dtype=jnp.uint32),
-        h1=jnp.zeros((k,), dtype=jnp.uint32),
-        h2=jnp.zeros((k,), dtype=jnp.uint32),
-        counts=jnp.full((k,), -1.0, dtype=jnp.float32),
-        valid=jnp.zeros((k,), dtype=jnp.bool_),
-    )
-
-
-def _select(words, h1, h2, est, k: int) -> TopK:
-    """Dedup by (h1, h2) identity and keep the top-k by est (invalid est = -1)."""
-    n = h1.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    s_h1, s_h2, s_idx = jax.lax.sort((h1, h2, idx), num_keys=2)
-    s_est = est[s_idx]
-    first = jnp.concatenate([
-        jnp.ones((1,), dtype=jnp.bool_),
-        (s_h1[1:] != s_h1[:-1]) | (s_h2[1:] != s_h2[:-1]),
-    ])
-    s_est = jnp.where(first, s_est, -1.0)
-    top_est, top_pos = jax.lax.top_k(s_est, k)
-    orig = s_idx[top_pos]
-    sel_valid = top_est > 0
-    return TopK(
-        words=jnp.where(sel_valid[:, None], words[orig], 0),
-        h1=jnp.where(sel_valid, s_h1[top_pos], 0),
-        h2=jnp.where(sel_valid, s_h2[top_pos], 0),
-        counts=jnp.where(sel_valid, top_est, -1.0),
-        valid=sel_valid,
-    )
-
-
-_SLOT_BITS = 19  # dedup slot space (2^19 ~ 0.2% residual collision vs K=1024)
-
-
-def update(table: TopK, cm: countmin.CountMin, words: jax.Array, h1: jax.Array,
-           h2: jax.Array, valid: jax.Array, query_fn=None,
-           salt: jax.Array | int = 0) -> TopK:
-    """Fold one batch (whose mass is already in `cm`) into the table.
-
-    `query_fn(h1, h2) -> est` overrides the plain CM point query (used for
-    width-sharded sketches, where the query needs a psum over the sketch axis).
-
-    Dedup strategy: a full lexicographic sort over table+batch is exact but
-    dominates ingest cost (~5ms/batch measured). Instead, duplicates are
-    collapsed with a scatter-min "slot owner" table over 2^19 slots: every
-    live row hashes its full 64-bit key identity (h1 AND h2) plus `salt`
-    into a slot, the lowest row index owns it, and only owners are eligible
-    for `lax.top_k` selection. Two *distinct* keys sharing a slot suppress
-    the higher-indexed one for the CURRENT WINDOW (table rows always outrank
-    batch rows); passing the window counter as `salt` reshuffles slots at
-    every roll so a colliding pair is re-separated next window. Residual
-    loss: ~(K+B)/2^19 ≈ 3% chance a given new key collides with anything in
-    one window, ~0.2% with a table key — and never the same pair twice.
-    (A naive candidate cut by estimate does NOT work: under skew the top
-    rows are duplicates of a few mega-keys and recall collapses — measured.)
-    The exact sort-based `_select` remains in use for window merges.
-    """
-    if query_fn is None:
-        query_fn = lambda a, b: countmin.query(cm, a, b)  # noqa: E731
-    batch_est = jnp.where(valid, query_fn(h1, h2), -1.0)
-    table_est = jnp.where(table.valid,
-                          query_fn(table.h1, table.h2), -1.0)
-    all_words = jnp.concatenate([table.words, words], axis=0)
-    all_h1 = jnp.concatenate([table.h1, h1])
-    all_h2 = jnp.concatenate([table.h2, h2])
-    all_est = jnp.concatenate([table_est, batch_est])
-
-    n = all_h1.shape[0]
-    n_slots = 1 << _SLOT_BITS
-    # slot identity covers the full 64-bit key hash (h1 AND h2) plus the salt
-    slot = (hashing.fmix32(all_h1 ^ ((all_h2 << 16) | (all_h2 >> 16))
-                           ^ jnp.uint32(salt))
-            & jnp.uint32(n_slots - 1)).astype(jnp.int32)
-    rows = jnp.arange(n, dtype=jnp.int32)
-    live = all_est > 0
-    owner = jnp.full((n_slots,), n, dtype=jnp.int32)
-    # dead rows must not own slots (a stale table slot could otherwise
-    # suppress a live key)
-    owner = owner.at[jnp.where(live, slot, n_slots - 1)].min(
-        jnp.where(live, rows, n), mode="drop")
-    is_owner = owner[slot] == rows
-    sel_est = jnp.where(is_owner & live, all_est, -1.0)
-    top_est, pos = jax.lax.top_k(sel_est, table.k)
-    sel_valid = top_est > 0
-    return TopK(
-        words=jnp.where(sel_valid[:, None], all_words[pos], 0),
-        h1=jnp.where(sel_valid, all_h1[pos], 0),
-        h2=jnp.where(sel_valid, all_h2[pos], 0),
-        counts=jnp.where(sel_valid, top_est, -1.0),
-        valid=sel_valid,
-    )
-
-
-def merge_stacked(stacked: TopK, cm_merged: countmin.CountMin, k: int,
-                  query_fn=None) -> TopK:
-    """Merge per-device tables stacked along axis 0 into one size-k table.
-
-    stacked arrays have shape [n_dev * K, ...]. Counts are re-queried against
-    the merged CM so the selection reflects cluster-wide mass (SURVEY.md §5.8:
-    "allgather + re-select top-K over ICI")."""
-    if query_fn is None:
-        query_fn = lambda a, b: countmin.query(cm_merged, a, b)  # noqa: E731
-    est = jnp.where(stacked.valid, query_fn(stacked.h1, stacked.h2), -1.0)
-    return _select(stacked.words, stacked.h1, stacked.h2, est, k)
-
-
-# ---------------------------------------------------------------------------
-# Persistent-slot heavy-hitter table (the device-resident top-K plane)
-# ---------------------------------------------------------------------------
 
 #: d-way set associativity: each key identity hashes to SLOT_WAYS candidate
 #: slots (odd stride over a power-of-two K makes them distinct); a new key

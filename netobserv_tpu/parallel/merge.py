@@ -164,9 +164,7 @@ def make_sharded_ingest_fn(mesh: Mesh, cfg: sk.SketchConfig,
                       sketch_shards=nsk,
                       # owner-sharded sketches keep the masked-scatter path;
                       # the Pallas fold applies to whole-width replicas
-                      use_pallas=(cfg.use_pallas if nsk == 1 else False),
-                      enable_fanout=cfg.enable_fanout,
-                      enable_asym=cfg.enable_asym)
+                      use_pallas=(cfg.use_pallas if nsk == 1 else False))
         out = _add_lead(s)
         if with_token:
             return out, (batch[:1] if batch.ndim == 1 else batch[:1, 0])
@@ -235,9 +233,7 @@ def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
         s = sk.ingest(s, arrays,
                       sketch_axis=SKETCH_AXIS if nsk > 1 else None,
                       sketch_shards=nsk,
-                      use_pallas=(cfg.use_pallas if nsk == 1 else False),
-                      enable_fanout=cfg.enable_fanout,
-                      enable_asym=cfg.enable_asym)
+                      use_pallas=(cfg.use_pallas if nsk == 1 else False))
         return _add_lead(s), tbl[None], flat[:1]
 
     shmapped = jax.shard_map(

@@ -14,8 +14,7 @@ refresh enabled, attack scenarios must detect in under one window period
 — sub-window detection is the plane's point.
 
 Used by tests/test_scenarios.py (one fast smoke in tier-1, the full zoo in
-the slow tier) and `bench.py --scenarios` (the per-scenario quality
-artifact)."""
+the slow tier)."""
 
 from __future__ import annotations
 

@@ -462,8 +462,8 @@ def build_flow_ascent(path: str) -> dict:
     }
 
 
-#: name -> builder(path) -> truth; the runner, tests, and bench all
-#: iterate this registry
+#: name -> builder(path) -> truth; the runner and tests iterate this
+#: registry
 SCENARIOS = {
     "syn_flood": build_syn_flood,
     "dns_flood": build_dns_flood,

@@ -35,7 +35,8 @@ term), while a seam spending its whole wall clock folding (busy ~1)
 counts the full depth. ``slot_wait_p95`` comes from the staging ring's
 recent-wait window. ``SLOT_WAIT_REF_S`` converts device backpressure
 into batch-equivalents: a quarter second of slot wait per fold is
-severe (healthy folds measure ~ms, bench.py), so p95 == the reference
+severe (a device-bound collector waits about 20 ms a fold, PERF.md
+section 5), so p95 == the reference
 counts like one full batch of backlog.
 
 Disabled (``SKETCH_SHED_WATERMARK`` unset) the exporter never constructs

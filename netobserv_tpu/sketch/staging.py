@@ -358,9 +358,8 @@ class _SlotRing:
     def _fold_trace(self, trace):
         """Resolve a fold's trace context: the caller's (batch trace riding
         the eviction, or the exporter's NULL), else sample one here — a
-        directly-driven ring (bench.py --host-only) still exercises the
-        span layer. Returns (trace, owned): the ring finishes only traces
-        it created."""
+        directly-driven ring still exercises the span layer. Returns
+        (trace, owned): the ring finishes only traces it created."""
         if trace is not None:
             return trace, False
         return tracing.start_trace("fold"), True
@@ -602,8 +601,8 @@ class ShardedResidentStagingRing(_SlotRing):
         # says their jit is compiled (the exporter's construction warm) —
         # a cold ladder entry must never compile inside a live fold, which
         # would stall export_evicted for seconds (test_roll_nonblocking).
-        # Eager (default) trusts the caller to warm by folding (bench,
-        # offline tools, tests).
+        # Eager (default) trusts the caller to warm by folding (offline
+        # tools, tests).
         self._available = {1} if lazy_ladder else set(self.ladder)
         n_regions = n_shards * lanes
         if batch_size % n_regions:
@@ -641,12 +640,6 @@ class ShardedResidentStagingRing(_SlotRing):
         self._init_slots(
             [np.empty(self.superbatch_max * n_regions * self._region_words,
                       np.uint32) for _ in range(n_slots)], metrics)
-
-    @property
-    def _ingest(self):
-        """The 1x ladder entry (back-compat: retrace introspection in tests
-        predates the ladder)."""
-        return self._ingests[1]
 
     def mark_warm(self, *ks: int) -> None:
         """Make ladder entries selectable (call after compiling them — the
@@ -750,7 +743,7 @@ class ShardedResidentStagingRing(_SlotRing):
                 kd = self.kdicts[(i // kl) * kmax_l + (i % kl)]
                 resets = 0
                 if kd.count() >= self.slot_cap:
-                    kd.reset()  # per-region epoch roll (ResidentStagingRing)
+                    kd.reset()  # per-region epoch roll
                     resets = 1
                 _, consumed = flowpack.pack_resident(
                     shard_ev[i], batch_size=self.batch_per_region,
@@ -956,102 +949,3 @@ class ResidentPackSurface:
             ring._metrics.sketch_resident_dict_epochs_total.inc(
                 len(ring.kdicts))
 
-
-class ResidentStagingRing(_SlotRing):
-    """Staging ring for the RESIDENT feed — the lowest-bytes-per-record host
-    path (~15B/record vs the compact feed's 40B; byte budget in
-    docs/tpu_sketch.md). The host keeps a key->slot dictionary
-    (`flowpack.KeyDict`, native); the device keeps the matching key table
-    (`sketch.state.init_key_table`) threaded through the jitted ingest.
-
-    `ingest` must be `make_ingest_resident_fn(with_token=True)`:
-    `(state, table, flat) -> (state, table, token)`. The packer packs until
-    a lane fills and reports how many rows it consumed; the ring ships that
-    (always self-consistent) prefix and continues from the stop point in
-    the next slot — so the dictionary and the device table learn
-    monotonically even under cold-start key floods, with no dense fallback
-    and no rollback. A full dictionary starts a fresh epoch (reset) at the
-    next fold: stale device-table rows are harmless because every live
-    slot is redefined through the new-key lane before any hot row
-    references it."""
-
-    def __init__(self, batch_size: int, ingest: Callable,
-                 caps=None, slot_cap: int = 1 << 18,
-                 put: Optional[Callable] = None, n_slots: int = 4,
-                 metrics=None):
-        import jax
-
-        from netobserv_tpu.sketch import state as sk
-
-        self.batch_size = batch_size
-        self.caps = caps or flowpack.default_resident_caps(batch_size)
-        self.slot_cap = slot_cap
-        self.kdict = flowpack.KeyDict(slot_cap)
-        self.key_table = jax.device_put(sk.init_key_table(slot_cap))
-        self._ingest = ingest
-        self._put = put or jax.device_put
-        self.continuations = 0  # extra chunks beyond one per fold()
-        self.dict_resets = 0    # full-dictionary epochs
-        self.spill_rows = 0     # rows that rode the full-width spill lane
-        total = flowpack.resident_buf_len(batch_size, self.caps)
-        self._init_slots([np.empty(total, np.uint32)
-                          for _ in range(n_slots)], metrics)
-
-    def fold(self, state, events, extra=None, dns=None, drops=None,
-             xlat=None, quic=None, trace=None):
-        """Pack `events` (possibly in several chunks) into free ring slots,
-        ship and ingest each; returns the new sketch state (async — not
-        blocked on)."""
-        feats = dict(extra=extra, dns=dns, drops=drops, xlat=xlat, quic=quic)
-        n = len(events)
-        if n == 0:
-            return state
-        trace, owned = self._fold_trace(trace)
-        try:
-            start = 0
-            first = True
-            while start < n:
-                if self.kdict.count() >= self.slot_cap:
-                    # epoch roll: the device table needs no reset — every
-                    # live slot is redefined before any hot row references it
-                    self.kdict.reset()
-                    self.dict_resets += 1
-                    if self._metrics is not None:
-                        self._metrics.sketch_resident_dict_epochs_total.inc()
-                chunk = self._chunk(trace, 1, not first)
-                try:
-                    slot = self._wait_slot(chunk)
-                except StagingWedged as exc:
-                    # earlier chunks may have dispatched (donating the
-                    # caller's state buffers); hand over the valid state
-                    exc.state = state
-                    raise
-                with self._pack_stage(chunk):
-                    buf, consumed = flowpack.pack_resident(
-                        events, batch_size=self.batch_size, kdict=self.kdict,
-                        caps=self.caps, start=start, out=self._bufs[slot],
-                        **feats)
-                if consumed == 0 and n:
-                    raise RuntimeError("resident pack made no progress")
-                self.spill_rows += int(buf[2])
-                if self._metrics is not None:
-                    if buf[2]:
-                        self._metrics.sketch_resident_spill_rows_total.inc(
-                            int(buf[2]))
-                    if not first:
-                        self._metrics \
-                            .sketch_resident_continuations_total.inc()
-                if not first:
-                    self.continuations += 1
-                first = False
-                start += consumed
-                with chunk.stage("put"):
-                    dev = self._put(buf)
-                with chunk.stage("ingest_dispatch"):
-                    state, self.key_table, token = self._ingest(
-                        state, self.key_table, dev)
-                self._advance(slot, token)
-            return state
-        finally:
-            if owned:
-                trace.finish()

@@ -55,11 +55,6 @@ class SketchConfig(NamedTuple):
     #: (measured faster than the XLA scatter there, docs/tpu_sketch.md);
     #: the scatter everywhere else, incl. CPU where the kernel interprets
     use_pallas: bool | None = None
-    #: False skips the per-source fan-out grid fold (port-scan signal) —
-    #: the bench A/B switch for attributing its ingest cost
-    enable_fanout: bool = True
-    #: False skips the conversation-asymmetry fold (one-way detection)
-    enable_asym: bool = True
     #: tiered counter planes (SKETCH_TIERED, sketch/tiered.py): the
     #: resident form of the CM planes + HLL banks goes narrow (u8 base +
     #: u16/u32 overflow tiers; 6-bit packed HLL registers), decoded to the
@@ -329,8 +324,6 @@ def tiered_fold_form(cfg: SketchConfig) -> str | None:
 def ingest(state: SketchState, arrays: dict[str, jax.Array],
            sketch_axis: str | None = None, sketch_shards: int = 1,
            use_pallas: bool | None = None,
-           enable_fanout: bool = True,
-           enable_asym: bool = True,
            tier_interior: bool | None = None,
            _tier: "_TierHook | None" = None) -> SketchState:
     """Fold one batch into all sketches. Pure; jit with donate_argnums=0.
@@ -382,9 +375,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
                     and signal_kernel.hll_fusible(m_hll))
             hook = _TierHook(state, fuse)
             work = tiered.widen_interior(state, fuse)
-            new_work = ingest(work, arrays, use_pallas=True,
-                              enable_fanout=enable_fanout,
-                              enable_asym=enable_asym, _tier=hook)
+            new_work = ingest(work, arrays, use_pallas=True, _tier=hook)
             return tiered.interior_encode(
                 state, hook.out["cm_bytes"], hook.out["cm_pkts"],
                 hook.out.get("hll_src"), new_work)
@@ -392,9 +383,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
                                        spec.bytes_unit)
         cmp_wide = tiered.decode_plane(state.tables.cm_pkts, spec, 1)
         new_wide = ingest(tiered.widen(state, cmb_wide, cmp_wide), arrays,
-                          use_pallas=use_pallas,
-                          enable_fanout=enable_fanout,
-                          enable_asym=enable_asym)
+                          use_pallas=use_pallas)
         return tiered.fold_encode(state, cmb_wide, cmp_wide, new_wide)
     if use_pallas is None:
         # auto: the fused kernels (Count-Min fold + HLL) win on TPU at and
@@ -500,29 +489,26 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
         per_dst = hll.update_per_dst(state.hll_per_dst, dst_h1, src_h1,
                                      src_h2, valid)
         flags = arrays.get("tcp_flags")
-        if enable_fanout:
-            # port-scan signal: distinct (dst addr, dst port) fan-out per SOURCE
-            # bucket — a scanner touches many; a normal client few. The (dst,
-            # port) hashes come from the shared multi-hash sweep above (seed:
-            # hashing.DSTPORT_FANOUT_SEED). Only INITIATOR-side flows count:
-            # a flow that sent SYN+ACK together (the TcpFlags.SYN_ACK
-            # composite) is a RESPONDER — without the gate a server answering
-            # one NAT'd client churning through hundreds of source ports
-            # sweeps hundreds of distinct (addr, port) pairs and lights the
-            # grid (the nat_churn scenario). Initiators count whether the
-            # handshake completed or not (SYN with or without a later ACK),
-            # so both lone-SYN and full-connect scans fire; flows with no
-            # SYN-side evidence at all (non-TCP rows, mid-capture sessions:
-            # flags without SYN) keep the pre-gate behavior only when they
-            # are not responders.
-            fanout_valid = valid
-            if flags is not None:
-                f32 = flags.astype(jnp.int32)
-                fanout_valid = valid & ((f32 & TcpFlags.SYN_ACK) == 0)
-            per_src = hll.update_per_dst(state.hll_per_src, src_h1, mhash.dp_h1,
-                                         mhash.dp_h2, fanout_valid)
-        else:
-            per_src = state.hll_per_src
+        # port-scan signal: distinct (dst addr, dst port) fan-out per SOURCE
+        # bucket — a scanner touches many; a normal client few. The (dst,
+        # port) hashes come from the shared multi-hash sweep above (seed:
+        # hashing.DSTPORT_FANOUT_SEED). Only INITIATOR-side flows count:
+        # a flow that sent SYN+ACK together (the TcpFlags.SYN_ACK
+        # composite) is a RESPONDER — without the gate a server answering
+        # one NAT'd client churning through hundreds of source ports
+        # sweeps hundreds of distinct (addr, port) pairs and lights the
+        # grid (the nat_churn scenario). Initiators count whether the
+        # handshake completed or not (SYN with or without a later ACK),
+        # so both lone-SYN and full-connect scans fire; flows with no
+        # SYN-side evidence at all (non-TCP rows, mid-capture sessions:
+        # flags without SYN) keep the pre-gate behavior only when they
+        # are not responders.
+        fanout_valid = valid
+        if flags is not None:
+            f32 = flags.astype(jnp.int32)
+            fanout_valid = valid & ((f32 & TcpFlags.SYN_ACK) == 0)
+        per_src = hll.update_per_dst(state.hll_per_src, src_h1, mhash.dp_h1,
+                                     mhash.dp_h2, fanout_valid)
     with jax.named_scope("quantile"):
         rtt = arrays["rtt_us"]
         dns = arrays["dns_latency_us"]
@@ -563,15 +549,14 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             dpf = arrays["drop_packets"].astype(jnp.float32) * mass
             tdb = tdb + jnp.sum(jnp.where(valid, dbf, 0.0))
             tdp = tdp + jnp.sum(jnp.where(valid, dpf, 0.0))
-        if enable_asym:
-            pair_idx = ((src_sym + dst_h1)
-                        & jnp.uint32(state.conv_fwd.shape[0] - 1)
-                        ).astype(jnp.int32)
-            is_fwd = src_sym < dst_h1
-            # self-pairs (src == dst: hairpin NAT, loopback capture) have no
-            # meaningful direction — both ways would land "fwd" and fire a
-            # false one-way alert every window; exclude them from the signal
-            conv_ok = valid & (src_sym != dst_h1)
+        pair_idx = ((src_sym + dst_h1)
+                    & jnp.uint32(state.conv_fwd.shape[0] - 1)
+                    ).astype(jnp.int32)
+        is_fwd = src_sym < dst_h1
+        # self-pairs (src == dst: hairpin NAT, loopback capture) have no
+        # meaningful direction — both ways would land "fwd" and fire a
+        # false one-way alert every window; exclude them from the signal
+        conv_ok = valid & (src_sym != dst_h1)
 
         use_signal_kernel = use_pallas and sketch_axis is None
         if use_signal_kernel:
@@ -607,11 +592,8 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
                 v_cause = jnp.where(valid & (dpf > 0), dpf, 0.0)
             else:
                 cause_idx, v_cause = izeros_b, zeros_b
-            if enable_asym:
-                v_fwd = jnp.where(conv_ok & is_fwd, bytes_f, 0.0)
-                v_rev = jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0)
-            else:
-                pair_idx, v_fwd, v_rev = izeros_b, zeros_b, zeros_b
+            v_fwd = jnp.where(conv_ok & is_fwd, bytes_f, 0.0)
+            v_rev = jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0)
             if dscp is not None:
                 dscp_idx = dscp.astype(jnp.int32) & (N_DSCP - 1)
                 v_dscp = jnp.where(valid, bytes_f, 0.0)
@@ -645,13 +627,10 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             # — the fused kernel above is equivalence-pinned against exactly
             # this path (tests/test_pallas_signal.py)
             ddos = ewma.accumulate(state.ddos, dst_h1, bytes_f, valid)
-            if enable_asym:
-                conv_fwd = state.conv_fwd.at[pair_idx].add(
-                    jnp.where(conv_ok & is_fwd, bytes_f, 0.0), mode="drop")
-                conv_rev = state.conv_rev.at[pair_idx].add(
-                    jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0), mode="drop")
-            else:
-                conv_fwd, conv_rev = state.conv_fwd, state.conv_rev
+            conv_fwd = state.conv_fwd.at[pair_idx].add(
+                jnp.where(conv_ok & is_fwd, bytes_f, 0.0), mode="drop")
+            conv_rev = state.conv_rev.at[pair_idx].add(
+                jnp.where(conv_ok & ~is_fwd, bytes_f, 0.0), mode="drop")
             syn_state, synack_arr = state.syn, state.synack
             if flags is not None:
                 syn_state = ewma.accumulate(state.syn, dst_h1,
@@ -704,8 +683,6 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
 
 def make_ingest_fn(donate: bool = True,
                    use_pallas: bool | None = None,
-                   enable_fanout: bool = True,
-                   enable_asym: bool = True,
                    tier_interior: bool | None = None,
                    name: str = "ingest", tiered: str | None = None):
     """Jitted ingest; donates the state buffers so updates are in-place on
@@ -713,8 +690,6 @@ def make_ingest_fn(donate: bool = True,
     the watch name AND the XLA module's (`jit_<name>`); `tiered` is the
     registry's fold-form attribution (`retrace.watch`)."""
     fn = lambda s, a: ingest(s, a, use_pallas=use_pallas,  # noqa: E731
-                             enable_fanout=enable_fanout,
-                             enable_asym=enable_asym,
                              tier_interior=tier_interior)
     return retrace.jit(fn, name, tiered=tiered,
                        donate_argnums=(0,) if donate else ())
@@ -767,16 +742,13 @@ def make_ingest_compact_fn(batch_size: int, spill_cap: int,
                            donate: bool = True,
                            use_pallas: bool | None = None,
                            with_token: bool = False,
-                           enable_fanout: bool = True,
-                           enable_asym: bool = True,
                            name: str = "ingest_compact",
                            tiered: str | None = None):
     """Jitted `(state, flat compact feed) -> state` (see compact_to_arrays /
     flowpack.pack_compact). `with_token` as in make_ingest_dense_fn."""
     def fn(s, flat):
         arrays = compact_to_arrays(flat, batch_size, spill_cap)
-        s = ingest(s, arrays, use_pallas=use_pallas,
-                   enable_fanout=enable_fanout, enable_asym=enable_asym)
+        s = ingest(s, arrays, use_pallas=use_pallas)
         return (s, flat[:1]) if with_token else s
     return retrace.jit(fn, name, tiered=tiered,
                        donate_argnums=(0,) if donate else ())
@@ -785,30 +757,6 @@ def make_ingest_compact_fn(batch_size: int, spill_cap: int,
 RESIDENT_HDR = 4   # layout twins of flowpack.cc fp_pack_resident
 HOT_WORDS = 3
 NK_WORDS = 11
-
-
-def init_key_table(slot_cap: int) -> jax.Array:
-    """Device twin of the host KeyDict: (slot_cap, 10) u32 key words per
-    slot, updated from the new-key lane and gathered by hot-row slot id.
-    Auxiliary state — NOT part of SketchState (window rolls and checkpoints
-    leave it alone; a fresh process simply starts empty on both sides)."""
-    return jnp.zeros((slot_cap, KEY_WORDS), jnp.uint32)
-
-
-def resident_to_arrays(flat: jax.Array, key_table: jax.Array,
-                       batch_size: int, caps) -> tuple[dict, jax.Array]:
-    """Device-side unpack of the flowpack RESIDENT feed (layout pinned in
-    flowpack.cc fp_pack_resident; host packer flowpack.pack_resident).
-    Scatters the new-key lane into the key table FIRST — a slot referenced
-    by this batch's hot lane may have been defined by this same batch —
-    then gathers full 10-word keys by slot id, decodes the range-coded
-    rtt/dns codes, scatters the sparse dns/drop lanes onto their rows, and
-    concatenates the full-width spill lane. Returns (arrays, new_key_table)
-    for the ordinary ingest: all the row widening happens in HBM, the
-    transfer link only ever saw ~15 bytes/record (byte budget in
-    docs/tpu_sketch.md)."""
-    with jax.named_scope("resident_decode"):
-        return _resident_region_arrays(flat, key_table, batch_size, caps)
 
 
 def _region_nk(flat: jax.Array, batch_size: int, caps,
@@ -825,18 +773,17 @@ def _region_nk(flat: jax.Array, batch_size: int, caps,
 
 
 def _resident_region_arrays(flat: jax.Array, key_tables: jax.Array,
-                            batch_size: int, caps,
-                            lane: int | None = None,
-                            nk_applied: bool = False) -> tuple[dict,
-                                                               jax.Array]:
-    """One resident region against its key table. `lane=None`: key_tables
-    is a single (slot_cap, KW) table; otherwise it is the SHARED
-    (L, slot_cap, KW) per-lane array and this region uses row `lane`.
-    `nk_applied=True` skips the new-key scatter — the caller already
-    applied every region's new-key lane in one combined scatter
-    (`resident_lane_arrays`), which XLA updates in place under donation
-    (a per-region scatter/gather CHAIN was measured to copy the full
-    shared table once per region on the ladder path)."""
+                            batch_size: int, caps, lane: int) -> dict:
+    """One resident region's rows as an array dict (layout pinned in
+    flowpack.cc fp_pack_resident; host packer flowpack.pack_resident):
+    gathers full 10-word keys by slot id from row `lane` of the SHARED
+    (L, slot_cap, KW) per-lane key tables, decodes the range-coded rtt/dns
+    codes, scatters the sparse dns/drop lanes onto their rows, and
+    concatenates the full-width spill lane. The region's new-key lane is
+    NOT read here: the caller has already applied every region's in one
+    combined scatter (`resident_lane_arrays`), which XLA updates in place
+    under donation (a per-region scatter/gather CHAIN was measured to copy
+    the full shared table once per region on the ladder path)."""
     hot_off = RESIDENT_HDR
     dns_off = hot_off + batch_size * HOT_WORDS
     drop_off = dns_off + caps.dns
@@ -846,26 +793,12 @@ def _resident_region_arrays(flat: jax.Array, key_tables: jax.Array,
     hot = flat[hot_off:dns_off].reshape(batch_size, HOT_WORDS)
     dnsl = flat[dns_off:drop_off]
     dropl = flat[drop_off:nk_off].reshape(caps.drop, 2)
-    nk = flat[nk_off:spill_off].reshape(caps.nk, NK_WORDS)
     spill = dense_to_arrays(flat[spill_off:].reshape(caps.spill, DENSE_WORDS))
 
-    slot_cap = key_tables.shape[-2]
-    nk_def = (nk[:, 0] >> 31) != 0
-    # undefined rows index out of range -> mode="drop" discards the write
-    nk_slot = jnp.where(nk_def, nk[:, 0] & jnp.uint32(0xFFFFF),
-                        jnp.uint32(slot_cap)).astype(jnp.int32)
     w0 = hot[:, 0]
     valid = (w0 >> 31) != 0
     slots = (w0 & jnp.uint32(0xFFFFF)).astype(jnp.int32)
-    if lane is None:
-        if not nk_applied:
-            key_tables = key_tables.at[nk_slot].set(nk[:, 1:], mode="drop")
-        keys = key_tables[slots]
-    else:
-        if not nk_applied:
-            key_tables = key_tables.at[lane, nk_slot].set(nk[:, 1:],
-                                                          mode="drop")
-        keys = key_tables[lane, slots]
+    keys = key_tables[lane, slots]
     rtt = (((w0 >> 20) & jnp.uint32(0xFF))
            << (2 * ((w0 >> 28) & jnp.uint32(0x7)))).astype(jnp.int32)
     w2 = hot[:, 2]
@@ -900,8 +833,7 @@ def _resident_region_arrays(flat: jax.Array, key_tables: jax.Array,
         "drop_packets": drop_pkts,
         "drop_cause": drop_cause,
     }
-    arrays = {k: jnp.concatenate([comp[k], spill[k]], axis=0) for k in comp}
-    return arrays, key_tables
+    return {k: jnp.concatenate([comp[k], spill[k]], axis=0) for k in comp}
 
 
 def init_key_tables(n_lanes: int, slot_cap: int) -> jax.Array:
@@ -924,11 +856,13 @@ def resident_lane_arrays(flat: jax.Array, key_tables: jax.Array,
                          batch_per_lane: int, caps,
                          n_lanes: int) -> tuple[dict, jax.Array]:
     """Unpack `n_lanes` concatenated resident regions against per-lane key
-    tables into ONE array dict for the ordinary ingest. The three-place wire
-    contract (flowpack.cc fp_pack_resident <-> flowpack.pack_resident <->
-    resident_to_arrays) is unchanged PER REGION — this only loops it and
-    concatenates the resulting fixed-shape columns, so the jitted caller
-    still never retraces. Returns (arrays, new_key_tables).
+    tables into ONE array dict for the ordinary ingest — the device end of
+    the three-place wire contract (flowpack.cc fp_pack_resident <->
+    flowpack.pack_resident <-> here), which holds PER REGION: regions are
+    looped and their fixed-shape columns concatenated, so the jitted caller
+    never retraces. All the row widening happens in HBM; the transfer link
+    only ever saw ~15 bytes/record (byte budget in docs/tpu_sketch.md).
+    Returns (arrays, new_key_tables).
 
     `key_tables` may carry MORE rows than `n_lanes` (the superbatch fold
     ladder: every ladder entry shares ONE per-region table array sized for
@@ -956,11 +890,8 @@ def _resident_lane_arrays(flat, key_tables, batch_per_lane, caps, n_lanes):
     key_tables = key_tables.at[
         lane_ids, jnp.concatenate([s for s, _ in nk_parts])].set(
         jnp.concatenate([w for _, w in nk_parts]), mode="drop")
-    lanes = []
-    for i, r in enumerate(regions):
-        arrays, key_tables = _resident_region_arrays(
-            r, key_tables, batch_per_lane, caps, lane=i, nk_applied=True)
-        lanes.append(arrays)
+    lanes = [_resident_region_arrays(r, key_tables, batch_per_lane, caps, i)
+             for i, r in enumerate(regions)]
     if n_lanes == 1:
         return lanes[0], key_tables
     out = {k: jnp.concatenate([a[k] for a in lanes], axis=0)
@@ -971,8 +902,6 @@ def _resident_lane_arrays(flat, key_tables, batch_per_lane, caps, n_lanes):
 def make_ingest_resident_lanes_fn(batch_per_lane: int, caps, n_lanes: int,
                                   donate: bool = True,
                                   use_pallas: bool | None = None,
-                                  enable_fanout: bool = True,
-                                  enable_asym: bool = True,
                                   name: str = "ingest_resident_lanes",
                                   tiered: str | None = None):
     """Jitted `(state, key_tables, flat) -> (state, key_tables, token)` for
@@ -985,31 +914,8 @@ def make_ingest_resident_lanes_fn(batch_per_lane: int, caps, n_lanes: int,
     def fn(s, tables, flat):
         arrays, tables = resident_lane_arrays(flat, tables, batch_per_lane,
                                               caps, n_lanes)
-        s = ingest(s, arrays, use_pallas=use_pallas,
-                   enable_fanout=enable_fanout, enable_asym=enable_asym)
+        s = ingest(s, arrays, use_pallas=use_pallas)
         return s, tables, flat[:1]
-    return retrace.jit(fn, name, tiered=tiered,
-                       donate_argnums=(0, 1) if donate else ())
-
-
-def make_ingest_resident_fn(batch_size: int, caps,
-                            donate: bool = True,
-                            use_pallas: bool | None = None,
-                            with_token: bool = False,
-                            enable_fanout: bool = True,
-                            enable_asym: bool = True,
-                            name: str = "ingest_resident",
-                            tiered: str | None = None):
-    """Jitted `(state, key_table, flat resident feed) -> (state, key_table
-    [, token])` — the lowest-bytes-per-record host feed (see
-    resident_to_arrays / flowpack.pack_resident). The key table is threaded
-    alongside the sketch state (both donated) so table updates are in-place
-    HBM scatters."""
-    def fn(s, table, flat):
-        arrays, table = resident_to_arrays(flat, table, batch_size, caps)
-        s = ingest(s, arrays, use_pallas=use_pallas,
-                   enable_fanout=enable_fanout, enable_asym=enable_asym)
-        return (s, table, flat[:1]) if with_token else (s, table)
     return retrace.jit(fn, name, tiered=tiered,
                        donate_argnums=(0, 1) if donate else ())
 
@@ -1017,8 +923,6 @@ def make_ingest_resident_fn(batch_size: int, caps,
 def make_ingest_dense_fn(donate: bool = True,
                          use_pallas: bool | None = None,
                          with_token: bool = False,
-                         enable_fanout: bool = True,
-                         enable_asym: bool = True,
                          name: str = "ingest_dense",
                          tiered: str | None = None):
     """Jitted `(state, dense (B,20)u32) -> state` — the single-transfer host
@@ -1030,14 +934,11 @@ def make_ingest_dense_fn(donate: bool = True,
     slot-reuse guard for `sketch.staging.DenseStagingRing`."""
     if with_token:
         def fn(s, d):
-            return ingest(s, dense_to_arrays(d), use_pallas=use_pallas,
-                          enable_fanout=enable_fanout,
-                          enable_asym=enable_asym), d.reshape(-1)[:1]
+            return ingest(s, dense_to_arrays(d),
+                          use_pallas=use_pallas), d.reshape(-1)[:1]
     else:
         fn = lambda s, d: ingest(s, dense_to_arrays(d),  # noqa: E731
-                                 use_pallas=use_pallas,
-                                 enable_fanout=enable_fanout,
-                                 enable_asym=enable_asym)
+                                 use_pallas=use_pallas)
     return retrace.jit(fn, name, tiered=tiered,
                        donate_argnums=(0,) if donate else ())
 

@@ -110,9 +110,7 @@ class TenantStack(_SlotRing):
 
         def one(s, flat):
             return sk.ingest(s, sk.dense_to_arrays(flat),
-                             use_pallas=cfg.use_pallas,
-                             enable_fanout=cfg.enable_fanout,
-                             enable_asym=cfg.enable_asym)
+                             use_pallas=cfg.use_pallas)
 
         def ingest_fn(s, dense):
             # dense: (N, B*20) u32 — flat per tenant lane (the same
@@ -144,7 +142,7 @@ class TenantStack(_SlotRing):
               quic=None) -> tuple[np.ndarray, np.ndarray]:
         """Pack `events` once to dense rows and derive each row's tenant
         owner. Returns (rows (M, 20) u32, owners int32[M]). Split out so
-        tests (and the bench) reuse the exact production routing."""
+        tests reuse the exact production routing."""
         rows = flowpack.pack_dense(events, batch_size=max(len(events), 1),
                                    extra=extra, dns=dns, drops=drops,
                                    xlat=xlat, quic=quic)

@@ -141,8 +141,8 @@ class TieredState:
     def tree_unflatten(cls, spec, children):
         return cls(children[0], children[1], spec)
 
-    # ergonomic pass-throughs for the fields that stay wide (bench recall,
-    # exporters reading the window counter)
+    # ergonomic pass-throughs for the fields that stay wide (exporters
+    # reading the window counter)
     @property
     def heavy(self):
         return self.rest.heavy
@@ -412,11 +412,11 @@ def fold_encode(ts: TieredState, cmb_wide: jax.Array, cmp_wide: jax.Array,
 
 
 # --------------------------------------------------------------------------
-# accounting (the bench/metrics surface — host-side, never on the fold path)
+# accounting (the metrics surface — host-side, never on the fold path)
 # --------------------------------------------------------------------------
 
 #: the sketch tables the tiered representation covers — the byte-reduction
-#: claim in the bench artifact is computed over exactly these
+#: claim (tests/test_tiered.py) is computed over exactly these
 COUNTER_TABLES = ("cm_bytes", "cm_pkts", "hll_src", "hll_per_dst",
                   "hll_per_src")
 
@@ -440,20 +440,3 @@ def counter_table_bytes(state) -> dict[str, int]:
             "hll_per_dst": array_bytes(state.hll_per_dst),
             "hll_per_src": array_bytes(state.hll_per_src)}
 
-
-def plane_occupancy(plane: TieredPlane) -> dict[str, int]:
-    """Host-side tier occupancy of one CM plane (device->host transfer —
-    bench/publish time only)."""
-    base = np.asarray(plane.base)
-    mid = np.asarray(plane.mid)
-    top = np.asarray(plane.top)
-    return {
-        "base_counters": int(base.size),
-        "promoted": int((base == BASE_MAX).sum()),
-        "mid_cells": int(mid.size),
-        "mid_active": int((mid > 0).sum()),
-        "mid_saturated": int((mid == MID_MAX).sum()),
-        "top_cells": int(top.size),
-        "top_active": int((top > 0).sum()),
-        "top_saturated": int((top == TOP_MAX).sum()),
-    }

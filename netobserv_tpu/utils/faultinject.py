@@ -5,7 +5,7 @@ never per record): a stage loop calls ``fire("map_tracer.evict")`` and, when
 that point is armed, the call raises, hangs, delays, or corrupts a payload.
 Disarmed (the default, and always when ``FAULT_POINTS`` is unset) a fire is
 a single module-bool check and an immediate return — zero allocations, no
-locks, nothing on the bench host path.
+locks, nothing on the host path.
 
 Arming:
 

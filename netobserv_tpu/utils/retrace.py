@@ -35,8 +35,7 @@ writes plus one monotonic-clock pair per call — per *batch*, never per
 record.
 
 Beyond the alarm, the wrapper IS the per-executable accounting registry
-(``/debug/executables`` on agent and aggregator, stamped into bench
-artifacts): per watched jit it tracks dispatch count, cumulative dispatch
+(``/debug/executables`` on agent and aggregator): per watched jit it tracks dispatch count, cumulative dispatch
 wall seconds (fed to ``executable_dispatch_seconds_total{fn=...}`` when a
 Metrics facade is bound), cumulative compile seconds (the lowering
 listener's duration, warmup included), the last abstract-shape signature

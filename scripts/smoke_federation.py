@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Federation smoke: two in-process agents -> local aggregator -> query.
 
-`make smoke-federation` (non-gating CI artifact, like bench-host/
-bench-evict): spins up a FederationAggregatorService on ephemeral ports,
-two TpuSketchExporters pushing delta frames through the REAL gRPC seam,
+`make smoke-federation` (non-gating CI artifact): spins up a
+FederationAggregatorService on ephemeral ports, two TpuSketchExporters pushing delta frames through the REAL gRPC seam,
 folds a deterministic record stream through each, flushes both windows,
 and asserts the cluster-wide /federation/topk answer merges both agents'
 traffic. Prints ONE JSON line with what it saw.

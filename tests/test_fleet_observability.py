@@ -435,14 +435,12 @@ class TestExecutableRegistry:
         finally:
             retrace.set_metrics(None)
 
-    def test_bench_snapshot_matches_debug_route(self):
-        """bench.py stamps the SAME registry view /debug/executables
-        serves — one truth for the accounting."""
-        import bench
-
+    def test_snapshot_matches_debug_route(self):
+        """`retrace.snapshot()` is the SAME registry view
+        /debug/executables serves — one truth for the accounting."""
         from netobserv_tpu.server.debug import _executables_dump
 
-        stamped = bench.executables_snapshot()
+        stamped = retrace.snapshot()
         served = json.loads(_executables_dump({}))
         assert [r["fn"] for r in served["executables"]] == \
             [r["fn"] for r in stamped]

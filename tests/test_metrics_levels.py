@@ -109,16 +109,18 @@ def test_resident_staging_metrics_surface():
 
     from netobserv_tpu.metrics.registry import Metrics, MetricsSettings
     from netobserv_tpu.sketch import state as sk
-    from netobserv_tpu.sketch.staging import ResidentStagingRing
+    from netobserv_tpu.sketch.staging import ShardedResidentStagingRing
 
     if not flowpack.build_native():
         pytest.skip("native flowpack unavailable")
     m = Metrics(MetricsSettings(level="info"), registry=CollectorRegistry())
     B = 256
     caps = flowpack.ResidentCaps(dns=8, drop=8, nk=8, spill=4)  # tiny lanes
-    ring = ResidentStagingRing(
-        B, sk.make_ingest_resident_fn(B, caps, with_token=True),
-        caps=caps, slot_cap=64, metrics=m)
+    import jax
+    ring = ShardedResidentStagingRing(
+        B, 1, sk.make_ingest_resident_lanes_fn(B, caps, 1),
+        key_tables=jax.device_put(sk.init_key_tables(1, 64)),
+        put=jax.device_put, caps=caps, slot_cap=64, metrics=m)
     state = sk.init_state(sk.SketchConfig(
         cm_depth=2, cm_width=1 << 10, hll_precision=6, perdst_buckets=32,
         perdst_precision=4, topk=16, hist_buckets=64, ewma_buckets=32))

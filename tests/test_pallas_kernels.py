@@ -210,23 +210,3 @@ def test_use_pallas_auto_policy():
         assert SketchConfig.from_agent_config(cfg).use_pallas is want, \
             spelling
 
-
-def test_hll_grid_kernel_matches_scatter():
-    """The flat-indexed grid fold (interpret mode on CPU) must equal the
-    XLA scatter grid update bit-for-bit."""
-    import numpy as np
-
-    from netobserv_tpu.ops import hashing, hll
-    from netobserv_tpu.ops.pallas import hll_kernel
-
-    rng = np.random.default_rng(5)
-    n = 512
-    dsts = jnp.asarray(rng.integers(0, 2**32, (n, 4), dtype=np.uint32))
-    srcs = jnp.asarray(rng.integers(0, 2**32, (n, 4), dtype=np.uint32))
-    valid = jnp.asarray(rng.random(n) < 0.9)
-    dh, _ = hashing.base_hashes(dsts, seed=1)
-    sh1, sh2 = hashing.base_hashes(srcs)
-    s0 = hll.init_per_dst(dst_buckets=32, precision=4)  # 32*16=512 lanes
-    ref = hll.update_per_dst(s0, dh, sh1, sh2, valid)
-    got = hll_kernel.update_per_dst(s0, dh, sh1, sh2, valid, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref.regs), np.asarray(got.regs))

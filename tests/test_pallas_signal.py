@@ -145,19 +145,3 @@ def test_full_ingest_signal_planes_bit_exact_vs_unfused():
         np.testing.assert_allclose(np.asarray(ref.cm_bytes.counts),
                                    np.asarray(pal.cm_bytes.counts),
                                    rtol=1e-6)
-
-
-def test_full_ingest_signal_planes_asym_off():
-    """enable_asym=False must leave conv planes untouched on BOTH paths."""
-    cfg = sk.SketchConfig(cm_width=1024, topk=16, hll_precision=10,
-                          perdst_buckets=32, perdst_precision=4,
-                          persrc_buckets=32, persrc_precision=4,
-                          hist_buckets=64, ewma_buckets=M)
-    arrays = _arrays(512, seed=6)
-    for pallas in (False, True):
-        s = jax.jit(lambda st, a: sk.ingest(st, a, use_pallas=pallas,
-                                            enable_asym=False))(
-            sk.init_state(cfg), arrays)
-        assert not np.asarray(s.conv_fwd).any()
-        assert not np.asarray(s.conv_rev).any()
-        assert np.asarray(s.synack).any()  # other signals still fold

@@ -477,7 +477,7 @@ def test_ingest_never_retraces_across_windows():
     for n in (64, 17):
         exp.export_evicted(EvictedFlows(make_events(n)))
         exp.flush()
-    ingest_jit = exp._ring._ingest
+    ingest_jit = exp._ring._ingests[1]  # batch-sized evictions: x1 only
     warm = ingest_jit._cache_size()
     assert warm <= 2, f"ingest compiled {warm} variants during warmup"
     for n in (64, 3, 64, 17, 5):

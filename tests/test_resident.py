@@ -4,7 +4,7 @@ The resident feed is the lowest-bytes-per-record host->device path
 (~15B/record at production batch size; byte budget in docs/tpu_sketch.md):
 hot rows carry a 20-bit slot id into a device-resident key table instead of
 the 10 key words (flowpack.cc fp_pack_resident <-> flowpack.pack_resident
-<-> sketch.state.resident_to_arrays). These tests pin:
+<-> sketch.state.resident_lane_arrays). These tests pin:
 - native C++ packer == pure-python twin, byte for byte, dict state included
 - folding through the resident ring == folding the same batches dense, for
   every exact-path signal (CM planes, top-K, totals, drops, flags); the
@@ -130,13 +130,10 @@ def _fold_both_ways(feed, slot_cap=1 << 12, caps=None):
     import jax
 
     from netobserv_tpu.sketch import state as sk
-    from netobserv_tpu.sketch.staging import ResidentStagingRing
 
-    caps = caps or flowpack.default_resident_caps(B)
     cfg = sk.SketchConfig()
-    ring = ResidentStagingRing(
-        B, sk.make_ingest_resident_fn(B, caps, with_token=True),
-        caps=caps, slot_cap=slot_cap)
+    # one shard, one lane, ladder (1,): the ring the exporter serves
+    ring = _lane_ring(1, caps=caps, slot_cap=slot_cap)
     dense_fn = sk.make_ingest_dense_fn(with_token=True)
     s_r, s_d = sk.init_state(cfg), sk.init_state(cfg)
     for events, feats in feed:
@@ -402,10 +399,10 @@ def test_zero_resident_region_masks_garbage_exactly():
                           hll_precision=6, perdst_buckets=32,
                           perdst_precision=4, persrc_buckets=32,
                           persrc_precision=4, hist_buckets=64)
-    fn = sk.make_ingest_resident_fn(bs, caps, donate=False)
-    table = jax.device_put(sk.init_key_table(64))
-    s_g, t_g = fn(sk.init_state(cfg), table, jax.device_put(garbage))
-    s_z, t_z = fn(sk.init_state(cfg), table, jax.device_put(zeros))
+    fn = sk.make_ingest_resident_lanes_fn(bs, caps, 1, donate=False)
+    table = jax.device_put(sk.init_key_tables(1, 64))
+    s_g, t_g, _ = fn(sk.init_state(cfg), table, jax.device_put(garbage))
+    s_z, t_z, _ = fn(sk.init_state(cfg), table, jax.device_put(zeros))
     np.testing.assert_array_equal(np.asarray(t_g), np.asarray(t_z))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), s_g, s_z)

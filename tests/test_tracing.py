@@ -595,6 +595,50 @@ def test_exporter_full_cycle_stays_retrace_silent():
     assert retrace.total_retraces() == before
 
 
+def test_default_one_device_exporter_registers_exactly_the_served_entries(
+        monkeypatch):
+    """What `/debug/executables` lists for the exporter `from_config` builds
+    on ONE device at the default feed and ladder, after a warm ladder, an
+    eviction and a roll: the dict-arrays `ingest`, one resident entry per
+    ladder size, and `roll` — no more (a second form of a served entry
+    would be a second program to keep warm) and no fewer (a deleted factory
+    cannot silently take a served entry with it)."""
+    import jax
+
+    from netobserv_tpu.config import load_config
+    from netobserv_tpu.datapath.replay import SyntheticFetcher
+    from netobserv_tpu.exporter.tpu_sketch import TpuSketchExporter
+    from netobserv_tpu.server.debug import _executables_dump
+
+    real_devices = jax.devices
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: real_devices(*a, **k)[:1])
+    before = {id(w) for w in retrace.watched()}
+    cfg = load_config({
+        "EXPORT": "tpu-sketch", "SKETCH_WINDOW": "1h",
+        "SKETCH_BATCH_SIZE": "512", "SKETCH_CM_WIDTH": "1024",
+        "SKETCH_TOPK": "64", "SKETCH_HLL_PRECISION": "8",
+        "SKETCH_RESIDENT_SLOTS": "4096"})
+    exp = TpuSketchExporter.from_config(cfg, sink=lambda obj: None)
+    try:
+        exp._warm_thread.join()
+        exp.export_evicted(SyntheticFetcher(
+            flows_per_eviction=700, n_distinct=100).lookup_and_delete())
+        exp.flush()
+        mine = {w.name: w for w in retrace.watched() if id(w) not in before}
+        assert sorted(mine) == [
+            "ingest", "ingest_resident_lanes_x1", "ingest_resident_lanes_x2",
+            "ingest_resident_lanes_x4", "roll"]
+        # the served ones ran; the dict-arrays entry is built, never called
+        assert {n for n, w in mine.items() if w.calls} == set(mine) - {
+            "ingest"}
+        served = {r["fn"] for r in
+                  json.loads(_executables_dump({}))["executables"]}
+        assert set(mine) <= served
+    finally:
+        exp.close()
+
+
 # --- the stages on the profiler's clock, with ids --------------------------
 
 def _resident_exporter(metrics=None):
