@@ -2,10 +2,12 @@
 
 **SlotTable**: a SpaceSaving-style d-way set-associative slot table whose
 rows keep STABLE identity across batch folds and across window rolls.
-Candidate maintenance happens in the per-batch update path (`slot_update`,
-with a fused Pallas reduction twin in `ops/pallas/topk_kernel.py`), so a
-window roll ships a READY top-K with per-slot churn metadata (`counts`,
-`prev_counts`, `first_seen`, `epoch`) — no host post-pass. Counts are
+Candidate maintenance happens in the per-batch update path (`slot_update`:
+the gather/scatter form here, and its Pallas twin
+`ops/pallas/topk_kernel.py`, which classifies and reduces a round inside
+one batch walk with the table in VMEM), so a window roll ships a READY
+top-K with per-slot churn metadata (`counts`, `prev_counts`, `first_seen`,
+`epoch`) — no host post-pass. Counts are
 Count-Min point estimates, so the CM error bound (count <= true + e/w * N
 with prob 1-e^-d) carries over verbatim.
 
@@ -48,8 +50,8 @@ NO_WINNER = 0x7FFFFFFF
 #: round — its min-defense candidate is recomputed, so it usually lands
 #: in a still-empty slot. Two rounds make single-appearance insertion
 #: near-complete (a sustained stream's keys also re-challenge at their
-#: next appearance); the rounds share the same prepare/reduce/compose,
-#: so the two-form invariant holds per round
+#: next appearance); each round is classify + reduce + compose in either
+#: form, so the two-form invariant holds per round
 SLOT_ROUNDS = 2
 
 
@@ -90,54 +92,67 @@ def init_slots(k: int = 1024, key_words: int = 10) -> SlotTable:
     )
 
 
-def slot_candidates(h1: jax.Array, h2: jax.Array, k: int) -> jax.Array:
-    """The SLOT_WAYS candidate slots of each key identity: int32[B, WAYS].
-
-    Kirsch–Mitzenmacher over a slot-family remix of (h1, h2); the stride is
-    forced odd so the WAYS candidates are distinct mod the power-of-two K."""
+def slot_strides(h1: jax.Array, h2: jax.Array
+                 ) -> tuple[jax.Array, jax.Array]:
+    """(s1, s2) uint32[B]: a key identity's first candidate slot and its
+    stride, before the reduction mod K. Kirsch–Mitzenmacher over a
+    slot-family remix of (h1, h2); the stride is forced odd so the WAYS
+    candidates are distinct mod the power-of-two K."""
     s1 = hashing.fmix32(h1 ^ jnp.uint32(_SLOT_SEED))
     s2 = hashing.fmix32(h2 ^ jnp.uint32(_SLOT_SEED * 2 + 1)) | jnp.uint32(1)
+    return s1, s2
+
+
+def slot_candidates(h1: jax.Array, h2: jax.Array, k: int) -> jax.Array:
+    """The SLOT_WAYS candidate slots of each key identity: int32[B, WAYS],
+    `(s1 + way * s2) mod K` of `slot_strides`."""
+    s1, s2 = slot_strides(h1, h2)
     ways = jnp.arange(SLOT_WAYS, dtype=jnp.uint32)
     return ((s1[:, None] + ways[None, :] * s2[:, None])
             & jnp.uint32(k - 1)).astype(jnp.int32)
 
 
+def slot_defense(table: SlotTable) -> jax.Array:
+    """What each slot holds against a challenger: f32[K], the occupant's
+    `max(counts, prev_counts)` (a persistent heavy defends with last
+    window's mass right after a roll zeroes `counts`, while in decay/keep
+    modes — where `counts` already folds history — the max avoids
+    double-counting the same mass into the defense); invalid slots defend
+    with -1 and fill first. Counts are never negative and the clamp says
+    so on any table: a slot is valid exactly where its defense is >= 0,
+    which is how both forms read validity. A function of the slot alone:
+    computed once over K slots, never per (row, way)."""
+    held = jnp.maximum(jnp.maximum(table.counts, table.prev_counts), 0.0)
+    return jnp.where(table.valid, held, -1.0)
+
+
 def slot_prepare(table: SlotTable, h1: jax.Array, h2: jax.Array,
                  est: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """The SHARED per-row preamble of both slot-maintenance forms.
+    """The per-row preamble of the gather/scatter form (the walk kernel
+    classifies the same way inside its batch walk).
 
     Against the PRE-batch table, classify every batch row:
 
-    - `mslot` int32[B]: the slot this row's key already occupies (its count
-      refreshes to the new CM estimate), or K for rows with no slot;
+    - `mslot` int32[B]: the slot this row's key already occupies AMONG ITS
+      CANDIDATES (its count refreshes to the new CM estimate), or K for
+      rows with no such slot (`merge_slot_tables` places keys by rank, so a
+      table can hold a key outside its candidates: that is no match);
     - `target` int32[B]: the weakest candidate slot this row CHALLENGES
-      (defense = occupant's `max(counts, prev_counts)`: a persistent
-      heavy defends with last window's mass right after a roll zeroes
-      `counts`, while in decay/keep modes — where `counts` already folds
-      history — the max avoids double-counting the same mass twice into
-      the defense; invalid slots defend with -1 and fill first), or K
-      when the row matched, is dead (est <= 0), or its estimate does not
-      beat the defense.
-
-    Everything downstream — the scatter reduction and the Pallas kernel —
-    consumes only (mslot, target, est), which is what makes the two forms
-    bit-exact by construction."""
+      (lowest `slot_defense`, ties to the lowest way), or K when the row
+      matched, is dead (est <= 0), or its estimate does not beat the
+      defense."""
     k = table.k
     live = est > 0.0
     cands = slot_candidates(h1, h2, k)                       # [B, WAYS]
-    occ_h1 = table.h1[cands]
-    occ_h2 = table.h2[cands]
-    occ_valid = table.valid[cands]
-    match_way = occ_valid & (occ_h1 == h1[:, None]) & (occ_h2 == h2[:, None])
+    defense = slot_defense(table)[cands]
+    match_way = ((defense >= 0.0) & (table.h1[cands] == h1[:, None])
+                 & (table.h2[cands] == h2[:, None]))
     matched = live & jnp.any(match_way, axis=1)
-    # at most one way can match (a key occupies at most one slot); argmax
-    # picks the first True way
+    # at most one way can match (a key occupies at most one candidate);
+    # argmax picks the first True way
     mslot = jnp.take_along_axis(
         cands, jnp.argmax(match_way, axis=1)[:, None], axis=1)[:, 0]
     mslot = jnp.where(matched, mslot, k)
-    defense = jnp.where(occ_valid,
-                        jnp.maximum(table.counts[cands],
-                                    table.prev_counts[cands]), -1.0)
     tj = jnp.argmin(defense, axis=1)                         # ties -> low way
     target = jnp.take_along_axis(cands, tj[:, None], axis=1)[:, 0]
     tdef = jnp.take_along_axis(defense, tj[:, None], axis=1)[:, 0]
@@ -222,27 +237,30 @@ def slot_update(table: SlotTable, cm: countmin.CountMin, words: jax.Array,
     """Fold one batch (whose mass is already in `cm`) into the slot table.
 
     `query_fn(h1, h2) -> est` overrides the plain CM point query
-    (owner-sharded sketches). `use_pallas` routes the per-slot reductions
-    through the fused batch-walk kernel (`ops/pallas/topk_kernel.py`) —
-    bit-exact against the scatter form by the two-form invariant; the
-    preamble and compose are literally shared code.
+    (owner-sharded sketches). `use_pallas` classifies and reduces each
+    round inside the batch-walk kernel (`ops/pallas/topk_kernel.py`), the
+    table held in VMEM, instead of `slot_prepare`'s gathers and the scatter
+    reductions — the same three reductions bit for bit (the two-form
+    invariant), so the same table after every round; compose is shared.
 
     Returns (new table, f32 count of valid occupants evicted)."""
     if query_fn is None:
         query_fn = lambda a, b: countmin.query(cm, a, b)  # noqa: E731
     est = jnp.where(valid, query_fn(h1, h2), -1.0)
+    if use_pallas:
+        from netobserv_tpu.ops.pallas import topk_kernel
+        packed = topk_kernel.pack_rows(h1, h2, est, table.k)
+
+        def reductions(t):
+            return topk_kernel.walk(t, *packed)
+    else:
+        def reductions(t):
+            return _slot_reduce_scatter(*slot_prepare(t, h1, h2, est), est,
+                                        t.k)
     evicted = jnp.zeros((), jnp.float32)
     for _ in range(SLOT_ROUNDS):
-        mslot, target = slot_prepare(table, h1, h2, est)
-        if use_pallas:
-            from netobserv_tpu.ops.pallas import topk_kernel
-            match_max, chall_max, win_row = topk_kernel.reduce(
-                mslot, target, est, table.k)
-        else:
-            match_max, chall_max, win_row = _slot_reduce_scatter(
-                mslot, target, est, table.k)
-        table, ev = slot_compose(table, match_max, chall_max, win_row,
-                                 words, h1, h2, window)
+        table, ev = slot_compose(table, *reductions(table), words, h1, h2,
+                                 window)
         evicted = evicted + ev
     return table, evicted
 

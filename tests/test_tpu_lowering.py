@@ -29,7 +29,7 @@ from netobserv_tpu.sketch import state as sk, tenancy, tiered
 from netobserv_tpu.utils import platform
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-#: countmin.update_two, hll.update, topk.reduce x SLOT_ROUNDS, signal.update
+#: countmin.update_two, hll.update, topk.walk x SLOT_ROUNDS, signal.update
 MOSAIC_CALLS = 5
 CFG = sk.SketchConfig()
 BATCH = 8192
@@ -152,7 +152,7 @@ def test_fold_ops_carry_one_scope_per_sketch_update_and_kernels_their_names():
         for b in FOLD_SCOPES | {"resident_decode"}:
             assert f"/{a}/{b}/" not in text, (a, b)
     kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
-    assert kernels == {"countmin_update_two", "hll_update", "topk_reduce",
+    assert kernels == {"countmin_update_two", "hll_update", "topk_slot_walk",
                        "signal_update"}
 
 
