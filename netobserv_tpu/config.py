@@ -317,6 +317,15 @@ class AgentConfig:  # noqa: PLR0902 - deliberately wide, mirrors reference
     # --- TPU sketch backend (new; no reference equivalent) ---
     sketch_batch_size: int = field(default=8192, **_env("SKETCH_BATCH_SIZE", "8192"))
     sketch_cm_depth: int = field(default=4, **_env("SKETCH_CM_DEPTH", "4"))
+    #: Count-Min width W (power of two): a point query overestimates by at
+    #: most e/W of the window's total with probability 1 - e^-depth, so size
+    #: W for the DISTINCT keys a window holds — one counter a depth row for
+    #: every 4 live keys keeps the tail's answers inside BASELINE.json's
+    #: < 1% recall loss (docs/tpu_sketch.md "Sizing the sketch for a key
+    #: count"): 65,536 for a node's few hundred thousand flows, 2^20-2^22
+    #: for a cluster collector's millions. On the device: 2 planes x depth x
+    #: W x 4 B (2 MB at the default, 134 MB at 2^22). The fold picks the
+    #: Count-Min form from W by itself (sketch/state.fold_forms).
     sketch_cm_width: int = field(default=65536, **_env("SKETCH_CM_WIDTH", "65536"))
     sketch_hll_precision: int = field(default=14, **_env("SKETCH_HLL_PRECISION", "14"))
     sketch_topk: int = field(default=1024, **_env("SKETCH_TOPK", "1024"))
@@ -325,7 +334,8 @@ class AgentConfig:  # noqa: PLR0902 - deliberately wide, mirrors reference
     sketch_checkpoint_dir: str = field(default="", **_env("SKETCH_CHECKPOINT_DIR"))
     sketch_checkpoint_every: int = field(default=0, **_env("SKETCH_CHECKPOINT_EVERY", "0"))
     sketch_mesh_shape: str = field(default="", **_env("SKETCH_MESH_SHAPE"))  # e.g. "2x4"
-    #: auto (default) = fused MXU kernels on TPU at widths >= 16K, XLA
+    #: auto (default) = the Pallas kernels on TPU at Count-Min widths >= 16K,
+    #: the Count-Min form following the width (sketch/state.fold_forms), XLA
     #: scatter elsewhere; true/false (any bool spelling) force one path
     sketch_use_pallas: str = field(default="auto",
                                    **_env("SKETCH_USE_PALLAS", "auto"))
@@ -420,7 +430,14 @@ class AgentConfig:  # noqa: PLR0902 - deliberately wide, mirrors reference
     sketch_feed: str = field(default="resident", **_env("SKETCH_FEED", "resident"))
     #: resident-feed key-table capacity (slots; power of two <= 2^20).
     #: A full dictionary rolls its epoch — size it above the flow-cache
-    #: working set (CACHE_MAX_FLOWS)
+    #: working set (CACHE_MAX_FLOWS): rows reach the pack regions by
+    #: position, so EACH region's dictionary converges on every distinct
+    #: key of the traffic, and a collector whose live keys pass the slot
+    #: count rolls epochs (sketch_resident_dict_epochs_total). On the
+    #: device: regions (max(SKETCH_SUPERBATCH) x pack lanes, 32 at the
+    #: defaults) x slots x 40 B — 335 MB at 2^18, 1.34 GB at 2^20
+    #: (sketch_resident_table_bytes) — and every fold relays the whole
+    #: table, so a slot costs device time as well as memory
     sketch_resident_slots: int = field(
         default=1 << 18, **_env("SKETCH_RESIDENT_SLOTS", str(1 << 18)))
     # where window reports go: "stdout" (JSON lines) or "kafka" (uses the

@@ -827,6 +827,11 @@ class TpuSketchExporter(Exporter):
             # tenants resident per HBM — made visible per agent
             from netobserv_tpu.sketch.tiered import array_bytes
             metrics.sketch_resident_hbm_bytes.set(array_bytes(self._state))
+            # the key tables live in the staging ring, not in the state:
+            # at SKETCH_RESIDENT_SLOTS=2^20 they are 1.34 GB beside a
+            # state of 140 MB, so they get a gauge of their own
+            metrics.sketch_resident_table_bytes.set(
+                array_bytes(getattr(self._ring, "key_tables", ())))
         if warm_ladder:
             self.warm_superbatch_ladder()
         # the staging ring packs the next batch while the previous

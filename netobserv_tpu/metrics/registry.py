@@ -292,6 +292,14 @@ class Metrics:
             "SKETCH_TIERED shrinks this ~4x over the counter tables — "
             "the windows/tenants-per-HBM capacity signal",
             registry=self.registry)
+        self.sketch_resident_table_bytes = Gauge(
+            p + "sketch_resident_table_bytes",
+            "Bytes of the resident feed's device key tables as allocated "
+            "(pack regions x SKETCH_RESIDENT_SLOTS x 10 key words x 4; "
+            "shape math, set once at exporter construction; 0 without a "
+            "resident feed). The staging ring holds them beside the "
+            "sketch state, and every fold relays them whole",
+            registry=self.registry)
         self.sketch_reports_shed_total = Counter(
             p + "sketch_reports_shed_total",
             "Unpublished window reports shed because the report queue "

@@ -72,12 +72,17 @@ def _scatter_add_two(counts_a: jax.Array, counts_b: jax.Array,
     form; bit-exact either way (same adds per cell in the same batch order;
     tests/test_tenancy.py pins it per tenant)."""
     d, w = counts_a.shape
-    stacked = jnp.stack([counts_a, counts_b], axis=-1)  # [d, w, 2]
+    # one flat index per (depth row, record) into [d*w, 2]: the form XLA
+    # rewrites a 2-coordinate scatter into anyway, written here so that the
+    # scatter it runs is the one traced and keeps its op_name (a rewritten
+    # one has none, and a device capture then finds it under no scope)
+    stacked = jnp.stack([counts_a, counts_b], axis=-1).reshape(d * w, 2)
     vals = jnp.stack([va, vb], axis=-1)  # [B, 2]
     vals = jnp.broadcast_to(vals[None], (d,) + vals.shape)  # [d, B, 2]
-    rows = jnp.broadcast_to(jnp.arange(d, dtype=jnp.int32)[:, None],
-                            idx.shape)
-    new = stacked.at[rows, idx].add(vals, mode="drop", unique_indices=False)
+    flat = jnp.arange(d, dtype=jnp.int32)[:, None] * w + idx  # [d, B]
+    new = stacked.at[flat.reshape(-1)].add(
+        vals.reshape(-1, 2), mode="drop", unique_indices=False)
+    new = new.reshape(d, w, 2)
     return new[..., 0], new[..., 1]
 
 

@@ -24,10 +24,11 @@ whole values: integer sums below 2^24 equal the scatter twin's bit for bit
 A plain one-hot over the whole width would build d * B * W = 9.1e9 cells for
 the same sums. The MACs grow with W, so at widths of 2^20 and more, where HI
 is tiled over the grid and every tile walks the whole batch, the XLA scatter
-is the cheaper form (docs/tpu_sketch.md).
+is the cheaper form and `sketch.state.fold_forms` picks it (measured table in
+docs/tpu_sketch.md "Count-Min form by width").
 
 The counters are donated (input_output_aliases) so the fold is in-place in
-HBM. Callers use `countmin.update` unless `state.ingest`'s gate picks this.
+HBM. Callers use `countmin.update` unless `state.fold_forms` picks this.
 """
 
 from __future__ import annotations

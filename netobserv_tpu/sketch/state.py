@@ -51,9 +51,9 @@ class SketchConfig(NamedTuple):
     hist_buckets: int = 1024
     ewma_buckets: int = 4096
     ewma_alpha: float = 0.3
-    #: None = auto: the fused MXU one-hot kernel on TPU at eligible widths
-    #: (measured faster than the XLA scatter there, docs/tpu_sketch.md);
-    #: the scatter everywhere else, incl. CPU where the kernel interprets
+    #: None = auto: the forms `fold_forms` picks from the platform and the
+    #: Count-Min width (measured on the chip, docs/tpu_sketch.md); the
+    #: scatter everywhere off-TPU, incl. CPU where the kernel interprets
     use_pallas: bool | None = None
     #: tiered counter planes (SKETCH_TIERED, sketch/tiered.py): the
     #: resident form of the CM planes + HLL banks goes narrow (u8 base +
@@ -304,6 +304,46 @@ def _tier_interior_ok(state) -> bool:
     return countmin_kernel.tiered_eligible(width, state.spec)
 
 
+#: Count-Min widths (inclusive) at which the factored one-hot kernel
+#: (`ops/pallas/countmin_kernel._fold`) is the cheaper form on a TPU; outside
+#: them the XLA scatter is. See :func:`fold_forms`.
+CM_FACTORED_WIDTHS = (1 << 14, 1 << 19)
+
+
+def fold_forms(width: int, use_pallas: bool | None = None,
+               platform: str | None = None) -> tuple[bool, str]:
+    """The ONE selection of a fold's forms from what the code observes:
+    ``(run the Pallas kernels, Count-Min form)``, the form ``"factored"``
+    (the one-hot contraction on the MXU) or ``"scatter"`` (XLA's scatter-add).
+    `ingest`, every ladder factory and the mesh's per-shard fold resolve
+    through here at trace time; nothing else compares a width.
+
+    `use_pallas` None is the automatic rule (SKETCH_USE_PALLAS=auto), on a
+    TPU only (`platform` defaults to `jax.default_backend()`):
+
+    - the kernels (HLL, signals, the slot top-K walk and a Count-Min kernel)
+      run from width `CM_FACTORED_WIDTHS[0]` up. That bound is the whole
+      kernel set's and is kept where it was: the Count-Min call alone
+      favours the kernel down to 2^13, the lowest width timed, and below it
+      a sketch is a test's (no deployment runs under 2^16);
+    - the Count-Min form follows the width, because the factored kernel's
+      MACs are d x W a record and the scatter's touches are d: factored up to
+      `CM_FACTORED_WIDTHS[1]`, the scatter above. Measured on a v5e (PR 32,
+      the call alone, table in docs/tpu_sketch.md "Count-Min form by width").
+
+    An explicit True (SKETCH_USE_PALLAS=on, the tests' kernel twins) forces
+    every kernel, the factored one wherever the width tiles; False forces the
+    scatter forms. The `est` the slot top-K reads back is `countmin.query` of
+    the table whichever form folded it."""
+    auto = use_pallas is None
+    if auto:
+        use_pallas = ((platform or jax.default_backend()) == "tpu"
+                      and width >= CM_FACTORED_WIDTHS[0])
+    factored = (use_pallas and width % 512 == 0
+                and not (auto and width > CM_FACTORED_WIDTHS[1]))
+    return bool(use_pallas), "factored" if factored else "scatter"
+
+
 def tiered_fold_form(cfg: SketchConfig) -> str | None:
     """Which fold form a tiered pipeline under ``cfg`` engages on THIS
     backend: ``"interior"`` (tier-native Pallas walk), ``"decode"``
@@ -311,10 +351,7 @@ def tiered_fold_form(cfg: SketchConfig) -> str | None:
     trace-time gate in :func:`ingest` — accounting/attribution only."""
     if cfg.tiered is None:
         return None
-    up = cfg.use_pallas
-    if up is None:
-        up = jax.default_backend() == "tpu" and cfg.cm_width >= 16384
-    if up:
+    if fold_forms(cfg.cm_width, cfg.use_pallas)[0]:
         from netobserv_tpu.ops.pallas import countmin_kernel
         if countmin_kernel.tiered_eligible(cfg.cm_width, cfg.tiered):
             return "interior"
@@ -353,10 +390,8 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
                 "counter planes are single-device (config.validate blocks "
                 "SKETCH_MESH_SHAPE with SKETCH_TIERED)")
         spec = state.spec
-        up = use_pallas
-        if up is None:  # the same auto rule as the wide path, tier widths
-            up = (jax.default_backend() == "tpu"
-                  and state.tables.cm_bytes.base.shape[1] >= 16384)
+        # the same rule as the wide path, at the tiers' width
+        up = fold_forms(state.tables.cm_bytes.base.shape[1], use_pallas)[0]
         if up and tier_interior is not False and _tier_interior_ok(state):
             # TIER-INTERIOR fold: the Pallas walks read/promote the narrow
             # tier arrays directly in VMEM — no wide CM temporary in HBM.
@@ -385,12 +420,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
         new_wide = ingest(tiered.widen(state, cmb_wide, cmp_wide), arrays,
                           use_pallas=use_pallas)
         return tiered.fold_encode(state, cmb_wide, cmp_wide, new_wide)
-    if use_pallas is None:
-        # auto: the fused kernels (Count-Min fold + HLL) win on TPU at and
-        # above the measured ~16K-width crossover (docs/tpu_sketch.md);
-        # below it — and everywhere off-TPU — the scatter is faster
-        use_pallas = (jax.default_backend() == "tpu"
-                      and state.cm_bytes.width >= 16384)
+    use_pallas, cm_form = fold_forms(state.cm_bytes.width, use_pallas)
     words = arrays["keys"]
     valid = arrays["valid"]
     bytes_f = arrays["bytes"]
@@ -452,18 +482,21 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             topk_kw = dict(query_fn=lambda a, b: est,
                            use_pallas=state.heavy.k % 128 == 0)
         else:
-            # the Pallas kernel needs the width to tile; silently use the
-            # XLA scatter otherwise (static check, resolved at trace time)
-            if use_pallas and state.cm_bytes.width % 512 == 0:
-                from netobserv_tpu.ops.pallas import countmin_kernel
-                # fused: both planes share hash indices + one-hot build
-                cm_b, cm_p = countmin_kernel.update_two(
-                    state.cm_bytes, state.cm_pkts, h1, h2, bytes_f,
-                    pkts.astype(jnp.float32), valid)
-            else:
-                cm_b, cm_p = countmin.update_two(
-                    state.cm_bytes, state.cm_pkts, h1, h2, bytes_f, pkts,
-                    valid)
+            # the form `fold_forms` chose for this width, named in the ops'
+            # metadata (countmin/factored | countmin/scatter) and on the
+            # /debug/executables row of the entry being traced
+            retrace.label("countmin", cm_form)
+            with jax.named_scope(cm_form):
+                if cm_form == "factored":
+                    from netobserv_tpu.ops.pallas import countmin_kernel
+                    # fused: both planes share hash indices + one-hot build
+                    cm_b, cm_p = countmin_kernel.update_two(
+                        state.cm_bytes, state.cm_pkts, h1, h2, bytes_f,
+                        pkts.astype(jnp.float32), valid)
+                else:
+                    cm_b, cm_p = countmin.update_two(
+                        state.cm_bytes, state.cm_pkts, h1, h2, bytes_f, pkts,
+                        valid)
             # persistent-slot maintenance in the batch walk: the fused
             # Pallas reduction twin engages with the other kernels
             # (lane-aligned K); the scatter form everywhere else —

@@ -135,7 +135,8 @@ class Watched:
     __slots__ = ("_fn", "name", "warmup_calls", "calls", "compiles",
                  "retraces", "last_retrace", "dispatch_seconds",
                  "compile_seconds", "last_signature", "last_avals",
-                 "donated_bytes", "tenants", "tiered", "__weakref__")
+                 "donated_bytes", "tenants", "tiered", "labels",
+                 "__weakref__")
 
     def __init__(self, fn: Callable, name: str, warmup_calls: int,
                  tenants: Optional[int] = None,
@@ -163,6 +164,10 @@ class Watched:
         #: "decode") — same one-program rule: the registry attributes
         #: which walk the entry compiled to, never a hidden variant
         self.tiered = tiered
+        #: what the traced function said of the forms it compiled to
+        #: (:func:`label`, e.g. countmin=factored|scatter): chosen at trace
+        #: time from what the code observes, so only the trace can name it
+        self.labels: dict[str, str] = {}
 
     def __call__(self, *args, **kwargs):
         self.calls += 1
@@ -247,10 +252,20 @@ class Watched:
                    if self.tenants is not None else {}),
                 **({"tiered": self.tiered}
                    if self.tiered is not None else {}),
+                **self.labels,
                 **({"last_signature": self.last_signature}
                    if self.last_signature else {}),
                 **({"last_retrace": self.last_retrace}
                    if self.last_retrace else {})}
+
+
+def label(key: str, value: str) -> None:
+    """Called while a watched entry is being TRACED: put `key: value` on its
+    /debug/executables row (a form the function chose from the shapes it
+    saw). Outside a watched call it does nothing."""
+    w = getattr(_tls, "active", None)
+    if w is not None:
+        w.labels[key] = value
 
 
 def _listener(event: str, duration: float, **kwargs) -> None:

@@ -68,13 +68,14 @@ FOLD_SCOPES = {"hash", "countmin", "topk", "hll_src", "hll_grids",
                "quantile", "signals", "totals"}
 
 
-def resident_ladder_entry(k: int, cfg=CFG, lanes: int = 8):
+def resident_ladder_entry(k: int, cfg=CFG, lanes: int = 8,
+                          slots: int = 1 << 18):
     """(fn, args) of the exporter's single-device ladder entry x<k>."""
     bpl = BATCH // lanes
     caps = flowpack.default_resident_caps(bpl)
     fn = sk.make_ingest_resident_lanes_fn(
         bpl, caps, k * lanes, name=f"ingest_resident_lanes_x{k}")
-    tables = jax.ShapeDtypeStruct((4 * lanes, 1 << 18, sk.KEY_WORDS),
+    tables = jax.ShapeDtypeStruct((4 * lanes, slots, sk.KEY_WORDS),
                                   jnp.uint32)
     flat = jax.ShapeDtypeStruct(
         (k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
@@ -107,6 +108,28 @@ def test_resident_ladder_entry_lowers_with_five_mosaic_calls(k, cfg):
     fn, args = resident_ladder_entry(k, cfg)
     assert fn.name == f"ingest_resident_lanes_x{k}"
     assert mosaic_calls(fn, *args) == MOSAIC_CALLS
+
+
+#: collector-wide-1chip (cellbench/configs): Count-Min 4 x 2^22, 2^20 slots
+WIDE = CFG._replace(cm_width=1 << 22)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_wide_ladder_entry_folds_countmin_with_the_scatter(k):
+    """Above `sk.CM_FACTORED_WIDTHS` the automatic rule keeps the other four
+    kernels and hands the Count-Min fold to XLA's scatter, under a scope
+    that says so; at the default width the same entry keeps the kernel."""
+    fn, args = resident_ladder_entry(k, WIDE, slots=1 << 20)
+    text = lowered(fn, *args, debug_info=True)
+    kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert kernels == {"hll_update", "topk_slot_walk", "signal_update"}
+    assert text.count("tpu_custom_call") == MOSAIC_CALLS - 1
+    assert "/countmin/scatter/" in text
+    assert "/countmin/factored/" not in text
+    fn, args = resident_ladder_entry(k)
+    default = lowered(fn, *args, debug_info=True)
+    assert "/countmin/factored/" in default
+    assert "/countmin/scatter/" not in default
 
 
 def test_sharded_dense_ingest_lowers_with_five_mosaic_calls():
