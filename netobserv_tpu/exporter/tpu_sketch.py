@@ -647,14 +647,15 @@ class TpuSketchExporter(Exporter):
                 ladder = self._superbatch
                 ingests = {
                     k: pmerge.make_sharded_ingest_resident_fn(
-                        self._mesh, self._cfg, bpl, caps, lanes=k * lanes,
+                        self._mesh, self._cfg, bpl, caps, resident_slots,
+                        lanes=k * lanes,
                         watch_name=f"sharded_ingest_resident_x{k}")
                     for k in ladder}
                 self._ring = staging.ShardedResidentStagingRing(
                     self._batch_size, spec.data, ingests,
-                    key_tables=pmerge.init_resident_tables(
-                        self._mesh, resident_slots,
-                        lanes=max(ladder) * lanes),
+                    key_tables=functools.partial(
+                        pmerge.init_resident_tables, self._mesh,
+                        resident_slots, lanes=max(ladder) * lanes),
                     put=dense_put,
                     caps=caps, slot_cap=resident_slots, metrics=metrics,
                     pack_threads=pack_threads, lanes=lanes, ladder=ladder,
@@ -828,10 +829,15 @@ class TpuSketchExporter(Exporter):
             from netobserv_tpu.sketch.tiered import array_bytes
             metrics.sketch_resident_hbm_bytes.set(array_bytes(self._state))
             # the key tables live in the staging ring, not in the state:
-            # at SKETCH_RESIDENT_SLOTS=2^20 they are 1.34 GB beside a
-            # state of 140 MB, so they get a gauge of their own
+            # at SKETCH_RESIDENT_SLOTS=2^20 they are 1.34 GB (2.15 GB as
+            # a TPU lays 10 words out on 16 sublanes) beside a state of
+            # 140 MB, so they get a gauge of their own — one table a
+            # region dictionary, counted without making them
+            ring = self._ring
             metrics.sketch_resident_table_bytes.set(
-                array_bytes(getattr(self._ring, "key_tables", ())))
+                len(ring.kdicts) * ring.slot_cap * self._sk.KEY_WORDS * 4
+                if isinstance(ring, staging.ShardedResidentStagingRing)
+                else 0)
         if warm_ladder:
             self.warm_superbatch_ladder()
         # the staging ring packs the next batch while the previous
@@ -922,6 +928,11 @@ class TpuSketchExporter(Exporter):
 
         def _warm() -> None:
             import jax
+            # ONE spare state and table array for every entry: a call
+            # donates them and the next entry takes what it returned (zero
+            # regions define no key and hold no row) — an array an entry
+            # would be 2.15 GB each at 2^20 slots, beside the ring's own
+            state = tables = None
             for k in ring.ladder:
                 if self._closed.is_set():
                     return  # shutting down: stop compiling, exit promptly
@@ -932,22 +943,20 @@ class TpuSketchExporter(Exporter):
                     # retrace alarm, for zero benefit
                     continue
                 try:
-                    if self._distributed:
-                        state = self._pm.init_dist_state(self._cfg,
-                                                         self._mesh)
-                        tables = self._pm.init_resident_tables(
-                            self._mesh, ring.slot_cap,
-                            lanes=ring.superbatch_max * ring.lanes)
-                    else:
-                        state = self._sk.init_state(self._cfg)
-                        tables = jax.device_put(self._sk.init_key_tables(
-                            ring.superbatch_max * ring.lanes, ring.slot_cap))
+                    if tables is None:
+                        state = (
+                            self._pm.init_dist_state(self._cfg, self._mesh)
+                            if self._distributed
+                            else self._sk.init_state(self._cfg))
+                        tables = ring.make_tables()
                     nr = ring.n_shards * k * ring.lanes
                     flat = np.zeros(nr * ring._region_words, np.uint32)
-                    out = ring._ingests[k](state, tables, ring._put(flat))
-                    jax.block_until_ready(out[2])
+                    state, tables, token = ring._ingests[k](
+                        state, tables, ring._put(flat))
+                    jax.block_until_ready(token)
                     ring.mark_warm(k)
                 except Exception as exc:
+                    state = tables = None  # donated to the call that failed
                     if multiprocess:
                         # divergent availability across processes means
                         # divergent SPMD programs later — fail the startup
@@ -1669,14 +1678,15 @@ class TpuSketchExporter(Exporter):
             # and a device capture reads jit_ingest_resident_lanes_x<k>
             ingests = {
                 k: sk.make_ingest_resident_lanes_fn(
-                    bpl, caps, k * lanes, use_pallas=self._cfg.use_pallas,
+                    bpl, caps, k * lanes, resident_slots,
+                    use_pallas=self._cfg.use_pallas,
                     name=f"ingest_resident_lanes_x{k}",
                     tiered=self._tier_form)
                 for k in ladder}
             return staging.ShardedResidentStagingRing(
                 self._batch_size, 1, ingests,
-                key_tables=jax.device_put(
-                    sk.init_key_tables(max(ladder) * lanes, resident_slots)),
+                key_tables=functools.partial(
+                    sk.init_key_tables, max(ladder) * lanes, resident_slots),
                 put=jax.device_put, caps=caps, slot_cap=resident_slots,
                 metrics=metrics, pack_threads=pack_threads, lanes=lanes,
                 ladder=ladder, lazy_ladder=True)

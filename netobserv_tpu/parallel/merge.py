@@ -190,21 +190,25 @@ def make_sharded_ingest_fn(mesh: Mesh, cfg: sk.SketchConfig,
 def init_resident_tables(mesh: Mesh, slot_cap: int,
                          lanes: int = 1) -> jax.Array:
     """Per-DATA-shard device key tables for the sharded resident feed:
-    (n_data, lanes, slot_cap, KEY_WORDS) u32, sharded P(data) — each data
-    shard owns `lanes` independent tables, one per host-side packer lane
+    (n_data * lanes * slot_cap, KEY_WORDS) u32, rows sharded P(data) — each
+    data shard holds one `sketch.state.init_key_tables(lanes, slot_cap)`
+    array (lane `l`'s slot `s` in its row `l * slot_cap + s`; on the chip
+    16/10 of its logical bytes, and no second copy inside a fold; a leading
+    unit axis per shard would cost a copy in and a copy out): `lanes`
+    independent tables, one per host-side packer lane
     (lanes > 1 lets the host pack a shard's rows across several threads;
     `sketch.staging.ShardedResidentStagingRing`), and the sketch-axis
     replicas stay consistent because every sketch column of a data row
     applies the same new-key lanes. Lookups are pure local gathers, so the
     steady-state no-collectives invariant is untouched."""
     ndata = mesh.shape[DATA_AXIS]
-    arr = np.zeros((ndata, lanes, slot_cap, sk.KEY_WORDS), np.uint32)
+    arr = np.zeros((ndata * lanes * slot_cap, sk.KEY_WORDS), np.uint32)
     return _put_global(arr, mesh, P(DATA_AXIS))
 
 
 def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
                                     batch_per_lane: int, caps,
-                                    donate: bool = True,
+                                    slot_cap: int, donate: bool = True,
                                     lanes: int = 1,
                                     watch_name: str =
                                     "sharded_ingest_resident") -> Callable:
@@ -218,23 +222,25 @@ def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
     shard scatters its new-key lanes into ITS table slices and gathers
     hot-row keys locally — no collectives.
 
-    `key_tables` may carry MORE than `lanes` rows per shard (the superbatch
-    fold ladder shares one table array across ladder entries —
-    `sketch.state.resident_lane_arrays`); `watch_name` distinguishes ladder
-    entries in the retrace watchdog accounting."""
+    `key_tables` is `init_resident_tables(mesh, slot_cap, lanes=L)` with
+    L >= `lanes` lanes per shard (the superbatch fold ladder shares one
+    table array across ladder entries, so `slot_cap` is passed, not read
+    off the array — `sketch.state.resident_lane_arrays`, which the
+    per-shard step runs on its own rows); `watch_name` distinguishes
+    ladder entries in the retrace watchdog accounting."""
     nsk = mesh.shape[SKETCH_AXIS]
     template = sk.init_state(cfg)
     specs = _state_specs(template)
 
     def local_step(pstate: sk.SketchState, table, flat):
         s = _drop_lead(pstate)
-        arrays, tbl = sk.resident_lane_arrays(flat, table[0], batch_per_lane,
-                                              caps, lanes)
+        arrays, tbl = sk.resident_lane_arrays(flat, table, batch_per_lane,
+                                              caps, lanes, slot_cap)
         s = sk.ingest(s, arrays,
                       sketch_axis=SKETCH_AXIS if nsk > 1 else None,
                       sketch_shards=nsk,
                       use_pallas=(cfg.use_pallas if nsk == 1 else False))
-        return _add_lead(s), tbl[None], flat[:1]
+        return _add_lead(s), tbl, flat[:1]
 
     shmapped = jax.shard_map(
         local_step, mesh=mesh,

@@ -569,7 +569,7 @@ class ShardedResidentStagingRing(_SlotRing):
     amortizing the per-dispatch python/jit/transfer overhead. Every ladder
     entry is its own fixed-shape jitted fn (no retraces); they all share
     ONE key-table array sized for the largest entry (a smaller entry
-    updates only its leading regions' tables, `state.resident_lane_arrays`)
+    updates only its leading lanes' rows, `state.resident_lane_arrays`)
     and per-(shard, ladder-position, lane) dictionaries, so a region's
     dictionary <-> device-table pairing is stable across ladder sizes.
 
@@ -585,8 +585,14 @@ class ShardedResidentStagingRing(_SlotRing):
 
     `ingest`: `{k: (dist_state, key_tables, flat) -> (dist_state,
     key_tables, token)}` for every ladder entry (a bare callable means
-    `{1: fn}`). `key_tables` must carry `superbatch_max * lanes` rows per
-    shard. `pack_threads > 1` packs the regions concurrently."""
+    `{1: fn}`). `key_tables` must carry `superbatch_max * lanes` lanes of
+    `slot_cap` rows per shard (`state.init_key_tables`; the ring only hands
+    the array on), and every entry must have been built with this ring's
+    `slot_cap`. A zero-argument callable in its place makes the array at
+    the first dispatch (and a spare on request, `make_tables`): the
+    exporter's ladder warm-up folds through a spare, and at 2^20 slots a
+    table is 2.15 GB of a 16 GB chip — the two need not be alive together.
+    `pack_threads > 1` packs the regions concurrently."""
 
     def __init__(self, batch_size: int, n_shards: int, ingest,
                  key_tables, put: Callable,
@@ -620,7 +626,9 @@ class ShardedResidentStagingRing(_SlotRing):
         self.pack_threads = pack_threads
         self.kdicts = [flowpack.KeyDict(slot_cap)
                        for _ in range(n_regions * self.superbatch_max)]
-        self.key_tables = key_tables
+        #: a new zeroed table array (None where the ring was handed one)
+        self.make_tables = key_tables if callable(key_tables) else None
+        self._key_tables = None if self.make_tables else key_tables
         self._ingests = ingest if not callable(ingest) else {1: ingest}
         missing = set(self.ladder) - set(self._ingests)
         if missing:
@@ -640,6 +648,18 @@ class ShardedResidentStagingRing(_SlotRing):
         self._init_slots(
             [np.empty(self.superbatch_max * n_regions * self._region_words,
                       np.uint32) for _ in range(n_slots)], metrics)
+
+    @property
+    def key_tables(self):
+        """The device key tables (made here, once, where the ring was
+        given their factory)."""
+        if self._key_tables is None:
+            self._key_tables = self.make_tables()
+        return self._key_tables
+
+    @key_tables.setter
+    def key_tables(self, tables) -> None:
+        self._key_tables = tables
 
     def mark_warm(self, *ks: int) -> None:
         """Make ladder entries selectable (call after compiling them — the

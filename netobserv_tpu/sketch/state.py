@@ -792,25 +792,30 @@ HOT_WORDS = 3
 NK_WORDS = 11
 
 
-def _region_nk(flat: jax.Array, batch_size: int, caps,
-               slot_cap: int) -> tuple[jax.Array, jax.Array]:
-    """A region's new-key lane as (slot indices, key words) — undefined
-    rows index slot_cap so a mode=\"drop\" scatter discards them."""
+def _region_nk(flat: jax.Array, batch_size: int, caps, base: int,
+               past_end: int) -> tuple[jax.Array, jax.Array]:
+    """A region's new-key lane as (table rows, key words): a defined row
+    lands at `base + slot` (`base` = lane * slot_cap); an undefined one
+    indexes `past_end`, the row count of the WHOLE table array, so a
+    mode=\"drop\" scatter discards it — `base + slot_cap` would be slot 0
+    of the next lane."""
     nk_off = (RESIDENT_HDR + batch_size * HOT_WORDS + caps.dns
               + caps.drop * 2)
     nk = flat[nk_off:nk_off + caps.nk * NK_WORDS].reshape(caps.nk, NK_WORDS)
     nk_def = (nk[:, 0] >> 31) != 0
-    nk_slot = jnp.where(nk_def, nk[:, 0] & jnp.uint32(0xFFFFF),
-                        jnp.uint32(slot_cap)).astype(jnp.int32)
-    return nk_slot, nk[:, 1:]
+    nk_row = jnp.where(
+        nk_def, (nk[:, 0] & jnp.uint32(0xFFFFF)).astype(jnp.int32) + base,
+        past_end)
+    return nk_row, nk[:, 1:]
 
 
 def _resident_region_arrays(flat: jax.Array, key_tables: jax.Array,
-                            batch_size: int, caps, lane: int) -> dict:
+                            batch_size: int, caps, lane: int,
+                            slot_cap: int) -> dict:
     """One resident region's rows as an array dict (layout pinned in
     flowpack.cc fp_pack_resident; host packer flowpack.pack_resident):
-    gathers full 10-word keys by slot id from row `lane` of the SHARED
-    (L, slot_cap, KW) per-lane key tables, decodes the range-coded rtt/dns
+    gathers full 10-word keys by slot id from lane `lane`'s rows of the
+    SHARED (L * slot_cap, KW) key tables, decodes the range-coded rtt/dns
     codes, scatters the sparse dns/drop lanes onto their rows, and
     concatenates the full-width spill lane. The region's new-key lane is
     NOT read here: the caller has already applied every region's in one
@@ -830,8 +835,11 @@ def _resident_region_arrays(flat: jax.Array, key_tables: jax.Array,
 
     w0 = hot[:, 0]
     valid = (w0 >> 31) != 0
-    slots = (w0 & jnp.uint32(0xFFFFF)).astype(jnp.int32)
-    keys = key_tables[lane, slots]
+    # the 20-bit slot field means something only where `valid`: hold every
+    # row's index inside this lane (invalid rows are masked downstream)
+    slots = jnp.minimum((w0 & jnp.uint32(0xFFFFF)).astype(jnp.int32),
+                        slot_cap - 1)
+    keys = key_tables[lane * slot_cap + slots]
     rtt = (((w0 >> 20) & jnp.uint32(0xFF))
            << (2 * ((w0 >> 28) & jnp.uint32(0x7)))).astype(jnp.int32)
     w2 = hot[:, 2]
@@ -871,10 +879,22 @@ def _resident_region_arrays(flat: jax.Array, key_tables: jax.Array,
 
 def init_key_tables(n_lanes: int, slot_cap: int) -> jax.Array:
     """Per-LANE device key tables for the lane-sharded resident feed on a
-    single device: (n_lanes, slot_cap, KEY_WORDS) u32 — one independent
-    table per host-side packer lane (`sketch.staging` lane-sharded ring),
-    the single-device twin of `parallel.merge.init_resident_tables`."""
-    return jnp.zeros((n_lanes, slot_cap, KEY_WORDS), jnp.uint32)
+    single device: ONE (n_lanes * slot_cap, KEY_WORDS) u32 array, lane
+    `l`'s slot `s` in row `l * slot_cap + s` — one independent table per
+    host-side packer lane (`sketch.staging` lane-sharded ring), the
+    single-device twin of `parallel.merge.init_resident_tables`. A TPU
+    stores a 10-wide minor dimension words-major by itself (`{0,1:T(8,128)}`:
+    words on the sublanes, rows on the 128 lanes), which is the form its
+    scatter and gathers run in, so a fold updates the donated array in
+    place and holds no second copy of it; the 3-D (lanes, slots, words)
+    form tiled (8 lanes x 128 slots) and was relaid five times a fold
+    (PERF.md section 6, PR 33). The 10 words fill 16 sublanes: the array
+    holds 16/10 of the bytes `sketch_resident_table_bytes` reports (335 MB
+    at 32 lanes x 2^18 slots is 537 MB of HBM, 1.34 GB at 2^20 slots 2.15
+    GB). On the CPU the same array is row-major and the scatter is in place
+    as well — a (KEY_WORDS, rows) array is not: XLA's CPU scatter wants the
+    scattered dimension first and transposes the whole table to get it."""
+    return jnp.zeros((n_lanes * slot_cap, KEY_WORDS), jnp.uint32)
 
 
 def _resident_region_words(batch_size: int, caps) -> int:
@@ -886,8 +906,8 @@ def _resident_region_words(batch_size: int, caps) -> int:
 
 
 def resident_lane_arrays(flat: jax.Array, key_tables: jax.Array,
-                         batch_per_lane: int, caps,
-                         n_lanes: int) -> tuple[dict, jax.Array]:
+                         batch_per_lane: int, caps, n_lanes: int,
+                         slot_cap: int) -> tuple[dict, jax.Array]:
     """Unpack `n_lanes` concatenated resident regions against per-lane key
     tables into ONE array dict for the ordinary ingest — the device end of
     the three-place wire contract (flowpack.cc fp_pack_resident <->
@@ -897,10 +917,16 @@ def resident_lane_arrays(flat: jax.Array, key_tables: jax.Array,
     only ever saw ~15 bytes/record (byte budget in docs/tpu_sketch.md).
     Returns (arrays, new_key_tables).
 
-    `key_tables` may carry MORE rows than `n_lanes` (the superbatch fold
-    ladder: every ladder entry shares ONE per-region table array sized for
-    the largest superbatch; a smaller entry scatters only into its leading
-    regions' rows). EVERY region's new-key lane applies as one combined
+    `key_tables` is the (total_lanes * slot_cap, KEY_WORDS) array of
+    `init_key_tables`: lane `l`'s slot `s` is row `l * slot_cap + s`, the
+    2-D form the scatter and the gathers take as it stands, so the donated
+    array is updated in place (no table-sized op but the scatter:
+    tests/test_tpu_lowering.py pins it for the TPU). `slot_cap` is static
+    and comes from the caller (the ring's `slot_cap`): the array may carry
+    MORE lanes than `n_lanes` (the superbatch fold ladder: every ladder
+    entry shares ONE array sized for the largest superbatch; a smaller
+    entry scatters only into its leading lanes' rows), so its row count
+    does not give it. EVERY region's new-key lane applies as one combined
     scatter on the shared donated array before any hot-row gather — XLA
     keeps that single scatter in place, where a per-region scatter/gather
     chain was measured to copy the full table array once per region; the
@@ -909,21 +935,25 @@ def resident_lane_arrays(flat: jax.Array, key_tables: jax.Array,
     row-disjoint."""
     with jax.named_scope("resident_decode"):
         return _resident_lane_arrays(flat, key_tables, batch_per_lane, caps,
-                                     n_lanes)
+                                     n_lanes, slot_cap)
 
 
-def _resident_lane_arrays(flat, key_tables, batch_per_lane, caps, n_lanes):
+def _resident_lane_arrays(flat, key_tables, batch_per_lane, caps, n_lanes,
+                          slot_cap):
+    total, kw = key_tables.shape
+    if kw != KEY_WORDS or total % slot_cap or n_lanes * slot_cap > total:
+        raise ValueError(
+            f"key tables {key_tables.shape} do not hold {n_lanes} lanes of "
+            f"{slot_cap} slots as (lanes * slot_cap, {KEY_WORDS})")
     words = _resident_region_words(batch_per_lane, caps)
     regions = [flat[i * words:(i + 1) * words] for i in range(n_lanes)]
-    slot_cap = key_tables.shape[-2]
-    nk_parts = [_region_nk(r, batch_per_lane, caps, slot_cap)
-                for r in regions]
-    lane_ids = jnp.concatenate([
-        jnp.full((caps.nk,), i, jnp.int32) for i in range(n_lanes)])
+    nk_parts = [_region_nk(r, batch_per_lane, caps, i * slot_cap, total)
+                for i, r in enumerate(regions)]
     key_tables = key_tables.at[
-        lane_ids, jnp.concatenate([s for s, _ in nk_parts])].set(
+        jnp.concatenate([r for r, _ in nk_parts])].set(
         jnp.concatenate([w for _, w in nk_parts]), mode="drop")
-    lanes = [_resident_region_arrays(r, key_tables, batch_per_lane, caps, i)
+    lanes = [_resident_region_arrays(r, key_tables, batch_per_lane, caps, i,
+                                     slot_cap)
              for i, r in enumerate(regions)]
     if n_lanes == 1:
         return lanes[0], key_tables
@@ -933,7 +963,7 @@ def _resident_lane_arrays(flat, key_tables, batch_per_lane, caps, n_lanes):
 
 
 def make_ingest_resident_lanes_fn(batch_per_lane: int, caps, n_lanes: int,
-                                  donate: bool = True,
+                                  slot_cap: int, donate: bool = True,
                                   use_pallas: bool | None = None,
                                   name: str = "ingest_resident_lanes",
                                   tiered: str | None = None):
@@ -942,11 +972,12 @@ def make_ingest_resident_lanes_fn(batch_per_lane: int, caps, n_lanes: int,
     `n_lanes` independent resident regions, each packed by its own host
     KeyDict (`sketch.staging.ShardedResidentStagingRing` with one shard and
     L lanes — the native pack releases the GIL, so lanes pack in true
-    parallel), and `key_tables` is `init_key_tables(n_lanes, slot_cap)`.
+    parallel), and `key_tables` is `init_key_tables(L, slot_cap)` with
+    L >= n_lanes (`resident_lane_arrays`); `slot_cap` is the ring's.
     Always returns the slot-reuse token (the ring requires it)."""
     def fn(s, tables, flat):
         arrays, tables = resident_lane_arrays(flat, tables, batch_per_lane,
-                                              caps, n_lanes)
+                                              caps, n_lanes, slot_cap)
         s = ingest(s, arrays, use_pallas=use_pallas)
         return s, tables, flat[:1]
     return retrace.jit(fn, name, tiered=tiered,

@@ -118,7 +118,7 @@ def test_resident_staging_metrics_surface():
     caps = flowpack.ResidentCaps(dns=8, drop=8, nk=8, spill=4)  # tiny lanes
     import jax
     ring = ShardedResidentStagingRing(
-        B, 1, sk.make_ingest_resident_lanes_fn(B, caps, 1),
+        B, 1, sk.make_ingest_resident_lanes_fn(B, caps, 1, 64),
         key_tables=jax.device_put(sk.init_key_tables(1, 64)),
         put=jax.device_put, caps=caps, slot_cap=64, metrics=m)
     state = sk.init_state(sk.SketchConfig(

@@ -361,7 +361,7 @@ def test_sharded_resident_feed_matches_dense(mesh_shape, lanes):
     # resident path
     ring = ShardedResidentStagingRing(
         B, ndata,
-        pmerge.make_sharded_ingest_resident_fn(mesh, CFG, bpl, caps,
+        pmerge.make_sharded_ingest_resident_fn(mesh, CFG, bpl, caps, 1 << 12,
                                                lanes=lanes),
         key_tables=pmerge.init_resident_tables(mesh, 1 << 12, lanes=lanes),
         put=lambda buf: pmerge.shard_dense(mesh, buf),
@@ -416,7 +416,7 @@ def test_sharded_resident_ingest_has_no_collectives(mesh_shape, lanes):
     mesh = make_mesh(MeshSpec(data=ndata, sketch=nsk))
     bpl = 64 // lanes
     caps = flowpack.default_resident_caps(bpl)
-    fn = pmerge.make_sharded_ingest_resident_fn(mesh, CFG, bpl, caps,
+    fn = pmerge.make_sharded_ingest_resident_fn(mesh, CFG, bpl, caps, 1 << 12,
                                                 donate=False, lanes=lanes)
     dist = pmerge.init_dist_state(CFG, mesh)
     tables = pmerge.init_resident_tables(mesh, 1 << 12, lanes=lanes)

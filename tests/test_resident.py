@@ -285,7 +285,7 @@ def _fold_lanes(feed, lanes, slot_cap=1 << 12, caps=None):
     caps = caps or flowpack.default_resident_caps(bpl)
     cfg = sk.SketchConfig()
     ring = ShardedResidentStagingRing(
-        B, 1, sk.make_ingest_resident_lanes_fn(bpl, caps, lanes),
+        B, 1, sk.make_ingest_resident_lanes_fn(bpl, caps, lanes, slot_cap),
         key_tables=jax.device_put(sk.init_key_tables(lanes, slot_cap)),
         put=jax.device_put, caps=caps, slot_cap=slot_cap,
         pack_threads=lanes, lanes=lanes)
@@ -336,7 +336,8 @@ def test_lane_sharded_matches_unsharded_resident():
     # (the dictionary assigns slots sequentially; the new-key lane defines
     # them on device before any hot row references them)
     from netobserv_tpu.model.columnar import pack_key_words
-    tables = np.asarray(ring.key_tables)  # (lanes, slot_cap, 10)
+    tables = np.asarray(ring.key_tables)  # (lanes * slot_cap, 10)
+    assert tables.shape == (ring.n_regions * ring.slot_cap, 10)
     for lane in range(ring.n_regions):
         expected: dict[bytes, int] = {}
         for events, _feats in feed:
@@ -347,7 +348,7 @@ def test_lane_sharded_matches_unsharded_resident():
                 expected.setdefault(kw.tobytes(), len(expected))
         assert ring.kdicts[lane].count() == len(expected)
         for kb, slot in expected.items():
-            assert tables[lane, slot].tobytes() == kb
+            assert tables[lane * ring.slot_cap + slot].tobytes() == kb
 
 
 @needs_jax
@@ -399,7 +400,7 @@ def test_zero_resident_region_masks_garbage_exactly():
                           hll_precision=6, perdst_buckets=32,
                           perdst_precision=4, persrc_buckets=32,
                           persrc_precision=4, hist_buckets=64)
-    fn = sk.make_ingest_resident_lanes_fn(bs, caps, 1, donate=False)
+    fn = sk.make_ingest_resident_lanes_fn(bs, caps, 1, 64, donate=False)
     table = jax.device_put(sk.init_key_tables(1, 64))
     s_g, t_g, _ = fn(sk.init_state(cfg), table, jax.device_put(garbage))
     s_z, t_z, _ = fn(sk.init_state(cfg), table, jax.device_put(zeros))
@@ -417,7 +418,7 @@ def _lane_ring(lanes, caps=None, slot_cap=1 << 12):
     bpl = B // lanes
     caps = caps or flowpack.default_resident_caps(bpl)
     ring = ShardedResidentStagingRing(
-        B, 1, sk.make_ingest_resident_lanes_fn(bpl, caps, lanes),
+        B, 1, sk.make_ingest_resident_lanes_fn(bpl, caps, lanes, slot_cap),
         key_tables=jax.device_put(sk.init_key_tables(lanes, slot_cap)),
         put=jax.device_put, caps=caps, slot_cap=slot_cap,
         pack_threads=lanes, lanes=lanes)
@@ -511,3 +512,137 @@ def test_carry_fold_with_nothing_left_is_the_plain_fold():
                                   np.asarray(carried.key_tables))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), states[0], states[1])
+
+
+# --- the stored layout of the key tables (rows = lane * slot_cap + slot) ---
+
+#: (lanes an entry folds, lanes the shared table array holds): one lane, the
+#: x1 entry's 8 of 8, and the ladder case — an x1 entry's 8 leading lanes of
+#: the 32 the x4 entry needs
+LAYOUT_CASES = {"one_lane": (1, 1), "eight_lanes": (8, 8),
+                "eight_of_32_lanes": (8, 32)}
+
+
+def _np_decode_regions(flat, table, bpl, caps, n_lanes, slot_cap):
+    """NumPy reference of `sketch.state.resident_lane_arrays` on a table
+    stored (lanes * slot_cap, 10): every region's defined new-key rows land
+    at row lane * slot_cap + slot FIRST, then each region's hot rows gather
+    from their own lane (a row's slot field held inside the lane), the
+    sparse dns / drop lanes scatter onto their rows, and the full-width
+    spill rows follow. Returns (arrays, table)."""
+    words = flowpack.resident_buf_len(bpl, caps)
+    hot_off = flowpack.RESIDENT_HDR
+    dns_off = hot_off + bpl * flowpack.HOT_WORDS
+    drop_off = dns_off + caps.dns
+    nk_off = drop_off + caps.drop * 2
+    spill_off = nk_off + caps.nk * flowpack.NK_WORDS
+    table = table.copy()
+    regions = [flat[i * words:(i + 1) * words] for i in range(n_lanes)]
+    for lane, r in enumerate(regions):
+        nk = r[nk_off:spill_off].reshape(caps.nk, flowpack.NK_WORDS)
+        for row in nk[(nk[:, 0] >> 31) != 0]:
+            table[lane * slot_cap + int(row[0] & 0xFFFFF)] = row[1:]
+    cols = {}
+    for lane, r in enumerate(regions):
+        hot = r[hot_off:dns_off].reshape(bpl, flowpack.HOT_WORDS)
+        w0, w2 = hot[:, 0], hot[:, 2]
+        slots = np.minimum(w0 & 0xFFFFF, slot_cap - 1).astype(np.int64)
+        dns = np.zeros(bpl, np.int32)
+        for e in r[dns_off:drop_off]:
+            if (e >> 16) < bpl:
+                dns[e >> 16] += np.int32((e & 0xFFF) << ((e >> 12) & 0xF))
+        d_bytes, d_pkts, d_cause = (np.zeros(bpl, np.int32) for _ in "123")
+        for a, b in r[drop_off:nk_off].reshape(caps.drop, 2):
+            if (a >> 16) < bpl:
+                d_bytes[a >> 16] += np.int32(b & 0xFFFF)
+                d_pkts[a >> 16] += np.int32(b >> 16)
+                d_cause[a >> 16] = max(d_cause[a >> 16], np.int32(a & 0xFFFF))
+        sp = r[spill_off:].reshape(caps.spill, flowpack.DENSE_WORDS)
+        comp = {
+            "keys": (table[lane * slot_cap + slots], sp[:, :10]),
+            "bytes": (hot[:, 1].view(np.float32), sp[:, 10].view(np.float32)),
+            "packets": (w2 & 0x7FF, sp[:, 11]),
+            "rtt_us": (((w0 >> 20) & 0xFF) << (2 * ((w0 >> 28) & 0x7)),
+                       sp[:, 12]),
+            "dns_latency_us": (dns, sp[:, 13]),
+            "valid": ((w0 >> 31) != 0, sp[:, 14] != 0),
+            "sampling": (np.full(bpl, r[0]), sp[:, 15]),
+            "tcp_flags": ((w2 >> 11) & 0x7FF, sp[:, 16] & 0xFFFF),
+            "dscp": ((w2 >> 22) & 0x3F, (sp[:, 16] >> 16) & 0xFF),
+            "markers": (w2 >> 28, sp[:, 16] >> 24),
+            "drop_bytes": (d_bytes, sp[:, 17] & 0xFFFF),
+            "drop_packets": (d_pkts, sp[:, 17] >> 16),
+            "drop_cause": (d_cause, sp[:, 18] & 0xFFFF),
+        }
+        for k, parts in comp.items():
+            cols.setdefault(k, []).extend(parts)
+    kinds = {"keys": np.uint32, "bytes": np.float32, "valid": np.bool_}
+    return ({k: np.concatenate(v).astype(kinds.get(k, np.int32))
+             for k, v in cols.items()}, table)
+
+
+@needs_jax
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_key_table_layout_contract(case):
+    """The key tables are ONE (lanes * slot_cap, 10) array, lane `l`'s slot
+    `s` in row `l * slot_cap + s`. The trap this pins: an UNDEFINED new-key
+    row must index past the whole array — `lane * slot_cap + slot_cap` is
+    slot 0 of the next lane, occupied here — and a hot row without the
+    valid bit, whose slot field is garbage, must still read its own lane.
+    Arrays and table equal the NumPy reference bit for bit."""
+    import jax
+
+    from netobserv_tpu.sketch import state as sk
+
+    n_lanes, table_lanes = LAYOUT_CASES[case]
+    bpl, slot_cap = 32, 64
+    caps = flowpack.ResidentCaps(dns=8, drop=8, nk=8, spill=4)
+    words = flowpack.resident_buf_len(bpl, caps)
+    rng = np.random.default_rng(33)
+    # every slot of every lane occupied: a stray write shows anywhere
+    table0 = rng.integers(1, 1 << 32, (table_lanes * slot_cap, 10),
+                          dtype=np.uint32)
+    flat = np.empty(n_lanes * words, np.uint32)
+    n_defined = []
+    for lane in range(n_lanes):
+        # 2..6 distinct keys a lane, under caps.nk: the rest of the new-key
+        # lane stays UNDEFINED, in the last lane and in every lane before it
+        (events, feats), = make_feed(n_batches=1, seed=40 + lane, v6_every=5)
+        events["key"] = events["key"][np.arange(len(events)) % (2 + lane % 5)]
+        kd = flowpack.KeyDict(slot_cap)
+        region = flat[lane * words:(lane + 1) * words]
+        # an earlier fold took slots 0..2: this fold defines none of them,
+        # so slot 0 of the NEXT lane is occupied and nothing rewrites it
+        (older, _), = make_feed(n_batches=1, seed=90 + lane)
+        flowpack.pack_resident(older[:3], bpl, kd, caps, out=region)
+        assert kd.count() == 3
+        _, took = flowpack.pack_resident(events[:bpl - 3], bpl, kd, caps,
+                                         out=region, **{
+                                             k: v[:bpl - 3]
+                                             for k, v in feats.items()})
+        assert took == bpl - 3
+        n_defined.append(kd.count() - 3)
+        kd.close()
+        # a hot row past the packed ones: no valid bit, slot field all ones
+        region[flowpack.RESIDENT_HDR + (bpl - 1) * flowpack.HOT_WORDS] = (
+            0x000FFFFF)
+    assert all(0 < n < caps.nk for n in n_defined)
+
+    want, want_table = _np_decode_regions(flat, table0, bpl, caps, n_lanes,
+                                          slot_cap)
+    got, got_table = jax.jit(
+        lambda f, t: sk.resident_lane_arrays(f, t, bpl, caps, n_lanes,
+                                             slot_cap))(flat, table0)
+    got_table = np.asarray(got_table)
+    assert got_table.shape == (table_lanes * slot_cap, sk.KEY_WORDS)
+    np.testing.assert_array_equal(got_table, want_table)
+    # the writes are the defined new keys alone: slots 3..3+n-1 of each lane
+    changed = np.flatnonzero((got_table != table0).any(axis=1))
+    assert changed.tolist() == [lane * slot_cap + 3 + s
+                                for lane, n in enumerate(n_defined)
+                                for s in range(n)]
+    assert set(got) == set(want)
+    for k in want:
+        assert np.asarray(got[k]).dtype == want[k].dtype, k
+        np.testing.assert_array_equal(np.asarray(got[k]), want[k], err_msg=k)
+    assert int(want["valid"].sum()) == n_lanes * (bpl - 3)

@@ -76,7 +76,7 @@ def make_feats(n, seed=1):
 def _make_ring(ladder=(1, 2, 4), lanes=1, slot_cap=1 << 12):
     caps = flowpack.default_resident_caps(B // lanes)
     ingests = {k: sk.make_ingest_resident_lanes_fn(
-        B // lanes, caps, k * lanes, donate=True) for k in ladder}
+        B // lanes, caps, k * lanes, slot_cap, donate=True) for k in ladder}
     return staging.ShardedResidentStagingRing(
         B, 1, ingests,
         key_tables=jax.device_put(
@@ -403,6 +403,27 @@ def test_carried_rows_fold_once_and_match_the_finishing_form(tight_exporter):
     # dispatches for the same rows
     assert (sum(ring.superbatch_folds.values())
             < sum(plain.superbatch_folds.values()))
+
+
+def test_ring_makes_its_key_tables_at_the_first_fold(tight_exporter):
+    """The ladder warm-up folds through ONE spare table array of its own
+    and drops it; the ring's array is not alive beside it — it is made by
+    the first dispatch, (lanes * slot_cap, 10) a shard, and the gauge
+    counted its bytes from the geometry alone."""
+    from netobserv_tpu.metrics.registry import Metrics, MetricsSettings
+
+    metrics = Metrics(MetricsSettings())
+    exp = tight_exporter(metrics=metrics)
+    ring = exp._ring
+    assert ring.warm_entries() == [1, 2, 4]
+    assert ring._key_tables is None
+    exp.export_evicted(EvictedFlows(make_events(B, seed=4)))
+    tables = ring.key_tables
+    rows = ring.n_shards * ring.superbatch_max * ring.lanes * ring.slot_cap
+    assert tables.shape == (rows, sk.KEY_WORDS)
+    assert ring.key_tables is tables
+    assert (metrics.sketch_resident_table_bytes._value.get()
+            == rows * sk.KEY_WORDS * 4)
 
 
 def test_roll_flush_and_close_leave_nothing_carried(tight_exporter):

@@ -17,6 +17,7 @@ import subprocess
 import sys
 from unittest import mock
 
+import numpy as np
 import pytest
 
 import tests.conftest  # noqa: F401
@@ -74,8 +75,8 @@ def resident_ladder_entry(k: int, cfg=CFG, lanes: int = 8,
     bpl = BATCH // lanes
     caps = flowpack.default_resident_caps(bpl)
     fn = sk.make_ingest_resident_lanes_fn(
-        bpl, caps, k * lanes, name=f"ingest_resident_lanes_x{k}")
-    tables = jax.ShapeDtypeStruct((4 * lanes, slots, sk.KEY_WORDS),
+        bpl, caps, k * lanes, slots, name=f"ingest_resident_lanes_x{k}")
+    tables = jax.ShapeDtypeStruct((4 * lanes * slots, sk.KEY_WORDS),
                                   jnp.uint32)
     flat = jax.ShapeDtypeStruct(
         (k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
@@ -197,9 +198,9 @@ def test_sharded_resident_ladder_entry_lowers_named_and_scoped(k):
     caps = flowpack.default_resident_caps(bpl)
     name = f"sharded_ingest_resident_x{k}"
     fn = pmerge.make_sharded_ingest_resident_fn(
-        mesh, CFG, bpl, caps, lanes=k * lanes, watch_name=name)
+        mesh, CFG, bpl, caps, 1 << 18, lanes=k * lanes, watch_name=name)
     dist = jax.eval_shape(lambda: pmerge.init_dist_state(CFG, mesh))
-    tables = jax.ShapeDtypeStruct((4, 4 * lanes, 1 << 18, sk.KEY_WORDS),
+    tables = jax.ShapeDtypeStruct((4 * 4 * lanes << 18, sk.KEY_WORDS),
                                   jnp.uint32)
     flat = jax.ShapeDtypeStruct(
         (4 * k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
@@ -237,6 +238,104 @@ def test_tenant_roll_and_fold_delta_lower_named():
     dense = jax.ShapeDtypeStruct((n, BATCH * sk.DENSE_WORDS), jnp.uint32)
     assert "module @jit_tenant_ingest " in lowered(stack._ingest, state,
                                                    dense)
+
+
+# --- compiled for a described v5e: the key tables are never relaid ----------
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A v5e 2x2 host described to the TPU compiler (no chip attached), with
+    the persistent compile cache off while this module's tests run: such a
+    compile can be written to it but not read back without a chip. Only
+    this file describes a topology (one process may hold libtpu)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def compiled_entry(fn, *args) -> tuple:
+    """(header line, [instruction lines]) of the entry computation of
+    `fn(*args)` compiled for the TPU the arguments' shardings describe."""
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = fn.trace(*args).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    return (text.splitlines()[0],
+            entry[:entry.index("\n}")].splitlines()[2:])
+
+
+def assert_only_the_scatter_is_table_sized(header, entry, n_elements):
+    """In the entry computation, the instructions whose result holds
+    `n_elements` or more: the table's parameter, ONE fusion — the new-key
+    scatter, reading that parameter, its output aliased onto it — and the
+    root tuple. A pad, copy, slice or transpose of the table is a relayout
+    of hundreds of MB a fold (PERF.md section 6, PR 33)."""
+    big = {}
+    for line in entry:
+        m = re.match(r"\s*(ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(", line)
+        assert m, line[:200]
+        shapes = re.findall(r"\b(?:pred|bf16|[usf]\d+)\[([\d,]*)\]",
+                            m.group(3))
+        if any(s and np.prod([int(d) for d in s.split(",")]) >= n_elements
+               for s in shapes):
+            big[m.group(2)] = (m.group(4), bool(m.group(1)), line)
+    ops = sorted(op for op, _, _ in big.values())
+    assert ops == ["fusion", "parameter", "tuple"], [
+        line[:160] for _, _, line in big.values()]
+    (param, (_, _, pline)), = [kv for kv in big.items()
+                               if kv[1][0] == "parameter"]
+    (_, _, fline), = [v for v in big.values() if v[0] == "fusion"]
+    assert "resident_decode/scatter" in fline and f"fusion(%{param}," in fline
+    assert next(r for op, r, _ in big.values() if op == "tuple")
+    number = re.search(r"parameter\((\d+)\)", pline).group(1)
+    assert re.search(rf"\({number}, {{}}, may-alias\)", header), header[:300]
+
+
+def test_x1_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
+    """`ingest_resident_lanes_x1` at default geometry: 8 of the 32 lanes of
+    the array the x4 entry needs, 2^18 slots each."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(v5e.devices[0])
+    fn, args = resident_ladder_entry(1)
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one), args)
+    header, entry = compiled_entry(fn, *args)
+    assert_only_the_scatter_is_table_sized(
+        header, entry, sk.KEY_WORDS * 32 * (1 << 18))
+
+
+def test_per_shard_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
+    """`sharded_ingest_resident_x1` on mesh data=4: each chip's program
+    takes its rows of the sharded table as the same 2-D array."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(v5e.devices).reshape(4, 1), ("data", "sketch"))
+    lanes, slots = 2, 1 << 18
+    bpl = BATCH // (4 * lanes)
+    caps = flowpack.default_resident_caps(bpl)
+    fn = pmerge.make_sharded_ingest_resident_fn(
+        mesh, CFG, bpl, caps, slots, lanes=lanes,
+        watch_name="sharded_ingest_resident_x1")
+    cpu_mesh = make_mesh(MeshSpec(data=4), devices=jax.devices()[:4])
+    shapes = jax.eval_shape(lambda: (
+        pmerge.init_dist_state(CFG, cpu_mesh),
+        pmerge.init_resident_tables(cpu_mesh, slots, lanes=4 * lanes)))
+    flat = jax.ShapeDtypeStruct(
+        (4 * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
+    dist, tables, flat = jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+        (*shapes, flat),
+        (pmerge._state_specs(sk.init_state(CFG)), P("data"), P("data")))
+    header, entry = compiled_entry(fn, dist, tables, flat)
+    assert_only_the_scatter_is_table_sized(
+        header, entry, sk.KEY_WORDS * 4 * lanes * slots)
 
 
 # --- compile cache placement (utils/platform.enable_compile_cache) ---------

@@ -105,10 +105,12 @@ def test_exact_answers_equal_the_default_geometrys(served, name):
 @pytest.mark.parametrize("name", GEOMETRIES)
 def test_key_table_gauge_reads_the_bytes_as_allocated(served, name):
     """One table a pack region (max(ladder) x the host's pack lanes: 32 on
-    an 8-core host), SKETCH_RESIDENT_SLOTS rows of 10 key words."""
-    regions, slots, words = served[name]["table_shape"]
-    assert (slots, words) == (GEOMETRIES[name][1], 10) and regions % 4 == 0
-    assert served[name]["table_bytes"] == regions * slots * words * 4
+    an 8-core host), SKETCH_RESIDENT_SLOTS rows each, of 10 key words: one
+    (regions * slots, 10) array."""
+    rows, words = served[name]["table_shape"]
+    regions, rest = divmod(rows, GEOMETRIES[name][1])
+    assert (words, rest) == (10, 0) and regions and regions % 4 == 0
+    assert served[name]["table_bytes"] == rows * words * 4
 
 
 def test_state_gauge_grows_by_the_wider_planes_alone(served):
