@@ -321,9 +321,7 @@ class ArchiveQueryEngine:
         rng = snap["range"]
         if view in ("", "summary"):
             body = qcore.cardinality_payload(snap)
-            bars = qcore.cm_error_bars(snap)
-            if bars is not None:
-                body.update(bars)
+            body.update(qcore.cm_error_bars(snap))
         elif view == "topk":
             body = qcore.topk_payload(snap, params.get("n", 100))
         elif view == "cardinality":

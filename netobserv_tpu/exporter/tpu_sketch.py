@@ -616,21 +616,17 @@ class TpuSketchExporter(Exporter):
             if self._delta_sink is not None and spec.sketch > 1:
                 # width-sharded CM planes are independent local-width
                 # sketches — there is no whole-width snapshot to frame
-                # (parallel/merge.py make_merge_fn with_tables contract)
                 log.warning("federation delta export needs a data-axis-only "
                             "mesh; disabling it on this %dx%d exporter",
                             spec.data, spec.sketch)
                 self._drop_delta_sink()
-            # the query plane (and the delta export) need the merged
-            # whole-width table snapshot; it exists only on data-axis-only
-            # meshes — width-sharded CM planes are independent local-width
-            # sketches (parallel/merge.py make_merge_fn contract). Without
-            # tables the /query/frequency route answers 503; the
-            # report-backed routes still serve.
-            self._with_tables = spec.sketch == 1
+            # the merged table snapshot is one extra output of the roll on
+            # every mesh: whole-width planes on a data-axis-only mesh, the
+            # per-owner-shard local-width planes [S, depth, width / S] on a
+            # width-sharded one (/query/frequency answers from either)
             self._roll = pmerge.make_merge_fn(
                 self._mesh, self._cfg, decay_factor=decay_factor,
-                with_tables=self._with_tables)
+                with_tables=True)
             if feed == "resident":
                 # resident feed over the mesh: per-data-shard dictionaries
                 # + device key tables (~15B/record instead of dense's 80;
@@ -681,7 +677,6 @@ class TpuSketchExporter(Exporter):
             # the Record path routes through the stack's fold_rows; there
             # is no separate unstacked ingest entry to dispatch
             self._ingest = None
-            self._with_tables = True
             # ONE stacked roll closes every tenant's window; _roll_locked
             # drives it through the same (state, report, tables) contract
             self._roll = self._tenancy.roll
@@ -702,7 +697,6 @@ class TpuSketchExporter(Exporter):
             # one extra output of the same roll executable, and it feeds
             # BOTH the federation delta export and the query plane's
             # per-roll snapshot (/query/frequency needs the CM planes)
-            self._with_tables = True
             self._roll = sk.make_roll_fn(
                 self._cfg, decay_factor=decay_factor, with_tables=True,
                 name="roll", tiered=self._tier_roll_form)
@@ -778,7 +772,7 @@ class TpuSketchExporter(Exporter):
         # /query/range merges archived segments on demand. None
         # (ARCHIVE_DIR unset) keeps the publish path bit-identical: no
         # store, no engine, one is-None check (the zero-cost bar).
-        if archive is not None and not self._with_tables:
+        if archive is not None and self._mesh_shards()["sketch"] > 1:
             # width-sharded meshes have no whole-width table snapshot to
             # archive (the same contract that disables the delta export)
             log.warning("sketch archive needs a data-axis-only mesh; "
@@ -828,6 +822,8 @@ class TpuSketchExporter(Exporter):
             # tenants resident per HBM — made visible per agent
             from netobserv_tpu.sketch.tiered import array_bytes
             metrics.sketch_resident_hbm_bytes.set(array_bytes(self._state))
+            for axis, n in self._mesh_shards().items():
+                metrics.sketch_mesh_shards.labels(axis=axis).set(n)
             # the key tables live in the staging ring, not in the state:
             # at SKETCH_RESIDENT_SLOTS=2^20 they are 1.34 GB (2.15 GB as
             # a TPU lays 10 words out on 16 sublanes) beside a state of
@@ -1776,11 +1772,7 @@ class TpuSketchExporter(Exporter):
             self._overload.window_roll()
         with wtrace.stage("roll_dispatch"):
             with self._roll_mutex:  # vs a concurrent refresh roll
-                if self._with_tables:
-                    self._state, report, tables = self._roll(self._state)
-                else:
-                    self._state, report = self._roll(self._state)
-                    tables = None
+                self._state, report, tables = self._roll(self._state)
         # the window trace rides the queued report; render/sink spans attach
         # at publish time on the timer thread (the gap in between is the
         # report's queue wait)
@@ -1882,10 +1874,8 @@ class TpuSketchExporter(Exporter):
             "window": obj["Window"],
             "ts_ms": obj["TimestampMs"],
             "report": obj,
-            "cm_bytes": (np.asarray(tables["cm_bytes"])
-                         if tables is not None else None),
-            "cm_pkts": (np.asarray(tables["cm_pkts"])
-                        if tables is not None else None),
+            "cm_bytes": np.asarray(tables["cm_bytes"]),
+            "cm_pkts": np.asarray(tables["cm_pkts"]),
         }
         if tenant is not None:
             snap["tenant"] = int(tenant)
@@ -1900,6 +1890,12 @@ class TpuSketchExporter(Exporter):
         if self._alerts is not None:
             self._alerts.safe_evaluate(snap, mid_window=mid_window)
 
+    def _mesh_shards(self) -> dict:
+        """{"data": D, "sketch": S} of the device mesh (1 x 1 off a mesh)."""
+        if not self._distributed:
+            return {"data": 1, "sketch": 1}
+        return {k: int(v) for k, v in self._mesh.shape.items()}
+
     def query_status(self) -> dict:
         """/query/status payload: snapshot freshness + plane counters.
         Reads the publisher ONCE and derives seq/window/mid_window from
@@ -1912,6 +1908,11 @@ class TpuSketchExporter(Exporter):
                    "window_s": self._window_s,
                    "refresh_s": self._query_refresh_s,
                    "overloaded": self.overloaded})
+        # the device mesh, and the width of the Count-Min planes ONE chip
+        # folds into and /query/frequency indexes (cm_width / sketch shards)
+        shards = self._mesh_shards()
+        st["mesh"] = shards
+        st["cm_local_width"] = self._cfg.cm_width // shards["sketch"]
         ring = self._ring
         if isinstance(ring, staging.ShardedResidentStagingRing):
             # which superbatch entries are compiled and selectable (the
@@ -1999,11 +2000,7 @@ class TpuSketchExporter(Exporter):
             # federation checkpoint staging pattern).
             staged = jax.tree.map(jnp.copy, self._state)
         with self._roll_mutex:  # vs a concurrent window-close roll
-            out = self._roll(staged)
-        if self._with_tables:
-            _discard, report, tables = out
-        else:
-            (_discard, report), tables = out, None
+            _discard, report, tables = self._roll(staged)
         ts_ms = time.time_ns() // 1_000_000
         if self._tenancy is not None:
             # stacked refresh: one staged roll already closed every

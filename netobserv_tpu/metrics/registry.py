@@ -292,6 +292,15 @@ class Metrics:
             "SKETCH_TIERED shrinks this ~4x over the counter tables — "
             "the windows/tenants-per-HBM capacity signal",
             registry=self.registry)
+        self.sketch_mesh_shards = Gauge(
+            p + "sketch_mesh_shards",
+            "Shards of the sketch device mesh by axis (SKETCH_MESH_SHAPE "
+            "\"DxS\"): axis=\"data\" splits the rows, axis=\"sketch\" "
+            "the Count-Min width and the slot top-K by key ownership "
+            "(sketch > 1 is a width-sharded mesh: each chip's planes are "
+            "width / sketch wide). 1 and 1 on a single device; set once "
+            "at exporter construction",
+            ["axis"], registry=self.registry)
         self.sketch_resident_table_bytes = Gauge(
             p + "sketch_resident_table_bytes",
             "Bytes of the resident feed's device key tables as allocated "

@@ -158,42 +158,27 @@ def merge(a: CountMin, b: CountMin) -> CountMin:
 
 
 # ---------------------------------------------------------------------------
-# Width-sharded variants: the [d, W] counter array is split across the
-# `sketch` mesh axis by KEY OWNERSHIP (model-parallel sketches — SURVEY.md
-# §2.3 mapping). An independent hash assigns every key to one shard; the
-# owner folds the key's ENTIRE depth into its local [d, W/nsk] subtable.
-# Owner-locality is the point: a shard can point-query its own keys with NO
-# collective — which is what lets the steady-state ingest (top-K candidate
-# scoring, sketch/state.py) run collective-free on 2D meshes. The psum query
-# exists only for the window-roll merge. Per-key error matches an unsharded
-# width-W sketch: each shard holds ~1/nsk of the keys in 1/nsk of the
-# columns, so counter load (keys per column) is unchanged.
+# Width sharding: the [d, W] counter array is split across the `sketch`
+# mesh axis by KEY OWNERSHIP (model-parallel sketches — SURVEY.md §2.3
+# mapping). An independent hash assigns every key to one shard; the owner
+# folds the key's ENTIRE depth into its local [d, W/nsk] subtable, which is
+# an ordinary width-W/nsk sketch of the rows it owns: the fold is `update_two`
+# (or its kernel twin) with `valid & owned` for `valid`, the point query is
+# `query` on the local plane (sketch/state.ingest; query/core on the host).
+# Owner-locality is the point: a shard point-queries its own keys with NO
+# collective — which is what lets the steady-state ingest run collective-free
+# on 2D meshes. The psum query exists only for the window-roll merge. Per-key
+# error matches an unsharded width-W sketch: each shard holds ~1/nsk of the
+# keys in 1/nsk of the columns, so counter load (keys per column) is
+# unchanged.
 # ---------------------------------------------------------------------------
 
 def owner_shard(h1: jax.Array, h2: jax.Array, n_shards: int) -> jax.Array:
     """Which sketch shard owns each key — an independent hash of the 64-bit
-    key identity (decorrelated from the column hashes)."""
+    key identity (decorrelated from the column hashes). Host twin:
+    `hashing.owner_shard_np` (pinned equal, tests/test_width_sharded_served)."""
     return (hashing.fmix32(h1 ^ (h2 * jnp.uint32(0x9E3779B1)))
             % jnp.uint32(n_shards)).astype(jnp.int32)
-
-
-def update_sharded(cm_local: CountMin, h1: jax.Array, h2: jax.Array,
-                   values: jax.Array, valid: jax.Array,
-                   axis_name: str, n_shards: int) -> CountMin:
-    """Fold a batch into an owner-sharded sketch (call inside shard_map):
-    each shard accumulates only the keys it owns, at full depth."""
-    shard = jax.lax.axis_index(axis_name).astype(jnp.int32)
-    mine = valid & (owner_shard(h1, h2, n_shards) == shard)
-    return update(cm_local, h1, h2, values, mine)
-
-
-def query_sharded_local(cm_local: CountMin, h1: jax.Array, h2: jax.Array,
-                        axis_name: str, n_shards: int) -> jax.Array:
-    """Collective-free point query: complete estimates for keys THIS shard
-    owns, -1 (dead) for everyone else's. The steady-state scoring primitive."""
-    shard = jax.lax.axis_index(axis_name).astype(jnp.int32)
-    mine = owner_shard(h1, h2, n_shards) == shard
-    return jnp.where(mine, query(cm_local, h1, h2), -1.0)
 
 
 def query_sharded(cm_local: CountMin, h1: jax.Array, h2: jax.Array,

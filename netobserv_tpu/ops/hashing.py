@@ -228,6 +228,20 @@ def hash_words_np(words: np.ndarray, seed: int = 0) -> np.ndarray:
     return h
 
 
+def owner_shard_np(h1: np.ndarray, h2: np.ndarray,
+                   n_shards: int) -> np.ndarray:
+    """Pure-numpy twin of `ops.countmin.owner_shard`: which sketch shard of a
+    width-sharded mesh owns each key identity — the host query surface
+    (query/core.frequency_payload) picks the plane to index with it."""
+    with np.errstate(over="ignore"):
+        h = (np.asarray(h1, np.uint32)
+             ^ (np.asarray(h2, np.uint32) * np.uint32(0x9E3779B1)))
+        for shift, mult in ((16, _F1), (13, _F2)):     # fmix32
+            h = (h ^ (h >> np.uint32(shift))) * mult
+        h = h ^ (h >> np.uint32(16))
+    return (h % np.uint32(n_shards)).astype(np.int32)
+
+
 def tenant_of(words: jax.Array, n_tenants: int) -> jax.Array:
     """Tenant owner of each flow key: int32[...] in [0, n_tenants).
 

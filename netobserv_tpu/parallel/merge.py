@@ -10,10 +10,13 @@ Layout of the distributed state (`DistState` = SketchState pytree with a leading
                             only `rate` is a true partial)
 
 Steady state does **zero collectives**: each device folds its batch shard into
-its partial (the per-CPU-map analog, SURVEY.md §2.3 item 1). All communication
-happens at window roll: psum for linear sketches, max for HLL registers,
-all_gather + re-select for the top-K table — the ICI merge the north star asks
-for (BASELINE.json config 3).
+its partial (the per-CPU-map analog, SURVEY.md §2.3 item 1), on a sketch axis
+> 1 the rows it OWNS into its local-width planes, through the same fold forms
+as a whole-width replica (`_local_ingest`). All communication happens at
+window roll: psum for linear sketches, max for HLL registers, all_gather +
+re-select for the top-K table — the ICI merge the north star asks for
+(BASELINE.json config 3) — and, for the table snapshot of a width-sharded
+mesh, one all_gather of the merged planes over the sketch axis.
 """
 
 from __future__ import annotations
@@ -136,6 +139,19 @@ def shard_batch(mesh: Mesh, arrays: dict[str, np.ndarray]) -> dict[str, jax.Arra
 # ---------------------------------------------------------------------------
 
 
+def _local_ingest(s: sk.SketchState, arrays: dict, mesh: Mesh,
+                  cfg: sk.SketchConfig) -> sk.SketchState:
+    """One device's fold inside the shard_map. With a sketch axis the
+    Count-Min planes and the slot table are owner-sharded: the same fold
+    with ownership as a row mask, in the forms `sk.fold_forms` picks at the
+    LOCAL width. The mesh rides the entry's /debug/executables row."""
+    ndata, nsk = mesh.shape[DATA_AXIS], mesh.shape[SKETCH_AXIS]
+    retrace.label("mesh", f"{ndata}x{nsk}")
+    return sk.ingest(s, arrays,
+                     sketch_axis=SKETCH_AXIS if nsk > 1 else None,
+                     sketch_shards=nsk, use_pallas=cfg.use_pallas)
+
+
 def make_sharded_ingest_fn(mesh: Mesh, cfg: sk.SketchConfig,
                            donate: bool = True,
                            dense: bool = False,
@@ -152,19 +168,13 @@ def make_sharded_ingest_fn(mesh: Mesh, cfg: sk.SketchConfig,
     `sketch.state.make_ingest_dense_fn`)."""
     if with_token and not dense:
         raise ValueError("with_token requires dense=True")
-    nsk = mesh.shape[SKETCH_AXIS]
     template = sk.init_state(cfg)
     specs = _state_specs(template)
 
     def local_step(pstate: sk.SketchState, batch):
         s = _drop_lead(pstate)
         arrays = sk.dense_to_arrays(batch) if dense else batch
-        s = sk.ingest(s, arrays,
-                      sketch_axis=SKETCH_AXIS if nsk > 1 else None,
-                      sketch_shards=nsk,
-                      # owner-sharded sketches keep the masked-scatter path;
-                      # the Pallas fold applies to whole-width replicas
-                      use_pallas=(cfg.use_pallas if nsk == 1 else False))
+        s = _local_ingest(s, arrays, mesh, cfg)
         out = _add_lead(s)
         if with_token:
             return out, (batch[:1] if batch.ndim == 1 else batch[:1, 0])
@@ -228,7 +238,6 @@ def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
     off the array — `sketch.state.resident_lane_arrays`, which the
     per-shard step runs on its own rows); `watch_name` distinguishes
     ladder entries in the retrace watchdog accounting."""
-    nsk = mesh.shape[SKETCH_AXIS]
     template = sk.init_state(cfg)
     specs = _state_specs(template)
 
@@ -236,10 +245,7 @@ def make_sharded_ingest_resident_fn(mesh: Mesh, cfg: sk.SketchConfig,
         s = _drop_lead(pstate)
         arrays, tbl = sk.resident_lane_arrays(flat, table, batch_per_lane,
                                               caps, lanes, slot_cap)
-        s = sk.ingest(s, arrays,
-                      sketch_axis=SKETCH_AXIS if nsk > 1 else None,
-                      sketch_shards=nsk,
-                      use_pallas=(cfg.use_pallas if nsk == 1 else False))
+        s = _local_ingest(s, arrays, mesh, cfg)
         return _add_lead(s), tbl, flat[:1]
 
     shmapped = jax.shard_map(
@@ -431,16 +437,16 @@ def make_merge_fn(mesh: Mesh, cfg: sk.SketchConfig,
 
     `with_tables=True` additionally returns the REPLICATED merged table
     snapshot (`sketch.state.state_tables` of the merged pre-roll state) —
-    the federation aggregator's query-surface source on mesh deployments.
-    Data-axis-only meshes (like the federation fold itself: on a
-    width-sharded mesh the per-shard CM planes are independent local-width
-    sketches with no replicated whole-width form).
+    the query surface's source on mesh deployments. On a width-sharded mesh
+    the two Count-Min planes of that snapshot are `[nsk, depth, width / nsk]`:
+    shard `s` is the independent local-width sketch of the keys
+    `countmin.owner_shard` gives to `s` (psum over `data`, then one
+    all_gather over `sketch`, named scope `merge_tables_gather`); every
+    other table is as on a data-axis-only mesh. There is no whole-width
+    form of such planes, so the federation fold and the archive stay
+    data-axis-only (`make_fold_delta_fn`).
     """
     nsk = mesh.shape[SKETCH_AXIS]
-    if with_tables and nsk > 1:
-        raise ValueError("with_tables requires a data-axis-only mesh (Nx1) "
-                         "— width-sharded CM planes have no replicated "
-                         "whole-width snapshot")
     template = sk.init_state(cfg)
     specs = _state_specs(template)
 
@@ -465,6 +471,11 @@ def make_merge_fn(mesh: Mesh, cfg: sk.SketchConfig,
         tables = None
         if with_tables:
             tables = sk.state_tables(merged)
+            if nsk > 1:
+                with jax.named_scope("merge_tables_gather"):
+                    for name in ("cm_bytes", "cm_pkts"):
+                        tables[name] = jax.lax.all_gather(
+                            tables[name], SKETCH_AXIS, axis=0)
         ddos_state, z = ewma.roll(merged.ddos, cfg.ewma_alpha)
         syn_state, syn_z = ewma.roll(merged.syn, cfg.ewma_alpha)
         drops_state, drop_z = ewma.roll(merged.drops_ewma, cfg.ewma_alpha)

@@ -12,17 +12,16 @@ at a window boundary:
                 reader holding one sees a single window's consistent view;
                 pollers order responses by ``(window, seq)``)
 - ``report``   dict — the rendered window report (`report_to_json` shape)
-- ``cm_bytes``/``cm_pkts`` — f32[depth, width] Count-Min planes, or None
-                when the deployment has no whole-width snapshot
-                (width-sharded meshes)
+- ``cm_bytes``/``cm_pkts`` — the merged Count-Min planes: f32[depth, width],
+                or on a width-sharded mesh f32[shards, depth, width / shards]
+                (shard ``s`` is the independent local-width sketch of the
+                keys `ops.countmin.owner_shard` gives to ``s``)
 
 The CM error-bar math (Cormode–Muthukrishnan) and the victim-bucket naming
 (DST_BUCKET_SEED via `ops/hashing`, never inlined) live ONLY here.
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 import numpy as np
 
@@ -62,33 +61,35 @@ def _stamp(snap: dict, payload: dict) -> dict:
             "seq": snap.get("seq", 0), **payload}
 
 
-def cm_error_bars(snap: dict) -> Optional[dict]:
+def _bound(plane: np.ndarray) -> float:
+    """Cormode–Muthukrishnan: a point query of `plane` [depth, width]
+    overestimates by at most (e / width) x N, N the mass folded into it
+    (any one depth row sums to N)."""
+    return np.e / plane.shape[1] * float(np.sum(plane[0]))
+
+
+def cm_error_bars(snap: dict) -> dict:
     """The Cormode–Muthukrishnan overestimate bound of the snapshot's CM
     planes — THE error-bar math (shared by /query/frequency and
     /query/topk; the slot-table counts ARE CM point estimates, so the
-    same bound applies to every rendered heavy hitter). None when the
-    deployment has no whole-width CM snapshot (width-sharded meshes)."""
-    cm = snap.get("cm_bytes")
-    if cm is None:
-        return None
-    d, w = cm.shape
-    eps = np.e / w
+    same bound applies to every rendered heavy hitter). On a width-sharded
+    mesh every heavy hitter was scored by its owner shard's local-width
+    plane: the bound stated is the widest of the shards' own."""
+    cm = snap["cm_bytes"]
+    planes = cm if cm.ndim == 3 else cm[None]
     return {
-        "overestimate_bound_bytes": eps * float(np.sum(cm[0])),
-        "confidence": 1.0 - float(np.exp(-d)),
+        "overestimate_bound_bytes": max(_bound(p) for p in planes),
+        "confidence": 1.0 - float(np.exp(-planes.shape[1])),
     }
 
 
 def topk_payload(snap: dict, n: int = 100) -> dict:
     n = max(1, min(int(n), 1024))
-    payload = {"topk": snap["report"]["HeavyHitters"][:n]}
-    bars = cm_error_bars(snap)
-    if bars is not None:
-        # every EstBytes (and churn count) is a CM point estimate: true
-        # count <= estimate <= true + bound with the stated confidence —
-        # the same bars /query/frequency renders, from the ONE helper
-        payload.update(bars)
-    return _stamp(snap, payload)
+    # every EstBytes (and churn count) is a CM point estimate: true
+    # count <= estimate <= true + bound with the stated confidence —
+    # the same bars /query/frequency renders, from the ONE helper
+    return _stamp(snap, {"topk": snap["report"]["HeavyHitters"][:n],
+                         **cm_error_bars(snap)})
 
 
 def churn_payload(snap: dict) -> dict:
@@ -104,10 +105,8 @@ def churn_payload(snap: dict) -> dict:
         "new_heavy": report.get("NewHeavyKeys", []),
         "evicted": report.get("EvictedKeys", []),
         "summary": report.get("HeavyChurn", {}),
+        **cm_error_bars(snap),
     }
-    bars = cm_error_bars(snap)
-    if bars is not None:
-        payload.update(bars)
     return _stamp(snap, payload)
 
 
@@ -130,18 +129,19 @@ def victims_payload(snap: dict) -> dict:
 
 
 def frequency_payload(snap: dict, src: str, dst: str, src_port: int = 0,
-                      dst_port: int = 0, proto: int = 0) -> Optional[dict]:
+                      dst_port: int = 0, proto: int = 0) -> dict:
     """CM point query with error bars against the snapshot's merged planes —
-    pure host numpy through the hashing twins. Returns None when the
-    snapshot carries no whole-width CM planes (width-sharded mesh)."""
-    cm = snap.get("cm_bytes")
-    cm_pkts = snap.get("cm_pkts")
-    if cm is None or cm_pkts is None:
-        return None
+    pure host numpy through the hashing twins. On a width-sharded mesh the
+    key's OWNER shard answers (`hashing.owner_shard_np`): its plane is
+    indexed at the local width and the bar comes from that shard's own mass
+    and width, and the payload names `shard` and that local `width`."""
+    cm = snap["cm_bytes"]
+    cm_pkts = snap["cm_pkts"]
     from netobserv_tpu.model import binfmt
     from netobserv_tpu.model.columnar import pack_key_words
     from netobserv_tpu.model.flow import FlowKey
-    from netobserv_tpu.ops.hashing import base_hashes_multi_np
+    from netobserv_tpu.ops.hashing import (
+        base_hashes_multi_np, owner_shard_np)
 
     fk = FlowKey.make(src, dst, src_port, dst_port, proto)
     karr = np.zeros(1, binfmt.FLOW_KEY_DTYPE)
@@ -152,20 +152,20 @@ def frequency_payload(snap: dict, src: str, dst: str, src_port: int = 0,
     karr["proto"] = proto
     words = pack_key_words(karr)
     h = base_hashes_multi_np(words)
+    where = {}
+    if cm.ndim == 3:
+        shard = int(owner_shard_np(h["h1"], h["h2"], cm.shape[0])[0])
+        cm, cm_pkts = cm[shard], cm_pkts[shard]
+        where = {"shard": shard, "width": int(cm.shape[1])}
     d, w = cm.shape
     with np.errstate(over="ignore"):
         idx = (h["h1"][0] + np.arange(d, dtype=np.uint32) * h["h2"][0]) \
             & np.uint32(w - 1)
-    est_bytes = float(np.min(cm[np.arange(d), idx]))
-    est_pkts = float(np.min(cm_pkts[np.arange(d), idx]))
-    # Cormode–Muthukrishnan: overestimate <= (e/w)*N with prob 1-e^-d
-    n_bytes = float(np.sum(cm[0]))
-    n_pkts = float(np.sum(cm_pkts[0]))
-    eps = np.e / w
     return _stamp(snap, {
-        "est_bytes": est_bytes,
-        "est_packets": est_pkts,
-        "overestimate_bound_bytes": eps * n_bytes,
-        "overestimate_bound_packets": eps * n_pkts,
+        "est_bytes": float(np.min(cm[np.arange(d), idx])),
+        "est_packets": float(np.min(cm_pkts[np.arange(d), idx])),
+        "overestimate_bound_bytes": _bound(cm),
+        "overestimate_bound_packets": _bound(cm_pkts),
         "confidence": 1.0 - float(np.exp(-d)),
+        **where,
     })

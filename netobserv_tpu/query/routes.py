@@ -186,11 +186,7 @@ class QueryRoutes:
         # frequency
         if not params.get("src") or not params.get("dst"):
             return 400, {"error": "src and dst are required"}
-        out = core.frequency_payload(
+        return 200, core.frequency_payload(
             snap, params["src"], params["dst"],
             int(params.get("src_port", 0)), int(params.get("dst_port", 0)),
             int(params.get("proto", 0)))
-        if out is None:
-            return 503, {"error": "no whole-width CM snapshot on this "
-                                  "deployment (width-sharded mesh)"}
-        return 200, out

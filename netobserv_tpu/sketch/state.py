@@ -366,16 +366,18 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
     """Fold one batch into all sketches. Pure; jit with donate_argnums=0.
 
     When `sketch_axis` is set (inside shard_map over a 2D mesh), the Count-Min
-    arrays are width-sharded across that axis: updates mask out-of-shard
-    columns, queries psum partial gathers (model-parallel sketches).
-
-    Width-sharded (2D mesh) steady state performs NO collectives at all: the
-    Count-Min is sharded by KEY OWNERSHIP (`countmin.owner_shard`), so each
-    sketch shard folds and point-queries its own keys entirely locally
-    (`query_sharded_local`) and keeps a top-K table of just its keys. The
-    one psum-backed exact query (`query_sharded`) runs only inside the
-    window-roll merge, which gathers per-shard tables and re-scores against
-    the globally merged sketch (`parallel.merge.merge_states`).
+    planes and the slot table are width-sharded across that axis by KEY
+    OWNERSHIP (`countmin.owner_shard`): `state.cm_*` are the LOCAL-width
+    planes of this shard, and ownership is a row mask (named scope
+    `owner_mask`) — the planes fold the rows this shard owns through the
+    same form as a whole-width replica (`fold_forms` at the local width),
+    the slot walk sees every other row dead, and the HLL and signal kernels
+    fold the replicated planes as on one chip. Steady state performs NO
+    collectives at all: a shard folds and point-queries its own keys
+    entirely locally. The one psum-backed exact query (`query_sharded`)
+    runs only inside the window-roll merge, which gathers per-shard tables
+    and re-scores against the globally merged sketch
+    (`parallel.merge.merge_states`).
     """
     if isinstance(state, tiered.TieredState):
         # tiered counter planes: decode the resident tiers to the canonical
@@ -450,21 +452,21 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
         src_h1, src_h2 = mhash.src_h1, mhash.src_h2
         dst_h1 = mhash.dst_h1
 
+    # a width-sharded mesh changes WHICH rows a chip folds into its Count-Min
+    # planes and its slot table, not how: ownership is a row mask (`mine`
+    # stands where `valid` does) and the planes are the local width
+    owned = None
+    mine = valid
+    if sketch_axis is not None:
+        with jax.named_scope("owner_mask"):
+            owned = countmin.owner_shard(h1, h2, sketch_shards) == \
+                jax.lax.axis_index(sketch_axis).astype(jnp.int32)
+            mine = valid & owned
+
     # each branch folds the two Count-Min planes and says how the slot
     # top-K scores against them (`topk_kw`); the walk itself is one call
     with jax.named_scope("countmin"):
-        if sketch_axis is not None:
-            cm_b = countmin.update_sharded(state.cm_bytes, h1, h2, bytes_f,
-                                           valid, sketch_axis, sketch_shards)
-            cm_p = countmin.update_sharded(state.cm_pkts, h1, h2, pkts,
-                                           valid, sketch_axis, sketch_shards)
-            # collective-free scoring: this shard fully owns its keys'
-            # counters, so its table tracks exactly the keys it owns (the
-            # merge gathers tables across the sketch axis and re-scores
-            # globally)
-            topk_kw = dict(query_fn=lambda a, b: countmin.query_sharded_local(
-                cm_b, a, b, sketch_axis, sketch_shards))
-        elif _tier is not None:
+        if _tier is not None:
             # tier-interior: the CM fields here are zero-size placeholders
             # (whose width trivially tiles) — the walk reads and promotes
             # the resident tier arrays directly
@@ -482,27 +484,36 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             topk_kw = dict(query_fn=lambda a, b: est,
                            use_pallas=state.heavy.k % 128 == 0)
         else:
-            # the form `fold_forms` chose for this width, named in the ops'
-            # metadata (countmin/factored | countmin/scatter) and on the
-            # /debug/executables row of the entry being traced
+            # the form `fold_forms` chose for this width (the LOCAL width on
+            # a width-sharded mesh), named in the ops' metadata
+            # (countmin/factored | countmin/scatter) and, with that width,
+            # on the /debug/executables row of the entry being traced
             retrace.label("countmin", cm_form)
+            retrace.label("countmin_width", str(state.cm_bytes.width))
             with jax.named_scope(cm_form):
                 if cm_form == "factored":
                     from netobserv_tpu.ops.pallas import countmin_kernel
                     # fused: both planes share hash indices + one-hot build
                     cm_b, cm_p = countmin_kernel.update_two(
                         state.cm_bytes, state.cm_pkts, h1, h2, bytes_f,
-                        pkts.astype(jnp.float32), valid)
+                        pkts.astype(jnp.float32), mine)
                 else:
                     cm_b, cm_p = countmin.update_two(
                         state.cm_bytes, state.cm_pkts, h1, h2, bytes_f, pkts,
-                        valid)
+                        mine)
             # persistent-slot maintenance in the batch walk: the fused
             # Pallas reduction twin engages with the other kernels
             # (lane-aligned K); the scatter form everywhere else —
             # bit-exact either way (tests/test_pallas_topk.py pins it)
             topk_kw = dict(
                 use_pallas=use_pallas and state.heavy.k % 128 == 0)
+            if owned is not None:
+                # collective-free scoring: this shard fully owns its keys'
+                # counters, so its table tracks exactly the keys it owns
+                # and every other row is dead to it (the merge gathers the
+                # tables across the sketch axis and re-scores globally)
+                topk_kw["query_fn"] = lambda a, b: jnp.where(
+                    owned, countmin.query(cm_b, a, b), -1.0)
     with jax.named_scope("topk"):
         heavy, evicted = topk.slot_update(
             state.heavy, cm_b, words, h1, h2, valid, window=state.window,
@@ -512,8 +523,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             # the global-src bank stays 6-bit packed; the fused signal walk
             # below folds it and stashes the new packed bank in the hook
             hll_src = state.hll_src  # zero-size placeholder
-        elif (use_pallas and sketch_axis is None
-                and state.hll_src.regs.shape[0] % 512 == 0):
+        elif use_pallas and state.hll_src.regs.shape[0] % 512 == 0:
             from netobserv_tpu.ops.pallas import hll_kernel
             hll_src = hll_kernel.update(state.hll_src, src_h1, src_h2, valid)
         else:
@@ -591,7 +601,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
         # false one-way alert every window; exclude them from the signal
         conv_ok = valid & (src_sym != dst_h1)
 
-        use_signal_kernel = use_pallas and sketch_axis is None
+        use_signal_kernel = use_pallas
         if use_signal_kernel:
             from netobserv_tpu.ops.pallas import signal_kernel
             planes = signal_kernel.SignalPlanes(
@@ -656,7 +666,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             conv_fwd, conv_rev = out.conv_fwd, out.conv_rev
             dscp_bytes, drop_causes = out.dscp_bytes, out.drop_causes
         else:
-            # un-fused scatter chain (CPU / owner-sharded / ineligible shapes)
+            # un-fused scatter chain (CPU / ineligible shapes)
             # — the fused kernel above is equivalence-pinned against exactly
             # this path (tests/test_pallas_signal.py)
             ddos = ewma.accumulate(state.ddos, dst_h1, bytes_f, valid)

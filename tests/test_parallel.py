@@ -15,8 +15,11 @@ import jax.numpy as jnp
 # sharding in the default gate. The two *_has_no_collectives HLO-text
 # checks stay UN-marked: they only lower (no device execution) and they
 # pin the CLAUDE.md steady-state no-collectives invariant — that guard
-# must stay inside the tier-1 keep-it-green loop.
+# must stay inside the tier-1 keep-it-green loop. So does ONE small (2, 2)
+# case of every width-sharded answer comparison below (PR 34: four of the
+# eight devices, seconds each); their larger meshes stay in the slow tier.
 slow = pytest.mark.slow
+
 
 from netobserv_tpu.parallel import make_mesh, MeshSpec, merge as pmerge
 from netobserv_tpu.sketch import state as sk
@@ -49,15 +52,17 @@ def make_arrays(n, rng, n_distinct=200):
     }
 
 
-def single_device_report(arrays):
-    s = sk.init_state(CFG)
+def single_device_report(arrays, cfg=CFG):
+    s = sk.init_state(cfg)
     s = sk.ingest(s, {k: jnp.asarray(v) for k, v in arrays.items()})
-    _, report = sk.roll_window(s, CFG)
+    _, report = sk.roll_window(s, cfg)
     return report
 
 
-@slow
-@pytest.mark.parametrize("mesh_shape", [(8, 1), (4, 2), (2, 4)])
+@pytest.mark.parametrize("mesh_shape",
+                         [pytest.param((8, 1), marks=slow),
+                          pytest.param((4, 2), marks=slow),
+                          pytest.param((2, 4), marks=slow), (2, 2)])
 def test_sharded_matches_single_device(mesh_shape):
     """Exactness: with a key universe that fits every local table, the merged
     distributed report equals the single-device report bit-for-bit. (With more
@@ -68,13 +73,16 @@ def test_sharded_matches_single_device(mesh_shape):
         pytest.skip("not enough devices")
     rng = np.random.default_rng(42)
     arrays = make_arrays(ndata * 128, rng, n_distinct=24)
+    # "fits every table": a key has 8 candidate slots, so 24 keys need more
+    # than CFG's 32 slots for EVERY one of them to find a slot on one device
+    cfg = CFG._replace(topk=128)
 
-    ref = single_device_report(arrays)
+    ref = single_device_report(arrays, cfg)
 
     mesh = make_mesh(MeshSpec(data=ndata, sketch=nsk))
-    dist = pmerge.init_dist_state(CFG, mesh)
-    ingest_fn = pmerge.make_sharded_ingest_fn(mesh, CFG)
-    merge_fn = pmerge.make_merge_fn(mesh, CFG)
+    dist = pmerge.init_dist_state(cfg, mesh)
+    ingest_fn = pmerge.make_sharded_ingest_fn(mesh, cfg)
+    merge_fn = pmerge.make_merge_fn(mesh, cfg)
     dist = ingest_fn(dist, pmerge.shard_batch(mesh, arrays))
     dist, report = merge_fn(dist)
 
@@ -121,8 +129,9 @@ def test_sharded_matches_single_device(mesh_shape):
 arrays_to_dense = sk.arrays_to_dense
 
 
-@slow
-@pytest.mark.parametrize("mesh_shape", [(8, 1), (4, 2)])
+@pytest.mark.parametrize("mesh_shape",
+                         [pytest.param((8, 1), marks=slow),
+                          pytest.param((4, 2), marks=slow), (2, 2)])
 def test_sharded_dense_matches_dict_transport(mesh_shape):
     """The dense (single-transfer) sharded ingest must produce the same
     distributed state as the six-array dict transport — same ingest math,
@@ -220,8 +229,9 @@ def test_ddos_alarm_travels_through_merge():
     assert bool((report.ddos_z > 6.0).any())
 
 
-@slow
-@pytest.mark.parametrize("mesh_shape", [(8, 1), (4, 2)])
+@pytest.mark.parametrize("mesh_shape",
+                         [pytest.param((8, 1), marks=slow),
+                          pytest.param((4, 2), marks=slow), (2, 2)])
 def test_staging_ring_sharded_dense_token(mesh_shape):
     """The production distributed exporter combination — DenseStagingRing +
     sharded dense ingest with reuse tokens + shard_dense placement — must
@@ -298,8 +308,9 @@ def test_steady_state_ingest_has_no_collectives(mesh_shape):
     assert any(c in hlo_roll for c in ("all-reduce", "all-gather"))
 
 
-@slow
-@pytest.mark.parametrize("mesh_shape", [(8, 1), (4, 2)])
+@pytest.mark.parametrize("mesh_shape",
+                         [pytest.param((8, 1), marks=slow),
+                          pytest.param((4, 2), marks=slow), (2, 2)])
 def test_shard_dense_per_device_equivalent(mesh_shape):
     """Explicit per-device placement (N independent DMAs — the multi-chip
     feed shape) must produce the same global sharded array as the one-put
@@ -322,9 +333,11 @@ def test_shard_dense_per_device_equivalent(mesh_shape):
         np.asarray(x), np.asarray(y)), d1, d2)
 
 
-@slow
 @pytest.mark.parametrize("mesh_shape,lanes",
-                         [((8, 1), 1), ((4, 2), 1), ((4, 2), 2)])
+                         [pytest.param((8, 1), 1, marks=slow),
+                          pytest.param((4, 2), 1, marks=slow),
+                          pytest.param((4, 2), 2, marks=slow),
+                          ((2, 2), 2)])
 def test_sharded_resident_feed_matches_dense(mesh_shape, lanes):
     """The sharded RESIDENT feed (per-data-shard dictionaries + device key
     tables, ~15B/record) is a transport for the same math as the dense
@@ -400,14 +413,25 @@ def test_sharded_resident_feed_matches_dense(mesh_shape, lanes):
     assert got_r == got_d
 
 
+#: a geometry at which every kernel's static gate holds at the LOCAL width
+#: of a two-way sketch axis (2^10): with use_pallas=True the owner-sharded
+#: fold runs the factored Count-Min, the slot walk, the HLL and the signal
+#: kernels (interpreted on the CPU)
+KERNEL_CFG = sk.SketchConfig(cm_width=1 << 11, hll_precision=9, topk=128,
+                             perdst_buckets=128, persrc_buckets=128,
+                             hist_buckets=64, ewma_buckets=128,
+                             use_pallas=True)
+
+
 @pytest.mark.parametrize("mesh_shape,lanes",
-                         [((8, 1), 1), ((4, 2), 1), ((4, 2), 2)])
+                         [((8, 1), 1), ((4, 2), 1), ((4, 2), 2), ((2, 2), 4)])
 def test_sharded_resident_ingest_has_no_collectives(mesh_shape, lanes):
     """The resident transport must not weaken the steady-state invariant:
     table scatter/gather are shard-local, so the compiled sharded resident
     ingest contains NO collectives on either mesh axis — including with
     pack LANES per shard (the per-lane unpack loop + table stack must stay
-    purely local)."""
+    purely local), and on the (2, 2) mesh with the KERNELS on (the forms an
+    owner-sharded fold takes on a TPU)."""
     from netobserv_tpu.datapath import flowpack
 
     ndata, nsk = mesh_shape
@@ -416,9 +440,10 @@ def test_sharded_resident_ingest_has_no_collectives(mesh_shape, lanes):
     mesh = make_mesh(MeshSpec(data=ndata, sketch=nsk))
     bpl = 64 // lanes
     caps = flowpack.default_resident_caps(bpl)
-    fn = pmerge.make_sharded_ingest_resident_fn(mesh, CFG, bpl, caps, 1 << 12,
+    cfg = KERNEL_CFG if mesh_shape == (2, 2) else CFG
+    fn = pmerge.make_sharded_ingest_resident_fn(mesh, cfg, bpl, caps, 1 << 12,
                                                 donate=False, lanes=lanes)
-    dist = pmerge.init_dist_state(CFG, mesh)
+    dist = pmerge.init_dist_state(cfg, mesh)
     tables = pmerge.init_resident_tables(mesh, 1 << 12, lanes=lanes)
     flat = pmerge.shard_dense(mesh, np.zeros(
         ndata * lanes * flowpack.resident_buf_len(bpl, caps), np.uint32))

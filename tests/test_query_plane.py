@@ -170,20 +170,31 @@ def test_routes_dispatch_and_metrics_labels():
     assert 'query_requests_total{result="not_found",route="nope"} 1.0' in text
 
 
-def test_routes_no_snapshot_and_no_tables():
+def test_routes_no_snapshot_and_sharded_planes():
     qr = QueryRoutes(lambda: None, dict)
     for route in ("topk", "frequency", "cardinality", "victims"):
         code, body = qr.handle(f"/query/{route}", {"src": "1.1.1.1",
                                                    "dst": "2.2.2.2"})
         assert code == 503, route
-    # snapshot without CM planes (width-sharded mesh): frequency refuses,
-    # report-backed routes still serve
+    # a width-sharded mesh's snapshot holds [shards, depth, width / shards]
+    # planes: frequency answers from the key's OWNER shard at the local
+    # width, with the bar of that shard's own mass and width
     snap = _snap()
-    snap["cm_bytes"] = snap["cm_pkts"] = None
+    planes = np.stack([np.full((2, 1 << 9), 1.0, np.float32),
+                       np.full((2, 1 << 9), 3.0, np.float32)])
+    snap["cm_bytes"] = snap["cm_pkts"] = planes
     qr = QueryRoutes(lambda: snap, dict)
-    assert qr.handle("/query/topk", {})[0] == 200
-    assert qr.handle("/query/frequency",
-                     {"src": "1.1.1.1", "dst": "2.2.2.2"})[0] == 503
+    code, body = qr.handle("/query/topk", {})
+    # the widest shard's bound covers every rendered heavy hitter
+    assert code == 200
+    assert body["overestimate_bound_bytes"] == pytest.approx(3 * np.e)
+    code, body = qr.handle("/query/frequency",
+                           {"src": "1.1.1.1", "dst": "2.2.2.2"})
+    assert code == 200 and body["width"] == 1 << 9
+    assert body["shard"] in (0, 1)
+    mass = (1.0, 3.0)[body["shard"]]
+    assert body["est_bytes"] == mass
+    assert body["overestimate_bound_bytes"] == pytest.approx(mass * np.e)
 
 
 def test_routes_survive_raising_status():

@@ -210,6 +210,53 @@ def test_sharded_resident_ladder_entry_lowers_named_and_scoped(k):
     assert text.count("tpu_custom_call") == MOSAIC_CALLS
 
 
+@pytest.mark.parametrize("cfg,kernels,form", [
+    (CFG, {"countmin_update_two", "hll_update", "topk_slot_walk",
+           "signal_update"}, "factored"),
+    (WIDE, {"hll_update", "topk_slot_walk", "signal_update"}, "scatter")],
+    ids=["default", "wide"])
+def test_width_sharded_ladder_entry_lowers_with_the_kernels(cfg, kernels,
+                                                            form):
+    """Mesh data=2 x sketch=2 (collector-wide-mesh2x2's): the owner-sharded
+    fold is the whole-width fold with ownership as a row mask — the same
+    kernels, the Count-Min form `fold_forms` picks at the LOCAL width (2^15:
+    factored; 2^21: the scatter), `owner_mask` a scope of its own."""
+    mesh = make_mesh(MeshSpec(data=2, sketch=2), devices=jax.devices()[:4])
+    lanes, k = 4, 4
+    bpl = BATCH // (2 * lanes)
+    caps = flowpack.default_resident_caps(bpl)
+    name = f"sharded_ingest_resident_x{k}"
+    fn = pmerge.make_sharded_ingest_resident_fn(
+        mesh, cfg, bpl, caps, 1 << 18, lanes=k * lanes, watch_name=name)
+    dist = jax.eval_shape(lambda: pmerge.init_dist_state(cfg, mesh))
+    tables = jax.ShapeDtypeStruct((2 * 4 * lanes << 18, sk.KEY_WORDS),
+                                  jnp.uint32)
+    flat = jax.ShapeDtypeStruct(
+        (2 * k * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
+    text = lowered(fn, dist, tables, flat, debug_info=True)
+    assert f"module @jit_{name} " in text
+    assert scopes_of(text) >= FOLD_SCOPES | {"resident_decode", "owner_mask"}
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == kernels
+    # inside the shard_map body an op's name starts at the scope
+    assert f'loc("countmin/{form}/' in text
+    assert "stablehlo.all_" not in text and "collective_permute" not in text
+
+
+def test_width_sharded_merge_gathers_the_planes_under_their_scope():
+    """The table snapshot of a width-sharded roll: each merged Count-Min
+    plane leaves as [sketch, depth, width / sketch], gathered over the
+    sketch axis under `merge_tables_gather`."""
+    mesh = make_mesh(MeshSpec(data=2, sketch=2), devices=jax.devices()[:4])
+    fn = pmerge.make_merge_fn(mesh, CFG, with_tables=True)
+    dist = jax.eval_shape(lambda: pmerge.init_dist_state(CFG, mesh))
+    text = lowered(fn, dist, debug_info=True)
+    assert {"merge_allreduce", "merge_topk_gather",
+            "merge_tables_gather"} <= scopes_of(text)
+    _, _, tables = jax.eval_shape(fn, dist)
+    assert tables["cm_bytes"].shape == (2, CFG.cm_depth, CFG.cm_width // 2)
+    assert tables["cm_pkts"].shape == tables["cm_bytes"].shape
+
+
 def test_sharded_merge_lowers_named_with_its_collectives_scoped():
     mesh = make_mesh(MeshSpec(data=4), devices=jax.devices()[:4])
     fn = pmerge.make_merge_fn(mesh, CFG, with_tables=True)
@@ -260,15 +307,20 @@ def v5e():
     jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-def compiled_entry(fn, *args) -> tuple:
-    """(header line, [instruction lines]) of the entry computation of
-    `fn(*args)` compiled for the TPU the arguments' shardings describe."""
-    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        text = fn.trace(*args).lower(
-            lowering_platforms=("tpu",)).compile().as_text()
+def entry_of(text: str) -> tuple:
+    """(header line, [instruction lines]) of the entry computation of a
+    compiled module's HLO text."""
     entry = text[text.index("\nENTRY "):]
     return (text.splitlines()[0],
             entry[:entry.index("\n}")].splitlines()[2:])
+
+
+def compiled_entry(fn, *args) -> tuple:
+    """`entry_of` `fn(*args)` compiled for the TPU the arguments' shardings
+    describe."""
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return entry_of(fn.trace(*args).lower(
+            lowering_platforms=("tpu",)).compile().as_text())
 
 
 def assert_only_the_scatter_is_table_sized(header, entry, n_elements):
@@ -311,31 +363,59 @@ def test_x1_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
         header, entry, sk.KEY_WORDS * 32 * (1 << 18))
 
 
-def test_per_shard_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
-    """`sharded_ingest_resident_x1` on mesh data=4: each chip's program
-    takes its rows of the sharded table as the same 2-D array."""
+def compiled_per_shard_x1(v5e, ndata: int, nsk: int, lanes: int,
+                          slots: int = 1 << 18) -> str:
+    """`sharded_ingest_resident_x1` of mesh data=`ndata` x sketch=`nsk` at
+    the default geometry, compiled for the described chips: the HLO text of
+    one chip's program."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.array(v5e.devices).reshape(4, 1), ("data", "sketch"))
-    lanes, slots = 2, 1 << 18
-    bpl = BATCH // (4 * lanes)
+    mesh = Mesh(np.array(v5e.devices).reshape(ndata, nsk),
+                ("data", "sketch"))
+    bpl = BATCH // (ndata * lanes)
     caps = flowpack.default_resident_caps(bpl)
     fn = pmerge.make_sharded_ingest_resident_fn(
         mesh, CFG, bpl, caps, slots, lanes=lanes,
         watch_name="sharded_ingest_resident_x1")
-    cpu_mesh = make_mesh(MeshSpec(data=4), devices=jax.devices()[:4])
+    cpu_mesh = make_mesh(MeshSpec(data=ndata, sketch=nsk),
+                         devices=jax.devices()[:4])
     shapes = jax.eval_shape(lambda: (
         pmerge.init_dist_state(CFG, cpu_mesh),
         pmerge.init_resident_tables(cpu_mesh, slots, lanes=4 * lanes)))
     flat = jax.ShapeDtypeStruct(
-        (4 * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
+        (ndata * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
     dist, tables, flat = jax.tree.map(
         lambda x, spec: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
         (*shapes, flat),
         (pmerge._state_specs(sk.init_state(CFG)), P("data"), P("data")))
-    header, entry = compiled_entry(fn, dist, tables, flat)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return fn.trace(dist, tables, flat).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+
+
+def test_per_shard_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
+    """`sharded_ingest_resident_x1` on mesh data=4: each chip's program
+    takes its rows of the sharded table as the same 2-D array."""
+    lanes, slots = 2, 1 << 18
+    text = compiled_per_shard_x1(v5e, 4, 1, lanes, slots)
     assert_only_the_scatter_is_table_sized(
-        header, entry, sk.KEY_WORDS * 4 * lanes * slots)
+        *entry_of(text), sk.KEY_WORDS * 4 * lanes * slots)
+
+
+def test_width_sharded_entry_compiles_with_kernels_and_no_collective(v5e):
+    """The same entry on mesh data=2 x sketch=2 (4 lanes a shard): the
+    chip's compiler takes the five kernels inside the shard_map, puts in no
+    collective, and relays no table. (At collector-wide-mesh2x2's sizes —
+    2^21 a chip, 2^20 slots — it compiles with four and XLA's scatter: by
+    hand, PERF.md section 6, PR 34; 25 s, too long for this tier.)"""
+    lanes, slots = 4, 1 << 18
+    text = compiled_per_shard_x1(v5e, 2, 2, lanes, slots)
+    assert text.count('custom_call_target="tpu_custom_call"') == MOSAIC_CALLS
+    for coll in ("all-reduce", "all-gather", "collective-permute",
+                 "reduce-scatter", "all-to-all"):
+        assert coll not in text, coll
+    assert_only_the_scatter_is_table_sized(
+        *entry_of(text), sk.KEY_WORDS * 4 * lanes * slots)
 
 
 # --- compile cache placement (utils/platform.enable_compile_cache) ---------
