@@ -92,14 +92,37 @@ class ResidentCaps:
 
 
 def default_resident_caps(batch_size: int) -> ResidentCaps:
-    """Production sizing (byte budget in docs/tpu_sketch.md): DNS-latency
-    and drop rows are minorities of live traffic; new keys per batch are a
-    trickle once the flow table is warm; the spill lane only carries rows
-    the hot row cannot represent exactly."""
+    """The NARROW lane family — what every ladder entry ships with unless
+    the ring has seen a key flood (byte budget in docs/tpu_sketch.md):
+    DNS-latency and drop rows are minorities of live traffic; new keys are
+    a trickle on a warm flow table under STATIONARY traffic (Zipf: one row
+    in a hundred rides a second chunk); the spill lane only carries rows
+    the hot row cannot represent exactly. A region stops packing at `nk`
+    new keys + `spill` rows, so under a spoofed-source flood, a drifting hot
+    set or a dictionary epoch roll (miss rate near 0.3) it takes 300 of its
+    1,024 rows and every record is offered three times: that traffic
+    packs through `wide_resident_caps` instead (the ring chooses per
+    chunk, `sketch.staging.ShardedResidentStagingRing`)."""
     return ResidentCaps(dns=max(batch_size // 16, 64),
                         drop=max(batch_size // 16, 64),
                         nk=max(batch_size // 32, 64),
                         spill=max(batch_size // 64, 32))
+
+
+def wide_resident_caps(batch_size: int) -> ResidentCaps:
+    """The WIDE lane family: the narrow caps with a new-key lane three
+    eighths of the region's hot rows, so a region at a flood's miss rate
+    (0.3, up to 0.4) takes all its rows in one offer. Only `nk` differs: a
+    new-key row costs its 44 bytes of transfer and its share of the one
+    combined table scatter, whereas a spill row is a ROW OF THE FOLD (every
+    kernel walks `batch + spill` rows a region), so the spill lane stays as
+    it is. Settled on the chip (PERF.md section 6, PR 35): a quarter leaves
+    a tenth of a flood chunk's rows to sub-batch narrow chunks, for the
+    same rate end to end and more of the export thread's time."""
+    narrow = default_resident_caps(batch_size)
+    return ResidentCaps(dns=narrow.dns, drop=narrow.drop,
+                        nk=max(batch_size * 3 // 8, narrow.nk),
+                        spill=narrow.spill)
 
 
 def resident_buf_len(batch_size: int, caps: ResidentCaps) -> int:

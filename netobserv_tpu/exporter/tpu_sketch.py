@@ -640,22 +640,29 @@ class TpuSketchExporter(Exporter):
                     bps, max(1, self._lane_threads // spec.data))
                 bpl = bps // lanes
                 caps = flowpack.default_resident_caps(bpl)
+                wide_caps = flowpack.wide_resident_caps(bpl)
                 ladder = self._superbatch
-                ingests = {
-                    k: pmerge.make_sharded_ingest_resident_fn(
+
+                def sharded_entry(k, caps, family=""):
+                    return pmerge.make_sharded_ingest_resident_fn(
                         self._mesh, self._cfg, bpl, caps, resident_slots,
                         lanes=k * lanes,
-                        watch_name=f"sharded_ingest_resident_x{k}")
-                    for k in ladder}
+                        watch_name=f"sharded_ingest_resident{family}_x{k}")
                 self._ring = staging.ShardedResidentStagingRing(
-                    self._batch_size, spec.data, ingests,
+                    self._batch_size, spec.data,
+                    {k: sharded_entry(k, caps) for k in ladder},
                     key_tables=functools.partial(
                         pmerge.init_resident_tables, self._mesh,
                         resident_slots, lanes=max(ladder) * lanes),
                     put=dense_put,
                     caps=caps, slot_cap=resident_slots, metrics=metrics,
                     pack_threads=pack_threads, lanes=lanes, ladder=ladder,
-                    lazy_ladder=True)
+                    lazy_ladder=True,
+                    # a key flood saturates, so its chunks are the top
+                    # entry's: one wide program, not one a ladder size
+                    wide_ingest={max(ladder): sharded_entry(
+                        max(ladder), wide_caps, "_wide")},
+                    wide_caps=wide_caps)
             else:
                 if feed == "compact":
                     log.info("SKETCH_FEED=compact has no sharded form "
@@ -906,7 +913,10 @@ class TpuSketchExporter(Exporter):
         The exporter's ring is built `lazy_ladder`: entries beyond 1x only
         become SELECTABLE here, as each compile lands (`ring.mark_warm`) —
         an unwarmed exporter folds 1x forever rather than ever paying a
-        ladder compile inside a live `export_evicted`.
+        ladder compile inside a live `export_evicted`. The wide lane
+        family's entries (`ring.programs()`) compile after the narrow
+        ladder through the same spare state and tables, and a flood folds
+        narrow until they have.
 
         MULTI-PROCESS meshes warm synchronously regardless of `block`:
         every process must select the same ladder k for the same fold (the
@@ -929,15 +939,16 @@ class TpuSketchExporter(Exporter):
             # regions define no key and hold no row) — an array an entry
             # would be 2.15 GB each at 2^20 slots, beside the ring's own
             state = tables = None
-            for k in ring.ladder:
+            for k, wide in ring.programs():
                 if self._closed.is_set():
                     return  # shutting down: stop compiling, exit promptly
-                if k in ring._available:
+                if ring.is_warm(k, wide):
                     # already selectable (k=1, or a prior warm): live folds
                     # may be tracing it RIGHT NOW — a concurrent duplicate
                     # first-trace here would fire a spurious post-warmup
                     # retrace alarm, for zero benefit
                     continue
+                ingest, _, region_words = ring.program(k, wide)
                 try:
                     if tables is None:
                         state = (
@@ -946,11 +957,11 @@ class TpuSketchExporter(Exporter):
                             else self._sk.init_state(self._cfg))
                         tables = ring.make_tables()
                     nr = ring.n_shards * k * ring.lanes
-                    flat = np.zeros(nr * ring._region_words, np.uint32)
-                    state, tables, token = ring._ingests[k](
+                    flat = np.zeros(nr * region_words, np.uint32)
+                    state, tables, token = ingest(
                         state, tables, ring._put(flat))
                     jax.block_until_ready(token)
-                    ring.mark_warm(k)
+                    ring.mark_warm(k, wide=wide)
                 except Exception as exc:
                     state = tables = None  # donated to the call that failed
                     if multiprocess:
@@ -964,7 +975,7 @@ class TpuSketchExporter(Exporter):
                     # retry repairs: say so at error level, by name
                     log.error("superbatch ladder entry %r failed to warm "
                               "and stays disabled: %s",
-                              getattr(ring._ingests[k], "name", k), exc)
+                              getattr(ingest, "name", k), exc)
 
         if block:
             _warm()
@@ -1668,24 +1679,30 @@ class TpuSketchExporter(Exporter):
             ladder = self._superbatch
             bpl = self._batch_size // lanes
             caps = flowpack.default_resident_caps(bpl)
+            wide_caps = flowpack.wide_resident_caps(bpl)
+
             # one fixed-shape jitted entry PER ladder size, every one under
             # its own name and retrace watch — a post-warmup compile of any
             # ladder shape is a live alarm (sketch_retraces_total{fn=..._xk})
             # and a device capture reads jit_ingest_resident_lanes_x<k>
-            ingests = {
-                k: sk.make_ingest_resident_lanes_fn(
+            def entry(k, caps, family=""):
+                return sk.make_ingest_resident_lanes_fn(
                     bpl, caps, k * lanes, resident_slots,
                     use_pallas=self._cfg.use_pallas,
-                    name=f"ingest_resident_lanes_x{k}",
+                    name=f"ingest_resident_lanes{family}_x{k}",
                     tiered=self._tier_form)
-                for k in ladder}
             return staging.ShardedResidentStagingRing(
-                self._batch_size, 1, ingests,
+                self._batch_size, 1, {k: entry(k, caps) for k in ladder},
                 key_tables=functools.partial(
                     sk.init_key_tables, max(ladder) * lanes, resident_slots),
                 put=jax.device_put, caps=caps, slot_cap=resident_slots,
                 metrics=metrics, pack_threads=pack_threads, lanes=lanes,
-                ladder=ladder, lazy_ladder=True)
+                ladder=ladder, lazy_ladder=True,
+                # the wide lane family of the TOP entry (a key flood
+                # saturates, so its chunks are top-entry chunks)
+                wide_ingest={max(ladder): entry(max(ladder), wide_caps,
+                                                "_wide")},
+                wide_caps=wide_caps)
         if feed == "compact":
             spill_cap = staging.default_spill_cap(self._batch_size)
             return staging.DenseStagingRing(
@@ -1922,7 +1939,9 @@ class TpuSketchExporter(Exporter):
                 "ladder": list(ring.ladder),
                 "warm": ring.warm_entries(),
                 "folds": {str(k): n for k, n
-                          in sorted(ring.superbatch_folds.items())}}
+                          in sorted(ring.superbatch_folds.items())},
+                # of those folds, the chunks that took the wide lane family
+                "wide_folds": ring.wide_folds}
         if getattr(self, "_tiered_degraded", False):
             # mirror of the tiered_degraded supervisor condition: why
             # resident memory is wide despite SKETCH_TIERED being set

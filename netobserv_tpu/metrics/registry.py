@@ -172,7 +172,21 @@ class Metrics:
             p + "sketch_resident_carried_rows_total",
             "Rows a resident chunk could not take because a region's side "
             "lane filled, kept in the pending buffer to ride the next chunk "
-            "instead of a continuation chunk of their own",
+            "instead of a continuation chunk of their own. A sustained rate "
+            "near the record rate means every record is offered twice or "
+            "more: under a key flood the ring answers with the wide lane "
+            "family (sketch_resident_wide_folds_total) and this falls; "
+            "rows left behind a full SPILL lane are not helped by it",
+            registry=self.registry)
+        self.sketch_resident_wide_folds_total = Counter(
+            p + "sketch_resident_wide_folds_total",
+            "Resident chunks dispatched through a WIDE ladder entry (a "
+            "new-key lane three eighths of a region's rows where the narrow "
+            "one holds a sixteenth). The ring goes wide when the regions "
+            "whose new-key lane filled left a quarter of a chunk's rows, and "
+            "back when a chunk's new keys fit the narrow lanes: a key flood, "
+            "a drifting hot set or a dictionary epoch roll. Also counted in "
+            "sketch_superbatch_folds_total{k}",
             registry=self.registry)
         self.sketch_resident_dict_epochs_total = Counter(
             p + "sketch_resident_dict_epochs_total",

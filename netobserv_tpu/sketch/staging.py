@@ -318,15 +318,18 @@ class _SlotRing:
         #: fold chunks begun by this ring (`chunk=<n>` on their stages)
         self.chunks = 0
 
-    def _chunk(self, trace, k: int = 1, cont: bool = False):
+    def _chunk(self, trace, k: int = 1, cont: bool = False,
+               wide: bool = False):
         """The stage handle of the fold chunk about to begin: `trace` with
-        `chunk=<n>` (this ring's sequence number), `k=<ladder entry>` and
+        `chunk=<n>` (this ring's sequence number), `k=<ladder entry>`,
         `cont=<0|1>` (a continuation of the rows the chunk before could not
-        take) bound beside whatever the caller bound (`evictions=<a>-<b>`)
-        — the ids its staging_wait / pack / put / ingest_dispatch stages
-        carry in a profiler capture."""
+        take) and `wide=<0|1>` (the resident ring's lane family) bound
+        beside whatever the caller bound (`evictions=<a>-<b>`) — the ids
+        its staging_wait / pack / put / ingest_dispatch stages carry in a
+        profiler capture."""
         self.chunks += 1
-        return trace.bind(chunk=self.chunks, k=k, cont=int(cont))
+        return trace.bind(chunk=self.chunks, k=k, cont=int(cont),
+                          wide=int(wide))
 
     @contextlib.contextmanager
     def _pack_stage(self, chunk, stage: str = "resident_pack"):
@@ -583,22 +586,38 @@ class ShardedResidentStagingRing(_SlotRing):
     reaches a region's dictionary in stream order, so the schedule is a
     pure function of the row stream (the multi-process rule above holds).
 
+    Lane FAMILIES (`wide_ingest`): a ladder entry may have a second
+    program whose regions differ only in the capacity of the new-key lane
+    (`flowpack.wide_resident_caps`). The two share the dictionaries and the
+    key tables — a slot defined through either lane is the same slot — and
+    the ring picks the family of the next such chunk from what the packer
+    saw in the last one (`_next_family`): wide once the regions whose
+    new-key lane filled left a quarter of the rows on offer, narrow again
+    once a wide chunk's new keys come no faster than the narrow lanes hold
+    them. That too is a pure function of the row stream. A key flood (a
+    fifth of the records on never-seen keys) then folds in one offer a
+    record where the narrow lane takes three; stationary traffic never
+    leaves narrow, whose table scatter is a sixth the size.
+
     `ingest`: `{k: (dist_state, key_tables, flat) -> (dist_state,
     key_tables, token)}` for every ladder entry (a bare callable means
-    `{1: fn}`). `key_tables` must carry `superbatch_max * lanes` lanes of
-    `slot_cap` rows per shard (`state.init_key_tables`; the ring only hands
-    the array on), and every entry must have been built with this ring's
-    `slot_cap`. A zero-argument callable in its place makes the array at
-    the first dispatch (and a spare on request, `make_tables`): the
-    exporter's ladder warm-up folds through a spare, and at 2^20 slots a
-    table is 2.15 GB of a 16 GB chip — the two need not be alive together.
+    `{1: fn}`); `wide_ingest`: the same for the entries that have a wide
+    program, built with `wide_caps`. `key_tables` must carry
+    `superbatch_max * lanes` lanes of `slot_cap` rows per shard
+    (`state.init_key_tables`; the ring only hands the array on), and every
+    entry must have been built with this ring's `slot_cap`. A zero-argument
+    callable in its place makes the array at the first dispatch (and a
+    spare on request, `make_tables`): the exporter's ladder warm-up folds
+    through a spare, and at 2^20 slots a table is 2.15 GB of a 16 GB chip —
+    the two need not be alive together.
     `pack_threads > 1` packs the regions concurrently."""
 
     def __init__(self, batch_size: int, n_shards: int, ingest,
                  key_tables, put: Callable,
                  caps=None, slot_cap: int = 1 << 18, n_slots: int = 4,
                  metrics=None, pack_threads: int = 1, lanes: int = 1,
-                 ladder: tuple = (1,), lazy_ladder: bool = False):
+                 ladder: tuple = (1,), lazy_ladder: bool = False,
+                 wide_ingest: Optional[dict] = None, wide_caps=None):
         self.ladder = tuple(sorted({int(k) for k in ladder}))
         if not self.ladder or self.ladder[0] != 1:
             raise ValueError("superbatch ladder must include 1")
@@ -610,6 +629,14 @@ class ShardedResidentStagingRing(_SlotRing):
         # Eager (default) trusts the caller to warm by folding (offline
         # tools, tests).
         self._available = {1} if lazy_ladder else set(self.ladder)
+        self._wide_ingests = dict(wide_ingest or {})
+        if set(self._wide_ingests) - set(self.ladder):
+            raise ValueError("a wide entry needs its narrow ladder entry")
+        #: wide entries a fold may select (compiled: `mark_warm(wide=True)`)
+        self._wide_available = (set() if lazy_ladder
+                                else set(self._wide_ingests))
+        #: the family the next chunk of an entry with a wide program takes
+        self._wide_next = False
         n_regions = n_shards * lanes
         if batch_size % n_regions:
             raise ValueError(
@@ -643,11 +670,21 @@ class ShardedResidentStagingRing(_SlotRing):
         #: dispatch counts by superbatch size (mirrors
         #: sketch_superbatch_folds_total{k})
         self.superbatch_folds: dict[int, int] = {}
+        #: chunks dispatched through a wide entry (mirrors
+        #: sketch_resident_wide_folds_total)
+        self.wide_folds = 0
         self._region_words = flowpack.resident_buf_len(self.batch_per_region,
                                                        self.caps)
+        self.wide_caps = wide_caps or flowpack.wide_resident_caps(
+            self.batch_per_region)
+        self._wide_region_words = flowpack.resident_buf_len(
+            self.batch_per_region, self.wide_caps)
+        slot_words = max(
+            [self.superbatch_max * self._region_words]
+            + [k * self._wide_region_words for k in self._wide_ingests])
         self._init_slots(
-            [np.empty(self.superbatch_max * n_regions * self._region_words,
-                      np.uint32) for _ in range(n_slots)], metrics)
+            [np.empty(n_regions * slot_words, np.uint32)
+             for _ in range(n_slots)], metrics)
 
     @property
     def key_tables(self):
@@ -661,14 +698,55 @@ class ShardedResidentStagingRing(_SlotRing):
     def key_tables(self, tables) -> None:
         self._key_tables = tables
 
-    def mark_warm(self, *ks: int) -> None:
+    def programs(self) -> list[tuple[int, bool]]:
+        """Every compiled program this ring can dispatch, as `(k, wide)` in
+        warm-up order: the narrow ladder, then the wide entries."""
+        return ([(k, False) for k in self.ladder]
+                + [(k, True) for k in sorted(self._wide_ingests)])
+
+    def program(self, k: int, wide: bool = False):
+        """`(ingest fn, caps, words of one region)` of ladder entry `k` in
+        the narrow or the wide lane family."""
+        if wide:
+            return (self._wide_ingests[k], self.wide_caps,
+                    self._wide_region_words)
+        return self._ingests[k], self.caps, self._region_words
+
+    def is_warm(self, k: int, wide: bool = False) -> bool:
+        return k in (self._wide_available if wide else self._available)
+
+    def mark_warm(self, *ks: int, wide: bool = False) -> None:
         """Make ladder entries selectable (call after compiling them — the
         exporter's `warm_superbatch_ladder`)."""
-        self._available.update(int(k) for k in ks)
+        (self._wide_available if wide else self._available).update(
+            int(k) for k in ks)
 
     def warm_entries(self) -> list[int]:
-        """The ladder entries a fold may select right now."""
-        return sorted(self._available)
+        """The ladder entries whose every program is compiled (the narrow
+        one and, where the entry has one, the wide): `== list(ladder)` says
+        no fold can meet a compile any more. A narrow entry is selectable
+        from its own compile on, whatever its wide twin does."""
+        return sorted(k for k in self._available
+                      if k not in self._wide_ingests
+                      or k in self._wide_available)
+
+    def _next_family(self, wide: bool, offered: int, left_on_nk: int,
+                     new_keys: int) -> bool:
+        """The lane family of the NEXT chunk, from what the packer saw in
+        this one: of the `offered` rows, `left_on_nk` were left by regions
+        whose new-key lane filled (a region that stopped on its spill lane
+        alone gains nothing from a wider new-key lane), and the regions
+        defined `new_keys` keys between them. Narrow -> wide where a wider
+        lane would have taken a quarter of the chunk more; wide -> narrow
+        where new keys come no faster than the narrow lanes hold them (a
+        sixteenth of the rows at 1,024 rows a region). A narrow region
+        takes all its rows up to a miss rate of a tenth and leaves a
+        quarter of them from an eighth, so between the two the family
+        stays, and stationary traffic (one row in a hundred left, one in
+        ten while the dictionaries learn a wide universe) never flips it."""
+        if wide:
+            return new_keys * self.batch_per_region > self.caps.nk * offered
+        return 4 * left_on_nk >= offered > 0
 
     def fold(self, state, events, extra=None, dns=None, drops=None,
              xlat=None, quic=None, trace=None, carry: bool = False):
@@ -717,13 +795,14 @@ class ShardedResidentStagingRing(_SlotRing):
     def _fold_chunk(self, state, events, feats, k: int, trace, carry: bool):
         """Pack and dispatch ONE k-superbatch chunk (<= k * batch_size rows)
         through the k ladder entry, again for what its regions could not
-        take until none is left — or, with `carry`, once. Returns the state
-        and the row ranges left (empty without `carry`)."""
+        take until none is left — or, with `carry`, once. Each dispatch
+        takes the lane family the one before it called for (`_next_family`)
+        where entry k has a compiled wide program. Returns the state and
+        the row ranges left (empty without `carry`)."""
         n = len(events)
         nr = self.n_shards * k * self.lanes
         kl = k * self.lanes
         kmax_l = self.superbatch_max * self.lanes
-        ship_words = nr * self._region_words
         bounds = [n * i // nr for i in range(nr + 1)]
         shard_ev = [events[bounds[i]:bounds[i + 1]] for i in range(nr)]
         shard_feats = [
@@ -733,7 +812,11 @@ class ShardedResidentStagingRing(_SlotRing):
         starts = [0] * nr
         first = True
         while any(starts[i] < len(shard_ev[i]) for i in range(nr)):
-            chunk = self._chunk(trace, k, not first)
+            adaptive = k in self._wide_available
+            wide = adaptive and self._wide_next
+            ingest, caps, region_words = self.program(k, wide)
+            ship_words = nr * region_words
+            chunk = self._chunk(trace, k, not first, wide)
             try:
                 slot = self._wait_slot(chunk)
             except StagingWedged as exc:
@@ -742,24 +825,25 @@ class ShardedResidentStagingRing(_SlotRing):
                 exc.state = state
                 raise
             buf = self._bufs[slot]
+            offered = sum(len(shard_ev[i]) - starts[i] for i in range(nr))
 
             def pack_shard(i):
                 # touches only region-local state (its dict, its buffer
                 # region, starts[i]); returns the diagnostic counters so
                 # threaded packs don't race on shared attributes
-                region = buf[i * self._region_words:
-                             (i + 1) * self._region_words]
+                region = buf[i * region_words:(i + 1) * region_words]
                 if starts[i] >= len(shard_ev[i]):
                     # exhausted region in a continuation chunk: mask it
                     # empty (validity words only — 1/3 of a full memset),
                     # and don't roll its dictionary epoch for rows it
                     # isn't packing
                     flowpack.zero_resident_region(
-                        region, self.batch_per_region, self.caps)
-                    return 0, 0
+                        region, self.batch_per_region, caps)
+                    return 0, 0, 0
                 # region i of a k-chunk is (shard, ladder-position j) —
-                # dict j of that shard, whatever k the chunk uses, so the
-                # dictionary always matches device table row j
+                # dict j of that shard, whatever k and lane family the
+                # chunk uses, so the dictionary always matches device table
+                # row j
                 kd = self.kdicts[(i // kl) * kmax_l + (i % kl)]
                 resets = 0
                 if kd.count() >= self.slot_cap:
@@ -767,12 +851,12 @@ class ShardedResidentStagingRing(_SlotRing):
                     resets = 1
                 _, consumed = flowpack.pack_resident(
                     shard_ev[i], batch_size=self.batch_per_region,
-                    kdict=kd, caps=self.caps, start=starts[i],
+                    kdict=kd, caps=caps, start=starts[i],
                     out=region, **shard_feats[i])
                 if consumed == 0 and starts[i] < len(shard_ev[i]):
                     raise RuntimeError("resident pack made no progress")
                 starts[i] += consumed
-                return int(region[2]), resets
+                return int(region[2]), resets, int(region[1])
 
             with self._pack_stage(chunk):
                 if self.pack_threads > 1 and nr > 1:
@@ -785,9 +869,16 @@ class ShardedResidentStagingRing(_SlotRing):
                     outs = [pack_shard(i) for i in range(nr)]
             chunk_spills = sum(o[0] for o in outs)
             chunk_resets = sum(o[1] for o in outs)
+            if adaptive:
+                self._wide_next = self._next_family(
+                    wide, offered,
+                    sum(len(shard_ev[i]) - starts[i] for i in range(nr)
+                        if outs[i][2] >= caps.nk),
+                    sum(o[2] for o in outs))
             self.spill_rows += chunk_spills
             self.dict_resets += chunk_resets
             self.superbatch_folds[k] = self.superbatch_folds.get(k, 0) + 1
+            self.wide_folds += wide
             if self._metrics is not None:
                 if chunk_spills:
                     self._metrics.sketch_resident_spill_rows_total.inc(
@@ -799,6 +890,8 @@ class ShardedResidentStagingRing(_SlotRing):
                     self._metrics.sketch_resident_continuations_total.inc()
                 self._metrics.sketch_superbatch_folds_total.labels(
                     str(k)).inc()
+                if wide:
+                    self._metrics.sketch_resident_wide_folds_total.inc()
             if not first:
                 self.continuations += 1
             first = False
@@ -807,7 +900,7 @@ class ShardedResidentStagingRing(_SlotRing):
             with chunk.stage("put"):
                 dev = self._put(buf[:ship_words])
             with chunk.stage("ingest_dispatch"):
-                state, self.key_tables, token = self._ingests[k](
+                state, self.key_tables, token = ingest(
                     state, self.key_tables, dev)
             self._advance(slot, token)
             if carry:

@@ -549,3 +549,18 @@ class TestCompactDropSpill:
         assert float(s_comp.total_drop_bytes) == 700.0 * 50 * len(drops[::5])
         assert float(s_comp.quic_records) == 1.0
         assert float(s_comp.nat_records) == 1.0
+
+
+@pytest.mark.parametrize("batch", [256, 1024, 8192])
+def test_wide_resident_caps_differ_from_the_narrow_in_nk_alone(batch):
+    """The wide lane family of the resident feed: a new-key lane three
+    eighths of the region's rows, every other lane the narrow family's (a
+    spill row is a row of the fold; a new-key row 44 bytes of transfer)."""
+    narrow = flowpack.default_resident_caps(batch)
+    wide = flowpack.wide_resident_caps(batch)
+    assert (wide.dns, wide.drop, wide.spill) == (
+        narrow.dns, narrow.drop, narrow.spill)
+    assert wide.nk == max(batch * 3 // 8, narrow.nk) >= narrow.nk
+    assert (flowpack.resident_buf_len(batch, wide)
+            - flowpack.resident_buf_len(batch, narrow)
+            == (wide.nk - narrow.nk) * flowpack.NK_WORDS)

@@ -62,12 +62,37 @@ def make_feed(n_batches=4, n_distinct=200, seed=5, v6_every=0,
     return out
 
 
-def test_native_matches_python_twin():
-    caps = flowpack.default_resident_caps(B)
+#: the two lane families a ring packs with (`wide` differs in `nk` alone),
+#: at B rows a region
+CAPS_FAMILIES = {"narrow": flowpack.default_resident_caps,
+                 "wide": flowpack.wide_resident_caps}
+
+
+def flood_feed(n_batches=4, seed=9, new_keys=120):
+    """`make_feed` with `new_keys` keys a batch that no batch before it
+    held (one 5-tuple stamped with a counter), on its leading rows: more
+    than a narrow region takes in one offer (64 new keys + 32 spill rows),
+    fewer than the wide lane holds (192)."""
+    feed = make_feed(n_batches=n_batches, n_distinct=4000, seed=seed)
+    for i, (events, _) in enumerate(feed):
+        assert len(events) >= new_keys
+        events["key"] = events["key"][0]
+        events["key"]["src_port"] = (1000 * i
+                                     + np.arange(len(events)) % new_keys)
+    return feed
+
+
+@pytest.mark.parametrize("family", sorted(CAPS_FAMILIES))
+@pytest.mark.parametrize("feed", ["steady", "flood"])
+def test_native_matches_python_twin(family, feed):
+    caps = CAPS_FAMILIES[family](B)
     kd_n = flowpack.KeyDict(1 << 12, use_native=True)
     kd_p = flowpack.KeyDict(1 << 12, use_native=False)
     assert kd_n.native and not kd_p.native
-    for events, feats in make_feed(n_batches=5, v6_every=17):
+    chunks = 0
+    batches = (make_feed(n_batches=5, v6_every=17) if feed == "steady"
+               else flood_feed())
+    for events, feats in batches:
         start = 0
         while start < len(events):
             bn, cn = flowpack.pack_resident(events, B, kd_n, caps,
@@ -78,6 +103,11 @@ def test_native_matches_python_twin():
             assert np.array_equal(bn, bp)
             assert kd_n.count() == kd_p.count()
             start += cn
+            chunks += 1
+    if feed == "flood":
+        # the narrow region stops at 64 new keys + 32 spill rows and needs
+        # a second offer, the wide one takes the batch
+        assert (chunks > len(batches)) == (family == "narrow")
     kd_n.close()
 
 
@@ -170,9 +200,18 @@ def _assert_exact_signals_match(s_r, s_d):
 
 
 @needs_jax
-def test_resident_ring_matches_dense_ingest():
-    s_r, s_d, ring = _fold_both_ways(make_feed(n_batches=6, v6_every=29))
+@pytest.mark.parametrize("family", sorted(CAPS_FAMILIES))
+@pytest.mark.parametrize("feed", ["steady", "flood"])
+def test_resident_ring_matches_dense_ingest(family, feed):
+    """The device unpack of either lane family against the dense reference,
+    on steady traffic and on a flood (continuation chunks in the narrow
+    family, none in the wide)."""
+    s_r, s_d, ring = _fold_both_ways(
+        make_feed(n_batches=6, v6_every=29) if feed == "steady"
+        else flood_feed(), caps=CAPS_FAMILIES[family](B))
     assert ring.dict_resets == 0
+    if feed == "flood":
+        assert (ring.continuations > 0) == (family == "narrow")
     _assert_exact_signals_match(s_r, s_d)
     # rtt/dns ride range codes: total mass identical, values shift at most
     # one log bucket (code error 1/256 < the ~1.6% bucket width)

@@ -237,12 +237,15 @@ def test_zero_retraces_across_ladder():
     # /query/status says which entries a fold may select: x1 from the
     # start, the rest only once their warm compile landed
     assert exp.query_status()["superbatch"] == {
-        "ladder": [1, 2, 4], "warm": [1], "folds": {}}
+        "ladder": [1, 2, 4], "warm": [1], "folds": {}, "wide_folds": 0}
     exp.warm_superbatch_ladder(block=True)
     assert exp.query_status()["superbatch"]["warm"] == [1, 2, 4]
     # single-device names ingest_resident_lanes_x{k}; the 8-virtual-device
-    # mesh (tests/conftest.py) names sharded_ingest_resident_x{k}
-    prefixes = ("ingest_resident_lanes_x", "sharded_ingest_resident_x")
+    # mesh (tests/conftest.py) names sharded_ingest_resident_x{k}; the top
+    # entry's wide lane family has a program of its own in either
+    prefixes = ("ingest_resident_lanes_x", "sharded_ingest_resident_x",
+                "ingest_resident_lanes_wide_x",
+                "sharded_ingest_resident_wide_x")
 
     def ladder_watched():
         return {w["fn"]: w for w in retrace.snapshot()
@@ -250,6 +253,7 @@ def test_zero_retraces_across_ladder():
 
     watched = ladder_watched()
     assert {fn[-2:] for fn in watched} >= {"x1", "x2", "x4"}, set(watched)
+    assert sum("_wide_x4" in fn for fn in watched) == 1, set(watched)
     for fn, w in watched.items():
         # x1 is always selectable, so warm deliberately SKIPS it (a live
         # fold could be tracing it concurrently); it compiles at first use
@@ -259,10 +263,16 @@ def test_zero_retraces_across_ladder():
     # holds ~600 rows — a 2x chunk plus a padded 1x tail
     for size in (4 * B, B, 2 * B, 4 * B, 2 * B + 31, 4 * B, 313):
         exp.export_evicted(EvictedFlows(make_events(size, seed=size)))
+        # 40 distinct keys never call for the wide family: make the next
+        # x4 chunk take it, so both of the entry's programs are dispatched
+        exp._ring._wide_next = True
     with exp._lock:
         exp._drain_pending_locked()
     exp._ring.drain()
     assert {k for k in exp._ring.superbatch_folds} >= {1, 2, 4}
+    assert 0 < exp._ring.wide_folds < exp._ring.superbatch_folds[4]
+    assert exp.query_status()["superbatch"]["wide_folds"] == (
+        exp._ring.wide_folds)
     for w in ladder_watched().values():
         assert w["retraces"] == 0, w
         # ONE compile per fixed shape, ever — the warm call's
@@ -442,7 +452,9 @@ def test_roll_flush_and_close_leave_nothing_carried(tight_exporter):
     assert len(exp._pending_buf) > 0
     exp.flush()
     assert len(exp._pending_buf) == 0
-    for ev in evs[6:]:
+    # newest first: in arrival order the wide family (the top entry's, which
+    # this stream calls for once) happens to leave exactly nothing buffered
+    for ev in reversed(evs[6:]):
         exp.export_evicted(ev)
     assert len(exp._pending_buf) > 0
     exp.close()
@@ -498,3 +510,214 @@ def test_wedged_slot_mid_carry_drops_one_fold_and_adopts_the_state(
     offered = 3 * B
     lost = (B + 40 + 3 * B + B) - got[0]["Records"]
     assert 0 < lost <= offered
+
+
+# --- the wide lane family ----------------------------------------------------
+
+def keyed_events(keys, seed=0):
+    """One event a key: the 5-tuple is the 64-bit `keys[i]` written into
+    the source address and port."""
+    keys = np.asarray(keys, np.uint64)
+    n = len(keys)
+    rng = np.random.default_rng(seed)
+    ev = np.zeros(n, binfmt.FLOW_EVENT_DTYPE)
+    ev["key"]["src_ip"][:, 10:12] = 0xFF
+    for j in range(6):      # bytes 0..3 of the key in 12..15, 4..5 in 4..5
+        ev["key"]["src_ip"][:, (12, 13, 14, 15, 4, 5)[j]] = (
+            keys >> np.uint64(8 * j)) & np.uint64(0xFF)
+    ev["key"]["dst_ip"][:, 10:12] = 0xFF
+    ev["key"]["dst_ip"][:, 12] = 20
+    ev["key"]["src_port"] = (keys >> np.uint64(48)).astype(np.uint16)
+    ev["key"]["dst_port"] = 443
+    ev["key"]["proto"] = 6
+    ev["stats"]["bytes"] = rng.integers(64, 1500, n)
+    ev["stats"]["packets"] = rng.integers(1, 4, n)
+    ev["stats"]["eth_protocol"] = 0x0800
+    ev["stats"]["if_index_first"] = 1
+    return ev
+
+
+class KeyStream:
+    """Zipf(1.2) over `universe` keys from a fixed popularity order; with
+    `new_share`, that share of the rows carries a key never seen before (a
+    counter above the universe): the spoofed-source flood of the cell
+    `collector-1chip.newkeys-saturate`."""
+
+    def __init__(self, seed, universe=1 << 16, new_share=0.0):
+        self.rng = np.random.default_rng(seed)
+        self.universe, self.new_share = universe, new_share
+        self.fresh = 1 << 32
+
+    def take(self, n):
+        keys = np.minimum(self.rng.zipf(1.2, n), self.universe).astype(
+            np.uint64)
+        new = np.flatnonzero(self.rng.random(n) < self.new_share)
+        keys[new] = self.fresh + np.arange(len(new), dtype=np.uint64)
+        self.fresh += len(new)
+        return keyed_events(keys, seed=self.fresh)
+
+
+def family_ring(n_shards=1, lanes=8, batch=8192, log=None):
+    """The served ring's geometry (32 regions of 1,024 rows an x4 chunk,
+    the default narrow and wide caps) over programs that only say who was
+    called: the schedule is the host's alone."""
+    token = jax.numpy.zeros(1)
+
+    def program(name):
+        def ingest(state, tables, flat):
+            if log is not None:
+                log.append(name)
+            return state, tables, token
+        return ingest
+    ring = staging.ShardedResidentStagingRing(
+        batch, n_shards, {k: program(f"x{k}") for k in (1, 2, 4)},
+        key_tables=object(), put=lambda buf: buf, lanes=lanes,
+        pack_threads=4, ladder=(1, 2, 4), lazy_ladder=True,
+        wide_ingest={4: program("wide_x4")})
+    assert ring.batch_per_region == 1024
+    assert ring.caps == flowpack.default_resident_caps(1024)
+    assert ring.wide_caps == flowpack.wide_resident_caps(1024)
+    return ring
+
+
+def carry_through(ring, stream, chunks, rows=4 * 8192):
+    """Offer `chunks` top-entry chunks of `stream`, the rows each left
+    riding the front of the next, as the pending buffer offers them."""
+    held = np.zeros(0, binfmt.FLOW_EVENT_DTYPE)
+    for _ in range(chunks):
+        events = np.concatenate([held, stream.take(rows - len(held))])
+        _, left = ring.fold(None, events, carry=True)
+        held = np.concatenate([events[lo:hi] for lo, hi in left]
+                              or [events[:0]])
+
+
+FLOOD = dict(universe=1 << 12, new_share=0.2)
+RING_SHAPES = {"one_shard_eight_lanes": (1, 8),
+               "four_shards_two_lanes": (4, 2),
+               "two_shards_four_lanes": (2, 4)}
+
+
+@pytest.mark.parametrize("shape", sorted(RING_SHAPES))
+def test_a_key_flood_picks_the_wide_family_and_leaves_few_rows(shape):
+    """A fifth of the rows on never-seen keys: the narrow lanes (64 new keys
+    + 32 spill rows a region of 1,024) leave two rows in three, the ring
+    answers with the wide entry from the second chunk on and, once the 32
+    dictionaries hold the popular keys, leaves under a tenth; held narrow
+    it goes on offering every row three times."""
+    rings = {}
+    for held_narrow in (False, True):
+        ring = family_ring(*RING_SHAPES[shape])
+        ring.mark_warm(2, 4)
+        if not held_narrow:
+            ring.mark_warm(4, wide=True)
+        stream = KeyStream(7, **FLOOD)
+        carry_through(ring, stream, chunks=12)
+        learning = ring.carried_rows
+        carry_through(ring, stream, chunks=12)
+        rings[held_narrow] = (ring, ring.carried_rows - learning)
+    (wide, wide_left), (narrow, narrow_left) = rings[False], rings[True]
+    offered = 12 * 4 * 8192
+    assert narrow.wide_folds == 0 and narrow_left > offered // 2
+    assert wide.superbatch_folds == narrow.superbatch_folds == {4: 24}
+    assert wide.wide_folds == 23        # the first chunk is how it learns
+    assert wide_left < offered // 10
+
+
+def test_the_family_sequence_is_a_pure_function_of_the_row_stream():
+    """Two rings fed the same rows dispatch the same programs in the same
+    order (every process of a spanning mesh must), through a flood that
+    starts and ends inside the stream."""
+    logs = []
+    for _ in range(2):
+        log = []
+        ring = family_ring(log=log)
+        ring.mark_warm(2, 4)
+        ring.mark_warm(4, wide=True)
+        for seed, share, chunks in ((3, 0.0, 6), (4, 0.2, 6), (3, 0.0, 30)):
+            carry_through(ring, KeyStream(seed, 1 << 12, share), chunks)
+        logs.append(log)
+    assert logs[0] == logs[1]
+    assert {"x4", "wide_x4"} <= set(logs[0])
+    assert logs[0][-1] == "x4"          # and back once the flood is over
+
+
+def test_a_zipf_stream_on_learned_dictionaries_never_picks_wide():
+    """Stationary traffic: once the 32 dictionaries hold the head of the
+    popularity order (cold, a Zipf stream IS a key flood, and the ring may
+    answer it as one), no chunk calls for the wide family, chunk after
+    chunk."""
+    ring = family_ring()
+    ring.mark_warm(2, 4)
+    ring.mark_warm(4, wide=True)
+    stream = KeyStream(11, universe=1 << 12)
+    carry_through(ring, stream, chunks=30)
+    learned, left = ring.wide_folds, ring.carried_rows
+    carry_through(ring, stream, chunks=30)
+    assert ring.wide_folds == learned < 30
+    assert ring.carried_rows - left < 30 * 4 * 8192 // 100
+    assert not ring._wide_next
+
+
+def test_status_warm_waits_for_the_wide_entry():
+    """`superbatch.warm` equals `superbatch.ladder` only when EVERY program
+    is compiled: a benchmark's set-up waits on that equality, and a wide
+    entry that compiled inside the window would be a lowering there. The
+    narrow x4 entry is selectable from its own compile on."""
+    ring = family_ring()
+    assert ring.programs() == [(1, False), (2, False), (4, False),
+                               (4, True)]
+    assert ring.warm_entries() == [1]
+    ring.mark_warm(2, 4)
+    assert ring.warm_entries() == [1, 2] and ring.is_warm(4)
+    carry_through(ring, KeyStream(7, **FLOOD), chunks=3)
+    assert ring.superbatch_folds == {4: 3} and ring.wide_folds == 0
+    ring.mark_warm(4, wide=True)
+    assert ring.warm_entries() == [1, 2, 4] == list(ring.ladder)
+    carry_through(ring, KeyStream(8, **FLOOD), chunks=3)
+    assert ring.wide_folds == 2
+
+
+def test_wide_and_narrow_fold_a_flood_to_the_same_window(tight_exporter):
+    """The same flood through an exporter free to pick the wide family and
+    through one held narrow: the same window totals, the same heavy hitters
+    at the sink and the same head of /query/topk — a slot defined through
+    either lane is the same slot — in fewer dispatches."""
+    from netobserv_tpu.metrics.registry import Metrics, MetricsSettings
+
+    def evictions():
+        stream = KeyStream(21, universe=40, new_share=0.2)
+        out = []
+        for i, n in enumerate((4 * B, 4 * B + 70, 97, 8 * B, 4 * B, 301)):
+            out.append(EvictedFlows(stream.take(n)))
+            out[-1].eviction = i + 1
+        return out
+
+    handed = sum(len(e.events) for e in evictions())
+    seen = {}
+    for held_narrow in (False, True):
+        got = []
+        metrics = Metrics(MetricsSettings())
+        exp = tight_exporter(sink=got.append, metrics=metrics)
+        ring = exp._ring
+        assert ring.wide_caps.nk == 3 * ring.caps.nk
+        if held_narrow:
+            ring._wide_available.clear()
+        for ev in evictions():
+            exp.export_evicted(ev)
+        exp.flush()
+        assert got[0]["Records"] == handed
+        assert (metrics.sketch_resident_wide_folds_total._value.get()
+                == ring.wide_folds)
+        code, body = exp.query_routes.handle("/query/topk", {"n": "20"})
+        assert code == 200
+        seen[held_narrow] = (sorted_report(got[0]), body["topk"],
+                             ring.wide_folds,
+                             sum(ring.superbatch_folds.values()))
+    (rep_w, topk_w, wide_w, folds_w), (rep_n, topk_n, wide_n, folds_n) = (
+        seen[False], seen[True])
+    assert wide_w > 0 == wide_n
+    # the slot table's churn counts follow the fold order (module docstring)
+    rep_w.pop("HeavyChurn"), rep_n.pop("HeavyChurn")
+    assert rep_w == rep_n
+    assert topk_w == topk_n
+    assert folds_w < folds_n

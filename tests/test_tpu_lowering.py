@@ -69,13 +69,22 @@ FOLD_SCOPES = {"hash", "countmin", "topk", "hll_src", "hll_grids",
                "quantile", "signals", "totals"}
 
 
+def family_caps(bpl: int, wide: bool):
+    """(caps, the family's part of an entry's name) as the exporter builds
+    its narrow ladder and the top entry's wide twin."""
+    if wide:
+        return flowpack.wide_resident_caps(bpl), "_wide"
+    return flowpack.default_resident_caps(bpl), ""
+
+
 def resident_ladder_entry(k: int, cfg=CFG, lanes: int = 8,
-                          slots: int = 1 << 18):
+                          slots: int = 1 << 18, wide: bool = False):
     """(fn, args) of the exporter's single-device ladder entry x<k>."""
     bpl = BATCH // lanes
-    caps = flowpack.default_resident_caps(bpl)
+    caps, family = family_caps(bpl, wide)
     fn = sk.make_ingest_resident_lanes_fn(
-        bpl, caps, k * lanes, slots, name=f"ingest_resident_lanes_x{k}")
+        bpl, caps, k * lanes, slots,
+        name=f"ingest_resident_lanes{family}_x{k}")
     tables = jax.ShapeDtypeStruct((4 * lanes * slots, sk.KEY_WORDS),
                                   jnp.uint32)
     flat = jax.ShapeDtypeStruct(
@@ -350,12 +359,18 @@ def assert_only_the_scatter_is_table_sized(header, entry, n_elements):
     assert re.search(rf"\({number}, {{}}, may-alias\)", header), header[:300]
 
 
-def test_x1_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
+@pytest.mark.parametrize("k,wide", [(1, False), (4, True)],
+                         ids=["x1", "wide_x4"])
+def test_x1_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e, k,
+                                                                  wide):
     """`ingest_resident_lanes_x1` at default geometry: 8 of the 32 lanes of
-    the array the x4 entry needs, 2^18 slots each."""
+    the array the x4 entry needs, 2^18 slots each. And the top entry's wide
+    lane family (`ingest_resident_lanes_wide_x4`: 32 new-key lanes of 384
+    rows in the one combined scatter): a second program on the same table,
+    held to the same."""
     from jax.sharding import SingleDeviceSharding
     one = SingleDeviceSharding(v5e.devices[0])
-    fn, args = resident_ladder_entry(1)
+    fn, args = resident_ladder_entry(k, wide=wide)
     args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=one), args)
     header, entry = compiled_entry(fn, *args)
@@ -364,25 +379,28 @@ def test_x1_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
 
 
 def compiled_per_shard_x1(v5e, ndata: int, nsk: int, lanes: int,
-                          slots: int = 1 << 18) -> str:
-    """`sharded_ingest_resident_x1` of mesh data=`ndata` x sketch=`nsk` at
-    the default geometry, compiled for the described chips: the HLO text of
-    one chip's program."""
+                          slots: int = 1 << 18, k: int = 1,
+                          wide: bool = False) -> str:
+    """`sharded_ingest_resident_x1` (or `_x<k>`, or the wide family's) of
+    mesh data=`ndata` x sketch=`nsk` at the default geometry, `lanes` pack
+    lanes a shard, compiled for the described chips: the HLO text of one
+    chip's program."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(v5e.devices).reshape(ndata, nsk),
                 ("data", "sketch"))
     bpl = BATCH // (ndata * lanes)
-    caps = flowpack.default_resident_caps(bpl)
+    caps, family = family_caps(bpl, wide)
     fn = pmerge.make_sharded_ingest_resident_fn(
-        mesh, CFG, bpl, caps, slots, lanes=lanes,
-        watch_name="sharded_ingest_resident_x1")
+        mesh, CFG, bpl, caps, slots, lanes=k * lanes,
+        watch_name=f"sharded_ingest_resident{family}_x{k}")
     cpu_mesh = make_mesh(MeshSpec(data=ndata, sketch=nsk),
                          devices=jax.devices()[:4])
     shapes = jax.eval_shape(lambda: (
         pmerge.init_dist_state(CFG, cpu_mesh),
         pmerge.init_resident_tables(cpu_mesh, slots, lanes=4 * lanes)))
     flat = jax.ShapeDtypeStruct(
-        (ndata * lanes * flowpack.resident_buf_len(bpl, caps),), jnp.uint32)
+        (ndata * k * lanes * flowpack.resident_buf_len(bpl, caps),),
+        jnp.uint32)
     dist, tables, flat = jax.tree.map(
         lambda x, spec: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
@@ -393,11 +411,15 @@ def compiled_per_shard_x1(v5e, ndata: int, nsk: int, lanes: int,
             lowering_platforms=("tpu",)).compile().as_text()
 
 
-def test_per_shard_entry_compiles_with_no_table_sized_op_but_the_scatter(v5e):
+@pytest.mark.parametrize("k,wide", [(1, False), (4, True)],
+                         ids=["x1", "wide_x4"])
+def test_per_shard_entry_compiles_with_no_table_sized_op_but_the_scatter(
+        v5e, k, wide):
     """`sharded_ingest_resident_x1` on mesh data=4: each chip's program
-    takes its rows of the sharded table as the same 2-D array."""
+    takes its rows of the sharded table as the same 2-D array — and so does
+    `sharded_ingest_resident_wide_x4`, the top entry's wide lane family."""
     lanes, slots = 2, 1 << 18
-    text = compiled_per_shard_x1(v5e, 4, 1, lanes, slots)
+    text = compiled_per_shard_x1(v5e, 4, 1, lanes, slots, k, wide)
     assert_only_the_scatter_is_table_sized(
         *entry_of(text), sk.KEY_WORDS * 4 * lanes * slots)
 

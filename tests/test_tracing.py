@@ -600,9 +600,11 @@ def test_default_one_device_exporter_registers_exactly_the_served_entries(
     """What `/debug/executables` lists for the exporter `from_config` builds
     on ONE device at the default feed and ladder, after a warm ladder, an
     eviction and a roll: the dict-arrays `ingest`, one resident entry per
-    ladder size, and `roll` — no more (a second form of a served entry
-    would be a second program to keep warm) and no fewer (a deleted factory
-    cannot silently take a served entry with it)."""
+    ladder size, the top entry's wide lane family, and `roll` — no more (a
+    second form of a served entry would be a second program to keep warm:
+    the wide x4 is the one that earns it, PERF.md section 6, PR 35) and no
+    fewer (a deleted factory cannot silently take a served entry with
+    it)."""
     import jax
 
     from netobserv_tpu.config import load_config
@@ -627,7 +629,8 @@ def test_default_one_device_exporter_registers_exactly_the_served_entries(
         exp.flush()
         mine = {w.name: w for w in retrace.watched() if id(w) not in before}
         assert sorted(mine) == [
-            "ingest", "ingest_resident_lanes_x1", "ingest_resident_lanes_x2",
+            "ingest", "ingest_resident_lanes_wide_x4",
+            "ingest_resident_lanes_x1", "ingest_resident_lanes_x2",
             "ingest_resident_lanes_x4", "roll"]
         # the served ones ran; the dict-arrays entry is built, never called
         assert {n for n, w in mine.items() if w.calls} == set(mine) - {
