@@ -20,7 +20,7 @@ from netobserv_tpu.exporter import build_exporter
 from netobserv_tpu.exporter.base import Exporter, QueueExporter
 from netobserv_tpu.flow import Accounter, CapacityLimiter, MapTracer, RingBufTracer
 from netobserv_tpu.metrics.registry import Metrics, MetricsSettings
-from netobserv_tpu.utils import retrace, tracing
+from netobserv_tpu.utils import platform, retrace, tracing
 
 log = logging.getLogger("netobserv_tpu.agent")
 
@@ -55,6 +55,9 @@ class FlowsAgent:
         # post-warmup jit retraces alarm via sketch_retraces_total{fn=...}
         tracing.set_metrics(self.metrics)
         retrace.set_metrics(self.metrics)
+        # the drain's per-eviction arrays reuse the heap instead of
+        # faulting fresh pages in every other drain (utils/platform.py)
+        platform.pin_malloc_thresholds()
         self._status = Status.NOT_STARTED
         self._status_lock = threading.Lock()
         self._stop = threading.Event()

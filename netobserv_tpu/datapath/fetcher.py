@@ -30,9 +30,12 @@ class EvictedFlows:
     drain decode reads zero-copy views of the kernel batch buffers, and
     construction here is the single copy boundary (a later drain must never
     mutate an earlier EvictedFlows; pinned by the aliasing regression in
-    tests/test_bpfman.py). `decode_stats` carries the producing drain's
-    per-stage seconds (decode/merge/align) when the columnar eviction plane
-    built it; map_tracer feeds it to `eviction_decode_seconds`."""
+    tests/test_bpfman.py). `decode_stats` carries what the columnar
+    eviction plane says of the drain that built it — `seconds` (the whole
+    drain's decode; map_tracer feeds it to `eviction_decode_seconds`),
+    `fallback_rows`, `drain_lanes`, and on the fused native path
+    `native_path` and the `native` per-stage split. The seconds of each
+    stage inside it are the stage clocks (`utils/tracing.stage_clocks`)."""
 
     def __init__(self, events: np.ndarray,
                  dns: Optional[np.ndarray] = None,

@@ -219,13 +219,11 @@ def decode_eviction(agg_keys: np.ndarray, agg_vals: np.ndarray,
     tracer.go:1138-1143); one appended row per unique orphan key, shared by
     every feature that saw it, with min/max seen times across them."""
     trace = trace if trace is not None else tracing.NULL_TRACE
-    t0 = time.perf_counter()
     if merged is None:
         with trace.stage("merge_percpu"):
             merged = {attr: flowpack.merge_percpu_batch(
                 attr, vals, threads=merge_threads)
                 for attr, (_keys, vals) in drained.items()}
-    t1 = time.perf_counter()
     with trace.stage("align"):
         n_agg = len(agg_keys)
         attrs = [a for a, (k, _v) in drained.items() if len(k)]
@@ -270,14 +268,12 @@ def decode_eviction(agg_keys: np.ndarray, agg_vals: np.ndarray,
                 first_acc == _U64_MAX, np.uint64(0), first_acc)
             s["last_seen_ns"][n_agg:] = last_acc
     evicted = EvictedFlows(events, **features)
-    evicted.decode_stats = {"merge_s": t1 - t0,
-                            "align_s": time.perf_counter() - t1,
-                            # appended standalone rows: ringbuf-fallback
-                            # singles (or a racing eviction) whose flow
-                            # missed the aggregation drain — the bounded
-                            # double-count overload path, surfaced per
-                            # drain (evict_ringbuf_fallback_total)
-                            "fallback_rows": n_app}
+    # appended standalone rows: ringbuf-fallback singles (or a racing
+    # eviction) whose flow missed the aggregation drain — the bounded
+    # double-count overload path, surfaced per drain
+    # (evict_ringbuf_fallback_total). The stages' own seconds are the stage
+    # clocks (stage_wall_seconds_total{stage="merge_percpu"|"align"}).
+    evicted.decode_stats = {"fallback_rows": n_app}
     return evicted
 
 
@@ -421,10 +417,7 @@ class NativeEvictPipeline:
                  for kind, a in res.aligned.items()}
         evicted = EvictedFlows(events, **feats)
         evicted.decode_stats = {
-            "merge_s": res.merge_s,      # summed lane CPU (the lanes rule)
-            "align_s": res.join_s,
             "fallback_rows": res.n_orphans,
-            "decode_s": res.drain_s + res.merge_s + res.join_s,
             "drain_lanes": self._lanes,
             "seconds": time.perf_counter() - t0,
             "native_path": "fused",
@@ -599,9 +592,7 @@ class BpfmanFetcher:
                 self._agg, binfmt.FLOW_STATS_DTYPE)
             drained = {attr: _drain_map_arrays(fmap, dtype)
                        for attr, (fmap, dtype) in self._features.items()}
-        t1 = time.perf_counter()
         evicted = decode_eviction(agg_keys, agg_vals, drained, trace=trace)
-        evicted.decode_stats["decode_s"] = t1 - t0
         evicted.decode_stats["drain_lanes"] = 1
         evicted.decode_stats["seconds"] = time.perf_counter() - t0
         if self._native_gate is not None:
@@ -621,10 +612,8 @@ class BpfmanFetcher:
 
         def lane(attr, fmap, dtype):
             ks, vals = _drain_map_arrays(fmap, dtype)
-            tm = time.perf_counter()
-            recs = flowpack.merge_percpu_batch(attr, vals,
-                                               threads=mthreads)
-            return attr, ks, recs, time.perf_counter() - tm
+            return attr, ks, flowpack.merge_percpu_batch(attr, vals,
+                                                         threads=mthreads)
 
         with trace.stage("decode"):
             futs = [self._drain_pool.submit(lane, attr, fmap, dtype)
@@ -632,18 +621,13 @@ class BpfmanFetcher:
             agg_keys, agg_vals = _drain_map_arrays(
                 self._agg, binfmt.FLOW_STATS_DTYPE)
             lanes = [f.result() for f in futs]
-        t1 = time.perf_counter()
         # vals half None: the per-CPU partials were consumed in-lane —
         # decode_eviction's merged= contract (never smuggle merged
         # records into the partials slot)
-        drained = {attr: (ks, None) for attr, ks, _recs, _dt in lanes}
+        drained = {attr: (ks, None) for attr, ks, _recs in lanes}
         evicted = decode_eviction(
             agg_keys, agg_vals, drained, trace=trace,
-            merged={attr: recs for attr, _ks, recs, _dt in lanes})
-        # merge ran inside the lanes: report the summed lane CPU (the
-        # overlap evidence — decode_s is the whole section's WALL)
-        evicted.decode_stats["merge_s"] = sum(dt for *_x, dt in lanes)
-        evicted.decode_stats["decode_s"] = t1 - t0
+            merged={attr: recs for attr, _ks, recs in lanes})
         evicted.decode_stats["drain_lanes"] = self._drain_lanes
         evicted.decode_stats["seconds"] = time.perf_counter() - t0
         return evicted

@@ -1309,10 +1309,13 @@ class TpuSketchExporter(Exporter):
         slot-wait p95 drive the AIMD shed factor, and the batch is thinned
         BEFORE buffering — surviving rows carry the factor in their
         `sampling` field, so the device de-bias keeps every estimate
-        unbiased."""
+        unbiased.
+
+        The whole seam is the `export` stage — lock wait and any window
+        close it triggers included — on whichever thread runs it."""
         trace = getattr(evicted, "trace", None)
         seq = getattr(evicted, "eviction", 0)
-        with self._lock:
+        with tracing.NULL_TRACE.stage("export", eviction=seq), self._lock:
             packed = getattr(evicted, "packed", None)
             if packed is not None:
                 # fused-pipeline arena riding the eviction: ship it in
@@ -2080,12 +2083,17 @@ class TpuSketchExporter(Exporter):
 
     def _publish_report(self, report, wtrace=tracing.NULL_TRACE,
                         tables=None) -> None:
-        if self._tenancy is not None:
-            # stacked roll output: fan every tenant's slice out through the
-            # same publish discipline (delta -> render -> snapshot -> sink
-            # -> archive, each failure domain its own try)
-            self._publish_report_tenants(report, wtrace, tables)
-            return
+        """One window's publish, the `publish` stage round all of it."""
+        with wtrace.stage("publish"):
+            if self._tenancy is not None:
+                # stacked roll output: fan every tenant's slice out through
+                # the same publish discipline (delta -> render -> snapshot
+                # -> sink -> archive, each failure domain its own try)
+                self._publish_report_tenants(report, wtrace, tables)
+            else:
+                self._publish_report_one(report, wtrace, tables)
+
+    def _publish_report_one(self, report, wtrace, tables) -> None:
         self._windows_published += 1  # telemetry: counts THIS window
         if self._delta_sink is not None and tables is not None:
             # federation delta FIRST, in its own try: a dead aggregator (or

@@ -176,7 +176,7 @@ class MapTracer:
     def _evict_locked(self) -> None:
         # flight recorder: a batch trace is born here and rides the evicted
         # batch to the exporter fold (columnar path); un-sampled evictions
-        # get the shared NULL trace — no timestamps, no locks. Sampled or
+        # get the shared NULL trace — no Trace, no locks. Sampled or
         # not, the drain's stages carry `eviction=<n>` in a profiler
         # capture: the number is stamped on the EvictedFlows below and the
         # exporter's fold chunks name the range of evictions they carry.

@@ -48,6 +48,39 @@ class MetricsSettings:
         return lvl
 
 
+class _StageClocks:
+    """The always-on stage clocks of `utils/tracing.py`, summed over the
+    process's threads at scrape time (the hot path never calls Prometheus
+    code): wall seconds, thread-CPU seconds and calls per stage."""
+
+    def __init__(self, prefix: str):
+        self._prefix = prefix
+
+    def collect(self):
+        from prometheus_client.core import CounterMetricFamily
+
+        from netobserv_tpu.utils import tracing
+
+        p = self._prefix
+        wall = CounterMetricFamily(
+            p + "stage_wall_seconds",
+            "Wall seconds inside each pipeline stage, every call, sampled "
+            "or not (children included)", labels=["stage"])
+        cpu = CounterMetricFamily(
+            p + "stage_cpu_seconds",
+            "Thread-CPU seconds inside each pipeline stage (time blocked on "
+            "the GIL or a lock accrues none; children included)",
+            labels=["stage"])
+        calls = CounterMetricFamily(
+            p + "stage_calls", "Calls of each pipeline stage",
+            labels=["stage"])
+        for stage, (w, c, n) in sorted(tracing.stage_clocks().items()):
+            wall.add_metric([stage], w)
+            cpu.add_metric([stage], c)
+            calls.add_metric([stage], n)
+        yield from (wall, cpu, calls)
+
+
 class Metrics:
     """Facade handed to every pipeline stage (reference: `metrics.Metrics`)."""
 
@@ -427,6 +460,7 @@ class Metrics:
             ["stage"],
             buckets=(.0001, .0005, .001, .005, .01, .05, .1, .5, 1, 5),
             registry=self.registry)
+        self.registry.register(_StageClocks(p))
         self.sketch_retraces_total = Counter(
             p + "sketch_retraces_total",
             "Post-warmup XLA recompilations of a watched jitted entry "

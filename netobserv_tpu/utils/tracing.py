@@ -15,8 +15,19 @@ The inter-span *gaps* are as load-bearing as the spans: the time between the
 evicted/export queue wait — the first thing to grow when the exporter falls
 behind.
 
-Two recorders share every stage boundary:
+Three recorders share every stage boundary:
 
+- **the stage clocks** — always on, sampled or not, with jax loaded or not:
+  when a stage exits, its wall seconds (``time.perf_counter``), its
+  thread-CPU seconds (``time.thread_time``: a thread blocked on the GIL or
+  on a lock accrues none) and one call are added to a table owned by the
+  calling thread. No add can be lost between threads, and the hot path
+  takes no lock and calls no Prometheus code. :func:`stage_clocks` sums
+  the tables, a thread's table kept after the thread exits; the metrics
+  registry exposes the sums as ``stage_wall_seconds_total{stage}``,
+  ``stage_cpu_seconds_total{stage}`` and ``stage_calls_total{stage}``.
+  Totals include child stages: a stage's self time is its total less its
+  children's totals (the nesting is docs/observability.md "Stages").
 - **the profiler's** — every ``stage()`` opens a
   ``jax.profiler.TraceAnnotation`` named ``netobserv:<stage>``, sampled or
   not, carrying the stage's ids as arguments (``eviction``, ``evictions``,
@@ -43,9 +54,10 @@ Sampling and the zero-cost contract:
   sample).
 - Disabled (the default), :func:`start_trace` is one module-bool check
   returning the shared :data:`NULL_TRACE`, whose ``stage()`` opens the
-  profiler annotation and nothing else — no ``Trace``, no recorder entry,
-  no lock, no timestamp (the same discipline as ``utils.faultinject``;
-  pinned by tests/test_tracing.py).
+  profiler annotation and reads the stage clocks, and nothing else — no
+  ``Trace``, no recorder entry, no lock: two clock pairs and one add into
+  the thread's own table (a thread's first stage registers its table
+  once, under a lock; pinned by tests/test_tracing.py).
 - Unsampled calls while enabled cost one int increment + one modulo.
 
 ``TRACE_RING`` (env, default 64) bounds how many completed traces the
@@ -79,7 +91,7 @@ __all__ = [
     "NULL_SPAN", "NULL_TRACE", "Trace", "FlightRecorder", "TraceContext",
     "start_trace", "configure", "set_metrics", "snapshot", "enabled",
     "set_active", "clear_active", "active_trace",
-    "context_of", "continue_trace", "group", "annotate",
+    "context_of", "continue_trace", "group", "annotate", "stage_clocks",
 ]
 
 
@@ -144,13 +156,13 @@ class _Bound:
 
 class _NullTrace:
     """Shared do-nothing trace: every un-sampled batch carries this. Its
-    stages are profiler annotations only."""
+    stages are profiler annotations and stage clocks only."""
 
     __slots__ = ()
     sampled = False
 
     def stage(self, name: str, **ids):
-        return annotate(name, **ids)
+        return _SpanCtx((), name, ids)
 
     def bind(self, **ids):
         return _Bound(self, ids)
@@ -188,32 +200,95 @@ class _Span:
 
 
 class _SpanCtx:
-    """Context manager recording one stage span onto its trace(s) — several
-    for a :class:`TraceGroup` — under ONE profiler annotation (records on
-    exit even when the stage raised — a failed stage's duration is evidence,
-    not noise)."""
+    """One stage boundary: ONE profiler annotation, the calling thread's
+    stage clocks, and a span on each sampled trace — none for the null
+    trace, several for a :class:`TraceGroup`. Clocks and spans record on
+    exit even when the stage raised — a failed stage's duration is
+    evidence, not noise."""
 
-    __slots__ = ("_traces", "_stage", "_ids", "_t0", "_ann")
+    __slots__ = ("_traces", "_stage", "_ids", "_t0", "_c0", "_ann")
 
     def __init__(self, traces: tuple, stage: str, ids: dict):
         self._traces = traces
         self._stage = stage
         self._ids = ids
-        self._t0 = 0.0
+        self._t0 = self._c0 = 0.0
         self._ann = NULL_SPAN
 
     def __enter__(self):
         self._ann = annotate(self._stage, **self._ids)
         self._ann.__enter__()
+        self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
+    def __exit__(self, et, ev, tb):
         t1 = time.perf_counter()
-        self._ann.__exit__(*exc)
+        cpu = time.thread_time() - self._c0
+        # the stage clocks, inline: the hot path's one add
+        try:
+            acc = _clock_local.table[self._stage]
+        except (AttributeError, KeyError):
+            acc = _clock_acc(self._stage)
+        acc[0] += t1 - self._t0
+        acc[1] += cpu
+        acc[2] += 1
+        self._ann.__exit__(et, ev, tb)
         for t in self._traces:
             t._add(self._stage, self._t0, t1, self._ids)
         return False
+
+
+# --- stage clocks ----------------------------------------------------------
+# Each thread adds into its own {stage: [wall_s, cpu_s, calls]}: the owner is
+# the table's only writer, so no add is lost and the hot path takes no lock.
+# A scrape (stage_clocks) reads every table under _tables_lock, which a
+# thread also takes once, to register its table at its first stage.
+_clock_local = threading.local()
+_tables: list = []      # (thread, table) of every live thread that clocked
+_retired: dict = {}     # the tables of exited threads, summed
+_tables_lock = threading.Lock()
+
+
+def _clock_acc(stage: str) -> list:
+    """The calling thread's accumulator of `stage`, made at its first
+    exit (and the thread's table at its first stage)."""
+    try:
+        table = _clock_local.table
+    except AttributeError:
+        table = _clock_local.table = {}
+        with _tables_lock:
+            _tables.append((threading.current_thread(), table))
+    return table.setdefault(stage, [0.0, 0.0, 0])
+
+
+def _sum_into(out: dict, table: dict) -> None:
+    for stage, (wall, cpu, calls) in table.items():
+        acc = out.setdefault(stage, [0.0, 0.0, 0])
+        acc[0] += wall
+        acc[1] += cpu
+        acc[2] += calls
+
+
+def stage_clocks() -> dict:
+    """``{stage: (wall_s, cpu_s, calls)}`` of every stage that has exited in
+    this process, summed over its threads, those that have exited included
+    (their tables fold into one here). Monotonic: the counters
+    ``stage_{wall,cpu}_seconds_total`` / ``stage_calls_total`` read it."""
+    with _tables_lock:
+        live = []
+        for thread, table in _tables:
+            if thread.is_alive():
+                live.append((thread, table))
+            else:
+                _sum_into(_retired, table)
+        _tables[:] = live
+        out = {stage: list(acc) for stage, acc in _retired.items()}
+        for _, table in live:
+            # copy() is one C call under the GIL: the owner may be adding a
+            # stage meanwhile
+            _sum_into(out, table.copy())
+    return {stage: tuple(acc) for stage, acc in out.items()}
 
 
 class Trace:

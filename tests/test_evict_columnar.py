@@ -26,6 +26,7 @@ import pytest
 from netobserv_tpu.datapath import flowpack, loader
 from netobserv_tpu.model import accumulate as acc
 from netobserv_tpu.model import binfmt
+from netobserv_tpu.utils import tracing
 
 KINDS = ["stats", "extra", "drops", "dns", "nevents", "xlat", "quic"]
 
@@ -241,8 +242,10 @@ class TestDecodeEviction:
         dn_k = orph[:1].copy()
         dn_v = np.zeros((1, c), binfmt.DNS_REC_DTYPE)
         dn_v["latency_ns"][0] = [5, 9, 2, 1]
+        clocks0 = tracing.stage_clocks()
         ev = loader.decode_eviction(
             agg, stats, {"extra": (ex_k, ex_v), "dns": (dn_k, dn_v)})
+        clocks1 = tracing.stage_clocks()
         assert len(ev) == n + 3
         assert np.array_equal(ev.events["stats"][:n], stats[:, 0])
         for j, si in enumerate(sel):
@@ -258,9 +261,14 @@ class TestDecodeEviction:
             int(mex["first_seen_ns"])
         assert int(ev.events["stats"][shared]["last_seen_ns"]) == \
             int(mex["last_seen_ns"])
-        # decode stats ride the EvictedFlows for map_tracer's histogram
-        assert ev.decode_stats["merge_s"] >= 0
-        assert ev.decode_stats["align_s"] >= 0
+        # the merge and the join are one call each of their stage clocks
+        # (stage_{wall,cpu}_seconds_total); fallback_rows rides the
+        # EvictedFlows for map_tracer's counter
+        for stage in ("merge_percpu", "align"):
+            w0, c0, n0 = clocks0.get(stage, (0.0, 0.0, 0))
+            w1, c1, n1 = clocks1[stage]
+            assert n1 == n0 + 1 and w1 >= w0 and c1 >= c0
+        assert ev.decode_stats == {"fallback_rows": 3}
 
     def test_duplicate_keys_last_wins(self):
         """Duplicate agg keys across drain chunks: feature rows land on the

@@ -32,6 +32,7 @@ import pytest
 
 from netobserv_tpu.datapath import flowpack, loader
 from netobserv_tpu.model import binfmt
+from netobserv_tpu.utils import tracing
 
 
 @pytest.fixture(scope="module")
@@ -181,11 +182,18 @@ class TestParallelLanes:
                 load_fetcher(seq, drain)
                 load_fetcher(par, drain)
                 a = seq.lookup_and_delete()
+                clocks0 = tracing.stage_clocks()
                 b = par.lookup_and_delete()
+                clocks1 = tracing.stage_clocks()
                 assert evicted_payload(a) == evicted_payload(b)
                 assert a.decode_stats["drain_lanes"] == 1
                 assert b.decode_stats["drain_lanes"] == lanes
-                assert b.decode_stats["merge_s"] >= 0.0
+                # the lanes merged in-lane: the drain is one `decode` and
+                # one `align` on the stage clocks, and no `merge_percpu`
+                for stage, calls in (("decode", 1), ("align", 1),
+                                     ("merge_percpu", 0)):
+                    assert (clocks1.get(stage, (0, 0, 0))[2]
+                            - clocks0.get(stage, (0, 0, 0))[2]) == calls
                 assert b.decode_stats["fallback_rows"] == \
                     a.decode_stats["fallback_rows"] > 0
         finally:

@@ -2,10 +2,16 @@
 
 Pins the tentpole contracts:
 
-- tracing disabled (TRACE_SAMPLE unset) records nothing: start_trace returns
-  the one shared null trace, whose stage() opens the profiler's annotation
-  `netobserv:<stage>` (the profiler's own no-op while no session runs) and
-  nothing else — no Trace, no recorder entry, no lock, no timestamp;
+- tracing disabled (TRACE_SAMPLE unset) records nothing in the flight
+  recorder: start_trace returns the one shared null trace, whose stage()
+  opens the profiler's annotation `netobserv:<stage>` (the profiler's own
+  no-op while no session runs) and reads the stage clocks — no Trace, no
+  recorder entry, no lock: two clock pairs and one add into the calling
+  thread's own table;
+- the stage clocks are always on, with jax loaded or not: two threads on
+  one stage lose no call and no second, a thread's totals outlive it, and
+  /metrics exposes them as stage_{wall,cpu}_seconds_total and
+  stage_calls_total;
 - under a jax.profiler session the same stages lie on the profiler's clock
   with the ids that chain an eviction to its fold chunks and a chunk to its
   dispatch (eviction / evictions / chunk / k / cont / fn / call);
@@ -58,33 +64,48 @@ def test_disabled_is_the_shared_null_trace_and_records_nothing(monkeypatch):
     t2 = tracing.start_trace("window")
     assert t1 is tracing.NULL_TRACE and t2 is tracing.NULL_TRACE
     assert not t1.sampled
-    # without sampling a stage is the profiler's annotation and nothing of
-    # the flight recorder's: no Trace and no span object, no lock, no
-    # timestamp (the recorder's clock and lock raise if touched)
-    monkeypatch.setattr(tracing.time, "perf_counter",
-                        lambda: pytest.fail("a timestamp without sampling"))
+    # this thread's clock table exists after its first stage
+    with t1.stage("warm"):
+        pass
+    # without sampling a stage is the profiler's annotation and the stage
+    # clocks, and nothing of the flight recorder's: no Trace and no span,
+    # no recorder entry, no lock (each raises if touched)
     monkeypatch.setattr(tracing.threading, "Lock",
                         lambda: pytest.fail("a lock without sampling"))
+    monkeypatch.setattr(tracing, "_tables_lock", None)
+    monkeypatch.setattr(tracing.Trace, "_add",
+                        lambda *a: pytest.fail("a span without sampling"))
+    monkeypatch.setattr(tracing.FlightRecorder, "add",
+                        lambda *a: pytest.fail("a recorder entry"))
+    before = tracing._clock_local.table.get("evict", [0.0, 0.0, 0])[2]
     for handle in (t1, t1.bind(eviction=7)):
         s1 = handle.stage("evict")
         s2 = handle.stage("fold", eviction=7)
         for span in (s1, s2):
-            assert not isinstance(span, (tracing._SpanCtx, tracing.Trace))
+            assert not isinstance(span, tracing.Trace)
+            assert span._traces == ()
             with span:
                 pass
         handle.finish()
+    assert tracing._clock_local.table["evict"][2] == before + 2
     assert len(tracing.recorder) == 0
     assert not tracing.enabled()
 
 
-def test_a_stage_is_the_null_span_until_the_process_loads_jax():
+def test_a_process_without_jax_still_counts():
     """`utils.tracing` never imports jax: an agent with EXPORT=grpc has no
-    profiler to annotate for, and importing the drain must not load one."""
-    code = ("import sys\n"
+    profiler to annotate for, and importing the drain must not load one —
+    yet its stages count on the stage clocks."""
+    code = ("import sys, time\n"
             "import netobserv_tpu.flow.map_tracer\n"
             "from netobserv_tpu.utils import tracing\n"
             "span = tracing.NULL_TRACE.stage('evict', eviction=1)\n"
-            "assert span is tracing.NULL_SPAN, span\n"
+            "assert tracing.annotate('evict') is tracing.NULL_SPAN\n"
+            "with span:\n"
+            "    time.sleep(0.01)\n"
+            "wall, cpu, calls = tracing.stage_clocks()['evict']\n"
+            "assert calls == 1 and wall >= 0.01 and 0 <= cpu < wall, "
+            "(wall, cpu)\n"
             "assert 'jax' not in sys.modules, 'jax was imported'\n")
     import subprocess
     import sys
@@ -92,6 +113,78 @@ def test_a_stage_is_the_null_span_until_the_process_loads_jax():
     got = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=120)
     assert got.returncode == 0, got.stderr
+
+
+def test_two_threads_on_one_stage_lose_no_call_and_no_second(monkeypatch):
+    """Each thread adds into its own table: whatever the interleaving, the
+    sum is exact. A fake clock makes every stage exactly 1 wall second and
+    0.5 CPU second, per thread."""
+    import threading
+    import types
+
+    local = threading.local()
+
+    def tick(attr, step):
+        def read():
+            v = getattr(local, attr, 0.0) + step
+            setattr(local, attr, v)
+            return v
+        return read
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        perf_counter=tick("wall", 1.0), thread_time=tick("cpu", 0.5)))
+    n, go = 20_000, threading.Barrier(2)
+
+    def hammer():
+        go.wait()
+        for _ in range(n):
+            with tracing.NULL_TRACE.stage("hammer", chunk=1):
+                pass
+    threads = [threading.Thread(target=hammer) for _ in range(2)]
+    base = tracing.stage_clocks().get("hammer", (0.0, 0.0, 0))
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    wall, cpu, calls = tracing.stage_clocks()["hammer"]
+    assert calls - base[2] == 2 * n
+    assert wall - base[0] == 2 * n * 1.0
+    assert cpu - base[1] == 2 * n * 0.5
+
+
+def test_a_threads_totals_outlive_it():
+    import threading
+
+    def run():
+        for _ in range(3):
+            with tracing.NULL_TRACE.stage("ephemeral"):
+                pass
+    base = tracing.stage_clocks().get("ephemeral", (0.0, 0.0, 0))[2]
+    t = threading.Thread(target=run)
+    t.start()
+    t.join()
+    # the first scrape folds the exited thread's table into the retired
+    # one; later scrapes still count it, once
+    for _ in range(2):
+        assert tracing.stage_clocks()["ephemeral"][2] == base + 3
+    assert all(th is not t for th, _ in tracing._tables)
+
+
+def test_stage_clocks_are_counters_on_metrics():
+    from prometheus_client.parser import text_string_to_metric_families
+
+    m = Metrics()
+    with tracing.NULL_TRACE.stage("scraped"):
+        pass
+    got = {}
+    for fam in text_string_to_metric_families(
+            generate_latest(m.registry).decode()):
+        for smp in fam.samples:
+            if smp.labels.get("stage") == "scraped":
+                got[smp.name] = smp.value
+    wall, cpu, calls = tracing.stage_clocks()["scraped"]
+    assert got["ebpf_agent_stage_calls_total"] == calls >= 1
+    assert got["ebpf_agent_stage_wall_seconds_total"] == wall > 0
+    assert got["ebpf_agent_stage_cpu_seconds_total"] == cpu >= 0
 
 
 def test_null_trace_survives_every_pipeline_verb():
@@ -726,9 +819,10 @@ def _of(capture, stage):
 
 def test_capture_holds_every_served_path_stage(capture):
     stages = {s[0] for s in capture["spans"]}
-    assert {"evict", "fold", "resident_pack", "put", "ingest_dispatch",
-            "dispatch", "roll_drain", "roll_dispatch", "report_render",
-            "query_snapshot", "report_sink"} <= stages
+    assert {"evict", "export", "fold", "resident_pack", "put",
+            "ingest_dispatch", "dispatch", "roll_drain", "roll_dispatch",
+            "publish", "report_render", "query_snapshot",
+            "report_sink"} <= stages
     # the profiler's spans need no sampling, and sample nothing
     assert capture["recorded"] == 0
 
@@ -752,6 +846,31 @@ def test_capture_eviction_ids_chain_evict_to_fold_to_chunks(capture):
             held.update(range(first, last + 1))
             assert s[1]["k"] == 1 and s[1]["cont"] in (0, 1)
     assert set(seqs) <= held    # every eviction's rows ride some chunk
+
+
+def test_capture_export_carries_the_eviction_and_holds_its_fold(capture):
+    """One `export` per eviction, on the seam's thread, after the eviction's
+    `evict`: end of `evict` to start of `export` is the queue alone."""
+    seqs, exports = capture["seqs"], _of(capture, "export")
+    assert [e[1]["eviction"] for e in exports] == seqs
+    evicts = {e[1]["eviction"]: e for e in _of(capture, "evict")}
+    for e in exports:
+        assert e[2] >= evicts[e[1]["eviction"]][3]
+    # a steady fold and its chunks nest inside the export that triggered it
+    inside = [f for f in _of(capture, "fold")
+              if any(e[2] <= f[2] and f[3] <= e[3] for e in exports)]
+    assert inside
+    for f in inside:
+        e = next(e for e in exports if e[2] <= f[2] and f[3] <= e[3])
+        assert f[1]["eviction"] == e[1]["eviction"]
+
+
+def test_capture_publish_carries_the_window_and_holds_render_to_sink(capture):
+    (publish,) = _of(capture, "publish")
+    render, sink = _of(capture, "report_render"), _of(capture, "report_sink")
+    assert publish[1]["window"] == render[0][1]["window"]
+    for child in render + sink + _of(capture, "query_snapshot"):
+        assert publish[2] <= child[2] and child[3] <= publish[3]
 
 
 def test_capture_chunk_ids_chain_pack_put_dispatch_to_the_jit_call(capture):
@@ -781,8 +900,8 @@ def test_capture_chunk_ids_chain_pack_put_dispatch_to_the_jit_call(capture):
 def test_capture_window_ids_ride_from_the_roll_to_the_sink(capture):
     windows = {s[0]: s[1]["window"] for s in capture["spans"]
                if "window" in s[1]}
-    assert set(windows) >= {"roll_drain", "roll_dispatch", "report_render",
-                            "query_snapshot", "report_sink"}
+    assert set(windows) >= {"roll_drain", "roll_dispatch", "publish",
+                            "report_render", "query_snapshot", "report_sink"}
     assert len(set(windows.values())) == 1
     roll = [d for d in _of(capture, "dispatch")
             if d[1]["fn"] in ("roll", "sharded_merge")]
