@@ -12,6 +12,7 @@ import ctypes
 import logging
 import os
 import subprocess
+import threading
 from typing import Optional
 
 import numpy as np
@@ -44,7 +45,7 @@ class _Columns(ctypes.Structure):
 _lib: Optional[ctypes.CDLL] = None
 
 
-_ABI_VERSION = 10
+_ABI_VERSION = 11
 
 #: count of library loads rejected for ABI/symbol mismatch (stale `make
 #: native` build) — the agent degrades to the numpy/python twin chain
@@ -225,6 +226,8 @@ def _find_lib() -> Optional[ctypes.CDLL]:
                 continue
             if ver == _ABI_VERSION:
                 lib.fp_crc32c.restype = ctypes.c_uint32
+                lib.fp_align_scratch_bytes.restype = ctypes.c_uint64
+                lib.fp_align_eviction.restype = ctypes.c_int64
                 return lib
             abi_fallbacks += 1
             log.warning("flowpack ABI mismatch at %s (built %d, need %d) — "
@@ -881,6 +884,82 @@ def events_from_keys_stats(keys: np.ndarray, stats: np.ndarray,
         _lib.fp_events_from_keys_stats(
             _ptr(keys), _ptr(stats), ctypes.c_size_t(n), _ptr(out))
     return out
+
+
+class _AlignFeat(ctypes.Structure):
+    _fields_ = [("keys", ctypes.c_void_p), ("recs", ctypes.c_void_p),
+                ("out", ctypes.c_void_p), ("n", ctypes.c_uint64),
+                ("value_size", ctypes.c_uint64)]
+
+
+class _AlignResult(ctypes.Structure):
+    _fields_ = [("n_events", ctypes.c_uint64), ("n_orphans", ctypes.c_uint64),
+                ("lex_fallback", ctypes.c_uint64)]
+
+
+#: the join's scratch, one buffer per calling thread (the drain thread in a
+#: deployment), grown geometrically and never freed between drains
+_ALIGN_SCRATCH = threading.local()
+
+
+def _align_scratch(nbytes: int) -> np.ndarray:
+    buf = getattr(_ALIGN_SCRATCH, "buf", None)
+    if buf is None or buf.nbytes < nbytes:
+        size = max(nbytes, 2 * buf.nbytes if buf is not None else 0)
+        buf = _ALIGN_SCRATCH.buf = np.empty((size + 7) // 8, np.uint64)
+    return buf
+
+
+def align_eviction(agg_keys: np.ndarray, agg_stats: np.ndarray,
+                   feats: list) -> Optional[tuple]:
+    """The align half of a drain as ONE GIL-releasing native call
+    (flowpack.cc fp_align_eviction): key join, event compose, every feature
+    map's alignment and the orphan events' seen times. `agg_keys` (n, 40)
+    u8, `agg_stats` (n,) FLOW_STATS, `feats` [(keys (m, 40) u8, merged
+    (m,) records)]. Returns (events, [aligned or None per feature map],
+    n_orphans, lex_fallback) — bit-exact with loader._join_keys and the
+    numpy align (the tests' oracle) — or None where the library is absent
+    or refuses the input, and the caller runs the numpy twin."""
+    if not native_available():
+        return None
+    agg_keys = np.ascontiguousarray(agg_keys, dtype=np.uint8)
+    agg_stats = np.ascontiguousarray(agg_stats, dtype=binfmt.FLOW_STATS_DTYPE)
+    n_agg = len(agg_keys)
+    feats = [(np.ascontiguousarray(k, dtype=np.uint8), np.ascontiguousarray(r))
+             for k, r in feats]
+    # the call takes one row count per map: keys and records must agree
+    # (too many maps the call refuses itself, -2)
+    if len(agg_stats) != n_agg or any(len(k) != len(r) for k, r in feats):
+        return None
+    n_feat_rows = sum(len(k) for k, _r in feats)
+    total = n_agg + n_feat_rows
+    cfeats = (_AlignFeat * max(len(feats), 1))()
+    outs = []
+    for i, (keys, recs) in enumerate(feats):
+        out = np.empty(total, recs.dtype) if len(keys) else None
+        outs.append(out)
+        cfeats[i] = _AlignFeat(
+            keys=keys.ctypes.data, recs=recs.ctypes.data,
+            out=out.ctypes.data if out is not None else None,
+            n=len(keys), value_size=recs.dtype.itemsize)
+    scratch = _align_scratch(int(_lib.fp_align_scratch_bytes(
+        ctypes.c_uint64(n_agg), ctypes.c_uint64(n_feat_rows))))
+    events = np.empty(total, binfmt.FLOW_EVENT_DTYPE)
+    res = _AlignResult()
+    rc = _lib.fp_align_eviction(
+        _ptr(agg_keys), _ptr(agg_stats), ctypes.c_uint64(n_agg), cfeats,
+        ctypes.c_uint32(len(feats)), _ptr(scratch),
+        ctypes.c_uint64(scratch.nbytes), _ptr(events), ctypes.c_uint64(total),
+        ctypes.byref(res))
+    if rc < 0:
+        return None
+    # the outputs were sized for the most events a drain can give; keep the
+    # first n (a shrinking realloc: no copy)
+    n = int(rc)
+    for a in [events] + outs:
+        if a is not None and len(a) != n:
+            a.resize(n, refcheck=False)
+    return events, outs, int(res.n_orphans), int(res.lex_fallback)
 
 
 # ---------------------------------------------------------------------------

@@ -74,8 +74,8 @@ _FEATURE_MAPS = [
 # Columnar eviction plane (docs/architecture.md "Eviction plane"): the drain
 # decodes as whole arrays straight from the batch-syscall buffers, per-CPU
 # partials merge as one native/columnar pass per feature map, and key
-# alignment is a void-view sort/searchsorted join — no per-record Python
-# anywhere.
+# alignment is one native hash join (flowpack.align_eviction) with a numpy
+# sort/searchsorted twin — no per-record Python anywhere.
 # ---------------------------------------------------------------------------
 
 _KEY_SIZE = binfmt.FLOW_KEY_DTYPE.itemsize
@@ -207,11 +207,15 @@ def decode_eviction(agg_keys: np.ndarray, agg_vals: np.ndarray,
 
     `merged` (attr -> (m,) merged records) skips the per-CPU merge stage —
     the parallel drain lanes (BpfmanFetcher) merge inside each lane worker
-    and hand only the align half here, keeping `_join_keys` the single join
-    point of the fused stream; `drained`'s partials half is then unused
+    and hand only the align half here, keeping it the single join point
+    of the fused stream; `drained`'s partials half is then unused
     (callers pass None rather than repurposing the slot). `merge_threads`
     row-shards each map's native merge (flowpack.merge_percpu_batch
     lanes) on the sequential path.
+
+    The align half is one GIL-releasing native call
+    (flowpack.align_eviction) wherever the library is loaded; the numpy
+    twin (`_align_numpy`) carries it otherwise, with the same bytes.
 
     Feature records whose flow is missing from the aggregation drain
     (ringbuf-fallback flows, or a racing eviction) become standalone
@@ -225,56 +229,79 @@ def decode_eviction(agg_keys: np.ndarray, agg_vals: np.ndarray,
                 attr, vals, threads=merge_threads)
                 for attr, (_keys, vals) in drained.items()}
     with trace.stage("align"):
-        n_agg = len(agg_keys)
-        attrs = [a for a, (k, _v) in drained.items() if len(k)]
-        if attrs:
-            idx_blocks, orphan_blocks, appended_keys = _join_keys(
-                np.ascontiguousarray(agg_keys),
-                [np.ascontiguousarray(drained[a][0]) for a in attrs])
-            joins = {a: (idx_blocks[i], orphan_blocks[i])
-                     for i, a in enumerate(attrs)}
+        native = flowpack.align_eviction(
+            agg_keys,
+            agg_vals[:, 0] if len(agg_keys)
+            else np.empty(0, binfmt.FLOW_STATS_DTYPE),
+            [(drained[a][0], merged[a]) for a in drained])
+        if native is not None:
+            events, outs, n_app, _lex = native
+            features = dict(zip(drained, outs))
         else:
-            joins, appended_keys = {}, np.empty((0, _KEY_SIZE), np.uint8)
-        n = n_agg + len(appended_keys)
-        events = flowpack.events_from_keys_stats(
-            agg_keys if n_agg else np.empty((0, _KEY_SIZE), np.uint8),
-            agg_vals[:, 0] if n_agg else np.empty(0, binfmt.FLOW_STATS_DTYPE),
-            n_total=n)
-        n_app = len(appended_keys)
-        if n_app:
-            events["key"][n_agg:] = appended_keys.view(
-                binfmt.FLOW_KEY_DTYPE).reshape(-1)
-        first_acc = np.full(n_app, _U64_MAX, np.uint64)
-        last_acc = np.zeros(n_app, np.uint64)
-        features: dict[str, Optional[np.ndarray]] = {}
-        for attr in drained:
-            recs = merged[attr]
-            if n == 0 or not len(recs):
-                features[attr] = None
-                continue
-            idx, orphan = joins[attr]
-            if orphan.any():
-                oi = idx[orphan] - n_agg
-                of = recs["first_seen_ns"][orphan]
-                np.minimum.at(first_acc, oi,
-                              np.where(of == 0, _U64_MAX, of))
-                np.maximum.at(last_acc, oi, recs["last_seen_ns"][orphan])
-            out = np.zeros(n, recs.dtype)
-            out[idx] = recs  # duplicate keys across drain chunks: last wins
-            features[attr] = out
-        if n_app:
-            s = events["stats"]
-            s["first_seen_ns"][n_agg:] = np.where(
-                first_acc == _U64_MAX, np.uint64(0), first_acc)
-            s["last_seen_ns"][n_agg:] = last_acc
+            events, features, n_app = _align_numpy(
+                agg_keys, agg_vals, drained, merged)
     evicted = EvictedFlows(events, **features)
     # appended standalone rows: ringbuf-fallback singles (or a racing
     # eviction) whose flow missed the aggregation drain — the bounded
     # double-count overload path, surfaced per drain
-    # (evict_ringbuf_fallback_total). The stages' own seconds are the stage
-    # clocks (stage_wall_seconds_total{stage="merge_percpu"|"align"}).
-    evicted.decode_stats = {"fallback_rows": n_app}
+    # (evict_ringbuf_fallback_total); the rows the native align carried
+    # (eviction_align_native_rows_total). The stages' own seconds are the
+    # stage clocks (stage_wall_seconds_total{stage="merge_percpu"|"align"}).
+    evicted.decode_stats = {
+        "fallback_rows": n_app,
+        "align_native_rows": len(events) if native is not None else 0}
     return evicted
+
+
+def _align_numpy(agg_keys: np.ndarray, agg_vals: np.ndarray,
+                 drained: dict, merged: dict) -> tuple:
+    """decode_eviction's align half in numpy (`_join_keys` + scatter): the
+    twin of flowpack.align_eviction, run where the native library is absent
+    or refuses the input, and the tests' oracle. Returns (events,
+    {attr: aligned or None}, appended orphan rows)."""
+    n_agg = len(agg_keys)
+    attrs = [a for a, (k, _v) in drained.items() if len(k)]
+    if attrs:
+        idx_blocks, orphan_blocks, appended_keys = _join_keys(
+            np.ascontiguousarray(agg_keys),
+            [np.ascontiguousarray(drained[a][0]) for a in attrs])
+        joins = {a: (idx_blocks[i], orphan_blocks[i])
+                 for i, a in enumerate(attrs)}
+    else:
+        joins, appended_keys = {}, np.empty((0, _KEY_SIZE), np.uint8)
+    n = n_agg + len(appended_keys)
+    events = flowpack.events_from_keys_stats(
+        agg_keys if n_agg else np.empty((0, _KEY_SIZE), np.uint8),
+        agg_vals[:, 0] if n_agg else np.empty(0, binfmt.FLOW_STATS_DTYPE),
+        n_total=n)
+    n_app = len(appended_keys)
+    if n_app:
+        events["key"][n_agg:] = appended_keys.view(
+            binfmt.FLOW_KEY_DTYPE).reshape(-1)
+    first_acc = np.full(n_app, _U64_MAX, np.uint64)
+    last_acc = np.zeros(n_app, np.uint64)
+    features: dict[str, Optional[np.ndarray]] = {}
+    for attr in drained:
+        recs = merged[attr]
+        if n == 0 or not len(recs):
+            features[attr] = None
+            continue
+        idx, orphan = joins[attr]
+        if orphan.any():
+            oi = idx[orphan] - n_agg
+            of = recs["first_seen_ns"][orphan]
+            np.minimum.at(first_acc, oi,
+                          np.where(of == 0, _U64_MAX, of))
+            np.maximum.at(last_acc, oi, recs["last_seen_ns"][orphan])
+        out = np.zeros(n, recs.dtype)
+        out[idx] = recs  # duplicate keys across drain chunks: last wins
+        features[attr] = out
+    if n_app:
+        s = events["stats"]
+        s["first_seen_ns"][n_agg:] = np.where(
+            first_acc == _U64_MAX, np.uint64(0), first_acc)
+        s["last_seen_ns"][n_agg:] = last_acc
+    return events, features, n_app
 
 
 class PackedEviction:
@@ -418,6 +445,7 @@ class NativeEvictPipeline:
         evicted = EvictedFlows(events, **feats)
         evicted.decode_stats = {
             "fallback_rows": res.n_orphans,
+            "align_native_rows": res.n_events,
             "drain_lanes": self._lanes,
             "seconds": time.perf_counter() - t0,
             "native_path": "fused",
@@ -462,8 +490,8 @@ class BpfmanFetcher:
     Eviction runs the columnar plane (decode_eviction); with more than one
     DRAIN LANE (EVICT_DRAIN_LANES) the per-feature-map drain→per-CPU-merge
     pairs run on a worker pool — one batched bpf(2) syscall stream per lane
-    — while the calling thread drains the aggregation map, and the
-    vectorized `_join_keys` alignment stays the single join point. The
+    — while the calling thread drains the aggregation map, and
+    decode_eviction's align half stays the single join point. The
     zero-copy drain-view lifetime rule holds PER LANE: a lane's views alias
     only its own map's cached batch buffers, each map is owned by exactly
     one lane per drain, and every view is copied out at the EvictedFlows

@@ -917,6 +917,337 @@ uint32_t fp_crc32c(const uint8_t *data, size_t n) {
 }
 
 // ===========================================================================
+// Eviction alignment: the key join, event compose and feature alignment of
+// one drain — the native form of loader._join_keys plus the numpy align of
+// loader.decode_eviction, which stay as its fallback and the tests' oracle.
+// fp_align_eviction serves the sequential and the lane drains as one
+// GIL-releasing ctypes call; fp_drain_to_resident runs the very same
+// align_join / align_fill below.
+//
+// Bit-exact with the twin: rows are numbered [agg | feature map 0 | map 1 |
+// ...]; a STABLE sort of (hash, row) — the twin's argsort(kind="stable") —
+// orders every key group by row, so a group's agg rows lead it and the LAST
+// of them is the match (dict-idiom parity); a group with no agg row appends
+// one orphan event, in sorted-group order. Two distinct keys on one 64-bit
+// hash anywhere in the drain redo the order lexicographically (the twin's
+// lexsort) before anything is written.
+//
+// Bounded by its input: the sort is an LSD radix sort (ALIGN_RADIX_PASSES
+// passes over the rows, a pass skipped where one bucket holds every row),
+// the walk visits each sorted row once and compares keys only where two
+// neighbours share a hash, and the rare lexicographic fallback is one
+// std::sort. The scratch is the caller's (fp_align_scratch_bytes): nothing
+// is allocated here.
+// ===========================================================================
+
+#define FP_ALIGN_MAX_FEATS 8
+#define ALIGN_RADIX_BITS 16
+#define ALIGN_RADIX_BUCKETS (1u << ALIGN_RADIX_BITS)
+#define ALIGN_RADIX_PASSES ((64 + ALIGN_RADIX_BITS - 1) / ALIGN_RADIX_BITS)
+
+// loader._hash_keys_u64 twin: the join's sort key over the 5 key words.
+static inline uint64_t key_hash64(const uint8_t *k) {
+    uint64_t w[5];
+    std::memcpy(w, k, sizeof(w));
+    uint64_t h = w[0];
+    for (int i = 1; i < 5; i++) {
+        h = (h ^ (w[i] * 0xC2B2AE3D27D4EB4FULL)) * 0x9E3779B97F4A7C15ULL;
+        h ^= h >> 29;  // per-round mix, exactly like the numpy twin
+    }
+    return h;
+}
+
+// One feature map of a drain. Every record type leads with its first- and
+// last-seen u64s (the orphan events' times are read from there).
+struct fp_align_feat {
+    const uint8_t *keys;  // [n] no_flow_key
+    const uint8_t *recs;  // [n] merged records, value_size bytes each
+    uint8_t *out;         // [>= n_events] aligned rows (the fill zeroes them)
+    uint64_t n, value_size;
+};
+
+struct fp_align_result {
+    uint64_t n_events, n_orphans, lex_fallback;
+};
+
+struct align_job {
+    const uint8_t *agg_keys, *agg_stats;
+    uint64_t n_agg, total, n_orphans, lex;
+    uint32_t n_feats;
+    struct fp_align_feat feats[FP_ALIGN_MAX_FEATS];
+    uint64_t feat_base[FP_ALIGN_MAX_FEATS + 1];  // first row of each map
+    // scratch views (align_bind_scratch)
+    uint64_t *hs, *hs_b, *app_first, *app_last;
+    uint32_t *rows, *rows_b, *feat_eidx, *app_row, *hist;
+};
+
+static size_t align_scratch_bytes(uint64_t total, uint64_t n_feat_rows) {
+    if (n_feat_rows == 0)
+        return 0;  // nothing to join: the agg rows compose as they are
+    return (2 * total + 2 * n_feat_rows) * (sizeof(uint64_t) + sizeof(uint32_t)) +
+           static_cast<size_t>(ALIGN_RADIX_PASSES) * ALIGN_RADIX_BUCKETS *
+               sizeof(uint32_t);
+}
+
+// Fills `j` from the drain's maps; -2 on arguments the join cannot take.
+static int align_setup(struct align_job *j, const uint8_t *agg_keys,
+                       const uint8_t *agg_stats, uint64_t n_agg,
+                       const struct fp_align_feat *feats, uint32_t n_feats) {
+    if (n_feats > FP_ALIGN_MAX_FEATS || (n_agg && (!agg_keys || !agg_stats)))
+        return -2;
+    std::memset(j, 0, sizeof(*j));
+    j->agg_keys = agg_keys;
+    j->agg_stats = agg_stats;
+    j->n_agg = n_agg;
+    j->n_feats = n_feats;
+    j->feat_base[0] = n_agg;
+    for (uint32_t f = 0; f < n_feats; f++) {
+        const struct fp_align_feat *m = &feats[f];
+        if (m->n && (!m->keys || !m->recs || m->value_size < 16))
+            return -2;
+        j->feats[f] = *m;
+        j->feat_base[f + 1] = j->feat_base[f] + m->n;
+    }
+    j->total = j->feat_base[n_feats];
+    if (j->total >= UINT32_MAX)
+        return -2;  // rows are u32 in the scratch
+    return 0;
+}
+
+static void align_bind_scratch(struct align_job *j, uint8_t *scratch) {
+    const uint64_t t = j->total, nf = t - j->n_agg;
+    uint64_t *w = reinterpret_cast<uint64_t *>(scratch);
+    j->hs = w;
+    j->hs_b = w + t;
+    j->app_first = w + 2 * t;
+    j->app_last = w + 2 * t + nf;
+    uint32_t *h = reinterpret_cast<uint32_t *>(w + 2 * t + 2 * nf);
+    j->rows = h;
+    j->rows_b = h + t;
+    j->feat_eidx = h + 2 * t;
+    j->app_row = h + 2 * t + nf;
+    j->hist = h + 2 * t + 2 * nf;
+}
+
+// `wa`: a key's five words, loaded once for the run it starts
+static inline bool key_eq(const uint64_t *wa, const uint8_t *b) {
+    uint64_t wb[5];
+    std::memcpy(wb, b, sizeof(wb));
+    return !((wa[0] ^ wb[0]) | (wa[1] ^ wb[1]) | (wa[2] ^ wb[2]) |
+             (wa[3] ^ wb[3]) | (wa[4] ^ wb[4]));
+}
+
+static inline const uint8_t *align_key(const struct align_job *j,
+                                       uint32_t row) {
+    if (row < j->n_agg)
+        return j->agg_keys + static_cast<size_t>(row) * sizeof(struct no_flow_key);
+    uint32_t f = 0;
+    while (row >= j->feat_base[f + 1])  // ends at the row's map (row < total)
+        f++;
+    return j->feats[f].keys +
+           static_cast<size_t>(row - j->feat_base[f]) * sizeof(struct no_flow_key);
+}
+
+// One pass over the sorted rows: group them by key, give every feature row
+// its event row (feat_eidx) and every agg-less group an orphan. With `hs`
+// (hash order) a group is a run of one hash, and two different keys in one
+// run are a collision: false, nothing kept. Without it (lexicographic order)
+// a group is a run of one key.
+static bool align_walk(struct align_job *j, const uint64_t *hs,
+                       const uint32_t *ord) {
+    const uint64_t t = j->total, n_agg = j->n_agg;
+    uint64_t n_app = 0, a = 0;
+    while (a < t) {
+        uint64_t b = a + 1;
+        if (!hs || (b < t && hs[b] == hs[a])) {
+            uint64_t wa[5];
+            std::memcpy(wa, align_key(j, ord[a]), sizeof(wa));
+            if (hs) {
+                for (; b < t && hs[b] == hs[a]; b++)
+                    if (!key_eq(wa, align_key(j, ord[b])))
+                        return false;
+            } else {
+                while (b < t && key_eq(wa, align_key(j, ord[b])))
+                    b++;
+            }
+        }
+        uint64_t k = a;
+        while (k < b && ord[k] < n_agg)  // a group's agg rows lead it
+            k++;
+        if (k < b) {
+            uint32_t eidx;
+            if (k > a) {
+                eidx = ord[k - 1];  // the last agg row wins
+            } else {
+                j->app_row[n_app] = ord[a];
+                j->app_first[n_app] = UINT64_MAX;
+                j->app_last[n_app] = 0;
+                eidx = static_cast<uint32_t>(n_agg + n_app++);
+            }
+            for (; k < b; k++)
+                j->feat_eidx[ord[k] - n_agg] = eidx;
+        }
+        a = b;
+    }
+    j->n_orphans = n_app;
+    return true;
+}
+
+// The join: hash, stable radix sort, walk (lexicographic on a collision).
+static void align_join(struct align_job *j) {
+    const uint64_t t = j->total, n_agg = j->n_agg;
+    j->n_orphans = 0;
+    j->lex = 0;
+    if (t == n_agg)
+        return;  // no feature rows (the twin skips the join too)
+    const size_t ks = sizeof(struct no_flow_key);
+    uint64_t *hs = j->hs;
+    uint32_t *rows = j->rows;
+    for (uint64_t r = 0; r < n_agg; r++) {
+        hs[r] = key_hash64(j->agg_keys + r * ks);
+        rows[r] = static_cast<uint32_t>(r);
+    }
+    for (uint32_t f = 0; f < j->n_feats; f++) {
+        const uint64_t base = j->feat_base[f];
+        for (uint64_t r = 0; r < j->feats[f].n; r++) {
+            hs[base + r] = key_hash64(j->feats[f].keys + r * ks);
+            rows[base + r] = static_cast<uint32_t>(base + r);
+        }
+    }
+    // LSD radix sort of (hash, row): every pass's histogram in one read
+    const uint32_t mask = ALIGN_RADIX_BUCKETS - 1;
+    uint32_t *hist = j->hist;
+    std::memset(hist, 0, sizeof(uint32_t) * ALIGN_RADIX_PASSES * ALIGN_RADIX_BUCKETS);
+    for (uint64_t i = 0; i < t; i++) {
+        const uint64_t h = hs[i];
+        for (uint32_t p = 0; p < ALIGN_RADIX_PASSES; p++)
+            hist[p * ALIGN_RADIX_BUCKETS + ((h >> (p * ALIGN_RADIX_BITS)) & mask)]++;
+    }
+    uint64_t *sh = hs, *dh = j->hs_b;
+    uint32_t *sr = rows, *dr = j->rows_b;
+    for (uint32_t p = 0; p < ALIGN_RADIX_PASSES; p++) {
+        const uint32_t shift = p * ALIGN_RADIX_BITS;
+        uint32_t *c = hist + p * ALIGN_RADIX_BUCKETS;
+        if (c[(sh[0] >> shift) & mask] == t)
+            continue;  // one bucket holds every row: the pass moves nothing
+        uint32_t sum = 0;
+        for (uint32_t b = 0; b < ALIGN_RADIX_BUCKETS; b++) {
+            const uint32_t n = c[b];
+            c[b] = sum;
+            sum += n;
+        }
+        for (uint64_t i = 0; i < t; i++) {
+            const uint32_t pos = c[(sh[i] >> shift) & mask]++;
+            dh[pos] = sh[i];
+            dr[pos] = sr[i];
+        }
+        std::swap(sh, dh);
+        std::swap(sr, dr);
+    }
+    if (align_walk(j, sh, sr))
+        return;
+    // distinct keys on one 64-bit hash (p ~ rows^2 / 2^65): the exact
+    // lexicographic order of the five key words, ties by row (lexsort twin)
+    j->lex = 1;
+    for (uint64_t r = 0; r < t; r++)
+        rows[r] = static_cast<uint32_t>(r);
+    std::sort(rows, rows + t, [j](uint32_t a, uint32_t b) {
+        uint64_t wa[5], wb[5];
+        std::memcpy(wa, align_key(j, a), sizeof(wa));
+        std::memcpy(wb, align_key(j, b), sizeof(wb));
+        for (int i = 0; i < 5; i++)
+            if (wa[i] != wb[i])
+                return wa[i] < wb[i];
+        return a < b;
+    });
+    align_walk(j, NULL, rows);
+}
+
+// The outputs: events (n_agg + n_orphans rows, every byte written) and each
+// feature map's aligned rows (`out`, zeroed, then its records scattered in
+// row order: duplicate keys across drain chunks, the last record wins).
+static void align_fill(const struct align_job *j, uint8_t *events) {
+    const uint64_t n_agg = j->n_agg, n_app = j->n_orphans;
+    const uint64_t n_events = n_agg + n_app;
+    if (n_agg)
+        fp_events_from_keys_stats(j->agg_keys, j->agg_stats, n_agg, events);
+    struct no_flow_event *ev = reinterpret_cast<struct no_flow_event *>(events);
+    for (uint64_t a = 0; a < n_app; a++) {
+        std::memcpy(&ev[n_agg + a].key, align_key(j, j->app_row[a]),
+                    sizeof(struct no_flow_key));
+        std::memset(&ev[n_agg + a].stats, 0, sizeof(struct no_flow_stats));
+    }
+    for (uint32_t f = 0; f < j->n_feats; f++) {
+        const struct fp_align_feat *m = &j->feats[f];
+        if (m->n == 0 || !m->out)
+            continue;
+        const size_t vs = m->value_size;
+        const uint32_t *eidx = j->feat_eidx + (j->feat_base[f] - n_agg);
+        std::memset(m->out, 0, n_events * vs);
+        for (uint64_t r = 0; r < m->n; r++) {
+            const uint8_t *rec = m->recs + r * vs;
+            const uint64_t e = eidx[r];
+            std::memcpy(m->out + e * vs, rec, vs);
+            if (e >= n_agg) {
+                uint64_t ft, lt;
+                std::memcpy(&ft, rec, 8);
+                std::memcpy(&lt, rec + 8, 8);
+                if (ft == 0)
+                    ft = UINT64_MAX;  // the 0 -> U64_MAX sentinel (loader)
+                if (ft < j->app_first[e - n_agg])
+                    j->app_first[e - n_agg] = ft;
+                if (lt > j->app_last[e - n_agg])
+                    j->app_last[e - n_agg] = lt;
+            }
+        }
+    }
+    for (uint64_t a = 0; a < n_app; a++) {
+        ev[n_agg + a].stats.first_seen_ns =
+            j->app_first[a] == UINT64_MAX ? 0 : j->app_first[a];
+        ev[n_agg + a].stats.last_seen_ns = j->app_last[a];
+    }
+}
+
+// Scratch bytes fp_align_eviction needs for a drain of these row counts.
+uint64_t fp_align_scratch_bytes(uint64_t n_agg, uint64_t n_feat_rows) {
+    return align_scratch_bytes(n_agg + n_feat_rows, n_feat_rows);
+}
+
+// The align half of a drain in one call. `events` and every feature map's
+// `out` hold `out_rows` rows; the caller sizes them for n_agg + every
+// feature row (the most events a drain can give) and keeps the first
+// n_events. Returns n_events, or -2 on bad arguments (the caller then runs
+// the numpy twin).
+int64_t fp_align_eviction(const uint8_t *agg_keys, const uint8_t *agg_stats,
+                          uint64_t n_agg, const struct fp_align_feat *feats,
+                          uint32_t n_feats, uint8_t *scratch,
+                          uint64_t scratch_bytes, uint8_t *events,
+                          uint64_t out_rows, struct fp_align_result *res) {
+    struct align_job j;
+    if (!res || (n_feats && !feats))
+        return -2;
+    std::memset(res, 0, sizeof(*res));
+    if (align_setup(&j, agg_keys, agg_stats, n_agg, feats, n_feats))
+        return -2;
+    const size_t need = align_scratch_bytes(j.total, j.total - n_agg);
+    if (scratch_bytes < need || (need && !scratch) ||
+        reinterpret_cast<uintptr_t>(scratch) % sizeof(uint64_t) ||
+        out_rows < j.total || (j.total && !events))
+        return -2;
+    for (uint32_t f = 0; f < n_feats; f++)
+        if (j.feats[f].n && !j.feats[f].out)
+            return -2;
+    if (need)
+        align_bind_scratch(&j, scratch);
+    align_join(&j);
+    align_fill(&j, events);
+    res->n_events = n_agg + j.n_orphans;
+    res->n_orphans = j.n_orphans;
+    res->lex_fallback = j.lex;
+    return static_cast<int64_t>(res->n_events);
+}
+
+// ===========================================================================
 // Fused one-call eviction pipeline (fp_drain_to_resident). ONE native call
 // owns the whole host chain of a drain: batched bpf(2) lookup-and-delete
 // over every map, per-CPU columnar merge, hash-sort key join (the
@@ -927,11 +1258,10 @@ uint32_t fp_crc32c(const uint8_t *data, size_t n) {
 //
 // SCHEDULING ONLY: the merge semantics are the very fp_merge_*_batch calls
 // above (never a fifth merge form), the pack is the very fp_pack_resident
-// above (never a fourth resident layout), and the join replicates
-// loader._join_keys bit-exactly (stable hash sort, collision fallback to the
-// lexicographic order, orphan appendix in sorted-group order, last-agg-row
-// match). tests/test_native_pipeline.py pins the fused output against the
-// Python-orchestrated chain.
+// above (never a fourth resident layout), and the join, compose and
+// alignment are fp_align_eviction's own align_join / align_fill (the
+// loader._join_keys twin, bit-exact). tests/test_native_pipeline.py pins the
+// fused output against the Python-orchestrated chain.
 //
 // Buffer ownership: per-map drain scratch, merged/aligned arrays, the event
 // compose buffer and the chunk table live in the fp_pipe handle and are
@@ -1238,18 +1568,6 @@ static void *pipe_worker(void *arg) {
 }
 #endif
 
-// loader._hash_keys_u64 twin: the join's pre-sort hash over the 5 key words.
-static inline uint64_t pipe_key_hash(const uint8_t *k) {
-    uint64_t w[5];
-    std::memcpy(w, k, sizeof(w));
-    uint64_t h = w[0];
-    for (int i = 1; i < 5; i++) {
-        h = (h ^ (w[i] * 0xC2B2AE3D27D4EB4FULL)) * 0x9E3779B97F4A7C15ULL;
-        h ^= h >> 29;  // per-round mix, exactly like the numpy twin
-    }
-    return h;
-}
-
 static int64_t pipe_pack(struct fp_pipe *p, const struct fp_pipe_pack_cfg *pk,
                          struct fp_pipe_result *res) {
     const uint64_t n_events = res->n_events;
@@ -1417,151 +1735,52 @@ int64_t fp_drain_to_resident(void *h, const struct fp_pipe_pack_cfg *pack,
         for (uint32_t i = 0; i < p->n_maps; i++)
             pipe_run_map(&p->maps[i]);
     }
-    uint64_t total = 0;
     for (uint32_t i = 0; i < p->n_maps; i++) {
         struct fp_pipe_map_state *m = &p->maps[i];
         res->drain_ns += m->drain_ns;
         res->merge_ns += m->merge_ns;
         res->syscalls += m->syscalls;
         res->map_rows[i] = m->n;
-        total += m->n;
         if (m->err == ENOMEM)
             return -1;
         if (m->err)
             res->batch_err_mask |= 1ull << i;
     }
     const uint64_t n_agg = p->maps[0].n;
-    // ---- join (loader._join_keys twin) + event compose + alignment ----
+    // ---- join + event compose + alignment (the fp_align_eviction routine) ----
     uint64_t t_join = pipe_now_ns();
-    const size_t ptr_sz = sizeof(const uint8_t *);
-    if (pipe_reserve(&p->join, total * (2 * ptr_sz + 5 * sizeof(uint64_t))))
-        return -1;
-    const uint8_t **kp = reinterpret_cast<const uint8_t **>(p->join.p);
-    const uint8_t **app_key = kp + total;
-    uint64_t *hs = reinterpret_cast<uint64_t *>(app_key + total);
-    uint64_t *ord = hs + total;
-    uint64_t *feat_eidx = ord + total;
-    uint64_t *app_first = feat_eidx + total;
-    uint64_t *app_last = app_first + total;
-    {
-        uint64_t g = 0;
-        for (uint32_t mi = 0; mi < p->n_maps; mi++) {
-            struct fp_pipe_map_state *m = &p->maps[mi];
-            for (uint32_t r = 0; r < m->n; r++, g++) {
-                kp[g] = m->keys.p + static_cast<size_t>(r) * sizeof(struct no_flow_key);
-                hs[g] = pipe_key_hash(kp[g]);
-                ord[g] = g;
-            }
-        }
-    }
-    std::sort(ord, ord + total, [hs](uint64_t a, uint64_t b) {
-        return hs[a] != hs[b] ? hs[a] < hs[b] : a < b;  // stable argsort twin
-    });
-    // collision check: distinct keys vs distinct hashes over the sort
-    uint64_t key_groups = total ? 1 : 0, hash_groups = total ? 1 : 0;
-    for (uint64_t j = 1; j < total; j++) {
-        if (std::memcmp(kp[ord[j]], kp[ord[j - 1]], sizeof(struct no_flow_key)))
-            key_groups++;
-        if (hs[ord[j]] != hs[ord[j - 1]])
-            hash_groups++;
-    }
-    if (key_groups != hash_groups) {
-        // u64 hash collision (~never): the exact lexicographic order twin
-        res->lex_fallback = 1;
-        std::sort(ord, ord + total, [kp](uint64_t a, uint64_t b) {
-            uint64_t wa[5], wb[5];
-            std::memcpy(wa, kp[a], sizeof(wa));
-            std::memcpy(wb, kp[b], sizeof(wb));
-            for (int i = 0; i < 5; i++)
-                if (wa[i] != wb[i])
-                    return wa[i] < wb[i];
-            return a < b;
-        });
-    }
-    // group walk: stable sort puts agg members (src < n_agg) first in each
-    // group, so the match is the LAST agg member; groups with none append
-    // one orphan event, in sorted-group order (the searchsorted twin)
-    uint64_t n_app = 0;
-    {
-        uint64_t a = 0;
-        while (a < total) {
-            uint64_t b = a + 1;
-            while (b < total && !std::memcmp(kp[ord[b]], kp[ord[a]],
-                                             sizeof(struct no_flow_key)))
-                b++;
-            int64_t agg_max = -1;
-            for (uint64_t j = a; j < b && ord[j] < n_agg; j++)
-                agg_max = static_cast<int64_t>(ord[j]);
-            uint64_t eidx;
-            if (agg_max >= 0) {
-                eidx = static_cast<uint64_t>(agg_max);
-            } else {
-                app_key[n_app] = kp[ord[a]];
-                app_first[n_app] = UINT64_MAX;
-                app_last[n_app] = 0;
-                eidx = n_agg + n_app++;
-            }
-            for (uint64_t j = a; j < b; j++)
-                if (ord[j] >= n_agg)
-                    feat_eidx[ord[j] - n_agg] = eidx;
-            a = b;
-        }
-    }
-    const uint64_t n_events = n_agg + n_app;
-    res->n_events = n_events;
-    res->n_agg = n_agg;
-    res->n_orphans = n_app;
-    if (pipe_reserve(&p->events, n_events * sizeof(struct no_flow_event)))
-        return -1;
-    std::memset(p->events.p, 0, n_events * sizeof(struct no_flow_event));
-    if (n_agg)
-        fp_events_from_keys_stats(p->maps[0].keys.p, p->maps[0].vals.p, n_agg,
-                                  p->events.p);
-    struct no_flow_event *ev =
-        reinterpret_cast<struct no_flow_event *>(p->events.p);
-    for (uint64_t a = 0; a < n_app; a++)
-        std::memcpy(&ev[n_agg + a].key, app_key[a],
-                    sizeof(struct no_flow_key));
-    // feature alignment: scatter merged rows to their event row (ascending —
-    // duplicate keys across drain chunks: last wins, like `out[idx] = recs`)
-    uint64_t fbase = 0;
+    struct fp_align_feat feats[FP_ALIGN_MAX_FEATS];
     for (uint32_t mi = 1; mi < p->n_maps; mi++) {
         struct fp_pipe_map_state *m = &p->maps[mi];
-        if (m->n == 0 || n_events == 0) {
-            fbase += m->n;
-            continue;
-        }
-        const size_t vs = m->value_size;
-        if (pipe_reserve(&m->aligned, n_events * vs))
+        feats[mi - 1] = {m->keys.p, m->merged.p, NULL, m->n, m->value_size};
+    }
+    struct align_job j;
+    if (align_setup(&j, p->maps[0].keys.p, p->maps[0].vals.p, n_agg, feats,
+                    p->n_maps - 1))
+        return -2;
+    if (j.total > n_agg) {
+        if (pipe_reserve(&p->join, align_scratch_bytes(j.total, j.total - n_agg)))
             return -1;
-        std::memset(m->aligned.p, 0, n_events * vs);
-        for (uint32_t r = 0; r < m->n; r++) {
-            const uint8_t *rec = m->merged.p + static_cast<size_t>(r) * vs;
-            const uint64_t e = feat_eidx[fbase + r];
-            std::memcpy(m->aligned.p + e * vs, rec, vs);
-            if (e >= n_agg) {
-                // orphan times: every record type leads with first/last u64s
-                uint64_t ft, lt;
-                std::memcpy(&ft, rec, 8);
-                std::memcpy(&lt, rec + 8, 8);
-                uint64_t *af = &app_first[e - n_agg];
-                uint64_t *al = &app_last[e - n_agg];
-                if (ft == 0)
-                    ft = UINT64_MAX;  // the 0 -> U64_MAX sentinel (loader)
-                if (ft < *af)
-                    *af = ft;
-                if (lt > *al)
-                    *al = lt;
-            }
-        }
+        align_bind_scratch(&j, p->join.p);
+    }
+    align_join(&j);
+    const uint64_t n_events = n_agg + j.n_orphans;
+    res->n_events = n_events;
+    res->n_agg = n_agg;
+    res->n_orphans = j.n_orphans;
+    res->lex_fallback = j.lex;
+    if (pipe_reserve(&p->events, n_events * sizeof(struct no_flow_event)))
+        return -1;
+    for (uint32_t mi = 1; mi < p->n_maps; mi++) {
+        struct fp_pipe_map_state *m = &p->maps[mi];
+        if (m->n == 0 || n_events == 0)
+            continue;
+        if (pipe_reserve(&m->aligned, n_events * m->value_size))
+            return -1;
+        j.feats[mi - 1].out = m->aligned.p;
         res->aligned[mi] = m->aligned.p;
-        fbase += m->n;
     }
-    for (uint64_t a = 0; a < n_app; a++) {
-        ev[n_agg + a].stats.first_seen_ns =
-            app_first[a] == UINT64_MAX ? 0 : app_first[a];
-        ev[n_agg + a].stats.last_seen_ns = app_last[a];
-    }
+    align_fill(&j, p->events.p);
     res->events = p->events.p;
     res->join_ns = pipe_now_ns() - t_join;
     // ---- resident-region pack (_fold_chunk twin) ----
@@ -1576,7 +1795,7 @@ int64_t fp_drain_to_resident(void *h, const struct fp_pipe_pack_cfg *pack,
 }
 
 #ifndef FP_ABI_VERSION
-#define FP_ABI_VERSION 10
+#define FP_ABI_VERSION 11
 #endif
 
 uint32_t fp_abi_version(void) { return FP_ABI_VERSION; }

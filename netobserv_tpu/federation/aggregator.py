@@ -114,13 +114,17 @@ class FederationAggregator:
             self._mesh = make_mesh(spec)
             self._ndata = spec.data
             self._pm = pmerge
-            self._state = pmerge.init_dist_state(self._cfg, self._mesh)
+            # merged counts are f32 from the first fold on: the jitted
+            # fold and roll then keep one dtype (sk.widen_count)
+            self._state = pmerge.init_dist_state(
+                self._cfg, self._mesh,
+                template=sk.widen_count(sk.init_state(self._cfg)))
             self._fold = pmerge.make_fold_delta_fn(self._mesh, self._cfg)
             self._roll = pmerge.make_merge_fn(self._mesh, self._cfg,
                                               with_tables=True)
         else:
             self._ndata = 1
-            self._state = sk.init_state(self._cfg)
+            self._state = sk.widen_count(sk.init_state(self._cfg))
             self._fold = retrace.jit(_federation_merge, "federation_merge",
                                      donate_argnums=(0,))
             self._roll = sk.make_roll_fn(self._cfg, with_tables=True,

@@ -81,7 +81,9 @@ def merge_tables(state: sk.SketchState, t: dict,
         dscp_bytes=state.dscp_bytes + t["dscp_bytes"],
         conv_fwd=state.conv_fwd + t["conv_fwd"],
         conv_rev=state.conv_rev + t["conv_rev"],
-        total_records=state.total_records + scalars[0],
+        # a merged count spans windows or agents: it stays f32 (see
+        # sketch.state.widen_count), never the live window's i32
+        total_records=state.total_records.astype(jnp.float32) + scalars[0],
         total_bytes=state.total_bytes + scalars[1],
         total_drop_bytes=state.total_drop_bytes + scalars[2],
         total_drop_packets=state.total_drop_packets + scalars[3],

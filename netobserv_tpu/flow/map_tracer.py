@@ -217,6 +217,11 @@ class MapTracer:
                 fallback = ds.get("fallback_rows", 0)
                 if fallback:
                     self._metrics.evict_ringbuf_fallback_total.inc(fallback)
+                # rows the native align carried (the rest: the numpy twin)
+                native_rows = ds.get("align_native_rows", 0)
+                if native_rows:
+                    self._metrics.eviction_align_native_rows_total.inc(
+                        native_rows)
                 # fused native pipeline (EVICT_NATIVE_PIPELINE): which host
                 # path carried this drain + the fused call's per-stage split
                 path = ds.get("native_path")

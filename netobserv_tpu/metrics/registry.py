@@ -150,6 +150,11 @@ class Metrics:
             "merge + key alignment, the userspace half of an eviction)",
             buckets=(.0001, .0005, .001, .005, .01, .05, .1, .5, 1),
             registry=self.registry)
+        self.eviction_align_native_rows_total = Counter(
+            p + "eviction_align_native_rows_total",
+            "Event rows whose key join and feature alignment ran in the "
+            "native library (fp_align_eviction, or the fused drain); the "
+            "rest ran the numpy twin", registry=self.registry)
         self.evicted_flows_per_drain = Histogram(
             p + "evicted_flows_per_drain",
             "Flows returned by one map drain (eviction batch size)",

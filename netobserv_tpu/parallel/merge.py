@@ -107,11 +107,15 @@ def put_replicated(mesh: Mesh, arr: np.ndarray) -> jax.Array:
     return _put_global(np.asarray(arr), mesh, P())
 
 
-def init_dist_state(cfg: sk.SketchConfig, mesh: Mesh) -> sk.SketchState:
-    """Per-device partial sketch state, zeros, laid out across the mesh."""
+def init_dist_state(cfg: sk.SketchConfig, mesh: Mesh,
+                    template: sk.SketchState | None = None
+                    ) -> sk.SketchState:
+    """Per-device partial sketch state, zeros, laid out across the mesh,
+    in the dtypes of `template` (default `sk.init_state(cfg)`)."""
     ndata = mesh.shape[DATA_AXIS]
     nsk = mesh.shape[SKETCH_AXIS]
-    template = sk.init_state(cfg)
+    if template is None:
+        template = sk.init_state(cfg)
     specs = _state_specs(template)
 
     def place(leaf, spec):

@@ -112,8 +112,11 @@ class SketchState(NamedTuple):
     # hashes under one seed); "fwd" is the canonical lower-hash endpoint
     conv_fwd: jax.Array       # f32[m]
     conv_rev: jax.Array       # f32[m]
-    total_records: jax.Array  # f32[] — window totals
-    total_bytes: jax.Array    # f32[]
+    # i32[] — records folded this window, an exact count: an f32 sum
+    # stops counting odd additions past 2^24 (16.8M records a window).
+    # f32 in a state whose count spans windows or agents (widen_count)
+    total_records: jax.Array
+    total_bytes: jax.Array    # f32[] — window totals
     total_drop_bytes: jax.Array    # f32[]
     total_drop_packets: jax.Array  # f32[]
     quic_records: jax.Array   # f32[] — window records with QUIC marker
@@ -190,7 +193,7 @@ def init_state(cfg: SketchConfig = SketchConfig()):
         dscp_bytes=jnp.zeros((N_DSCP,), jnp.float32),
         conv_fwd=jnp.zeros((cfg.ewma_buckets,), jnp.float32),
         conv_rev=jnp.zeros((cfg.ewma_buckets,), jnp.float32),
-        total_records=jnp.zeros((), jnp.float32),
+        total_records=jnp.zeros((), jnp.int32),
         total_bytes=jnp.zeros((), jnp.float32),
         total_drop_bytes=jnp.zeros((), jnp.float32),
         total_drop_packets=jnp.zeros((), jnp.float32),
@@ -714,7 +717,7 @@ def ingest(state: SketchState, arrays: dict[str, jax.Array],
             drop_causes=drop_causes, dscp_bytes=dscp_bytes,
             conv_fwd=conv_fwd, conv_rev=conv_rev,
             total_records=state.total_records + jnp.sum(
-                valid.astype(jnp.float32)),
+                valid.astype(jnp.int32)),
             total_bytes=state.total_bytes + jnp.sum(
                 jnp.where(valid, bytes_f, 0.0)),
             total_drop_bytes=tdb, total_drop_packets=tdp,
@@ -1017,6 +1020,16 @@ def make_ingest_dense_fn(donate: bool = True,
                        donate_argnums=(0,) if donate else ())
 
 
+def widen_count(state: SketchState) -> SketchState:
+    """`state` with its record count in f32, for a state whose count adds
+    up many windows or agents (the federation aggregate, an archive merge,
+    a state rolled in keep mode): an i32 wraps past 2^31 records, an f32
+    sum is approximate past 2^24 and never wraps. A live window keeps the
+    exact i32 count of `init_state`."""
+    return state._replace(
+        total_records=state.total_records.astype(jnp.float32))
+
+
 def decay_state(state: SketchState, factor: float) -> SketchState:
     """Sliding-window flavor: scale the linear sketches by `factor` instead of
     zeroing them (Count-Min and histograms are linear, so decay is exact for
@@ -1050,7 +1063,8 @@ def decay_state(state: SketchState, factor: float) -> SketchState:
         dscp_bytes=state.dscp_bytes * factor,
         conv_fwd=state.conv_fwd * factor,
         conv_rev=state.conv_rev * factor,
-        total_records=state.total_records * factor,
+        total_records=(state.total_records.astype(jnp.float32) * factor
+                       ).astype(state.total_records.dtype),
         total_bytes=state.total_bytes * factor,
         total_drop_bytes=state.total_drop_bytes * factor,
         total_drop_packets=state.total_drop_packets * factor,
@@ -1142,9 +1156,12 @@ def _roll_window(state, cfg, reset_sketches, decay_factor):
         # epoch); only its windowed counts roll: prev_counts <- counts,
         # counts <- 0 — next window's estimates rebuild from the fresh CM
         # while incumbents defend with last window's mass
+        # the count keeps its dtype (f32 in a widen_count state)
         new_state = fresh._replace(ddos=ddos_state, syn=syn_state,
                                    drops_ewma=drops_state,
                                    heavy=topk.slot_roll(state.heavy, 0.0),
+                                   total_records=jnp.zeros_like(
+                                       state.total_records),
                                    window=state.window + 1)
     else:
         # synack pairs with the syn EWMA's per-window rate (which roll just
@@ -1158,6 +1175,8 @@ def _roll_window(state, cfg, reset_sketches, decay_factor):
                                    # with the kept CM; churn = counts -
                                    # prev_counts per window. Eviction
                                    # EVENTS stay per-window like synack
+                                   # (total_records grows too: widen_count
+                                   # a state that may pass 2^31 records)
                                    heavy=topk.slot_roll(state.heavy, 1.0),
                                    heavy_evictions=jnp.zeros_like(
                                        state.heavy_evictions),
@@ -1205,7 +1224,7 @@ def state_tables(state: SketchState) -> dict[str, jax.Array]:
         "conv_rev": state.conv_rev,
         # federation.delta.SCALAR_FIELDS order
         "scalars": jnp.stack([
-            state.total_records, state.total_bytes,
+            state.total_records.astype(jnp.float32), state.total_bytes,
             state.total_drop_bytes, state.total_drop_packets,
             state.quic_records, state.nat_records,
             state.heavy_evictions]),

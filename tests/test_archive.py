@@ -336,6 +336,32 @@ def test_compacted_range_within_widened_cm_bars(tmp_path):
     assert snap["report"]["Records"] == 9 * 2 * 32
 
 
+def test_merged_record_totals_pass_2_31_without_wrapping(tmp_path):
+    """Range queries and compactions add up many windows' counts: past
+    2^31 records the merged total stays f32 (approximate past 2^24, never
+    wraps), through chained dispatches and through the compacted segment
+    written back for the next level."""
+    rng = np.random.default_rng(13)
+    universe = rng.integers(0, 2**32, (40, 10), dtype=np.uint32)
+    roll = sk.make_roll_fn(CFG, with_tables=True)
+    store = ArchiveStore(str(tmp_path), raw_windows=2, compact_group=2,
+                         max_levels=2)
+    arch = SketchArchive(store, CFG, agent_id="t", ladder_max=2)
+    s = sk.init_state(CFG)
+    per_window = float(3 << 29)  # 1.61e9 records, exact in f32
+    for w in range(6):
+        s = sk.ingest(s, make_arrays(rng, universe))
+        s, _, tables = roll(s)
+        host = {k: np.array(v) for k, v in tables.items()}
+        host["scalars"][0] = per_window
+        arch.write_window(host, window=w, ts_ms=1_000 + w)
+    assert any(seg.level > 0 for seg in store.segments())
+    snap = arch.engine.range_snapshot(0, 5)
+    assert snap["range"]["compacted"]
+    assert snap["range"]["merge_dispatches"] > 1
+    assert snap["report"]["Records"] == 6 * per_window
+
+
 def test_zero_retraces_across_ladder_and_compaction(tmp_path):
     """Watchdog-verified: every ladder entry compiles exactly once (its
     warmup call), across range queries of every size AND compactions —

@@ -262,13 +262,16 @@ class TestDecodeEviction:
         assert int(ev.events["stats"][shared]["last_seen_ns"]) == \
             int(mex["last_seen_ns"])
         # the merge and the join are one call each of their stage clocks
-        # (stage_{wall,cpu}_seconds_total); fallback_rows rides the
-        # EvictedFlows for map_tracer's counter
+        # (stage_{wall,cpu}_seconds_total); fallback_rows and the rows the
+        # native align carried ride the EvictedFlows for map_tracer's
+        # counters
         for stage in ("merge_percpu", "align"):
             w0, c0, n0 = clocks0.get(stage, (0.0, 0.0, 0))
             w1, c1, n1 = clocks1[stage]
             assert n1 == n0 + 1 and w1 >= w0 and c1 >= c0
-        assert ev.decode_stats == {"fallback_rows": 3}
+        assert ev.decode_stats == {
+            "fallback_rows": 3,
+            "align_native_rows": n + 3 if flowpack.native_available() else 0}
 
     def test_duplicate_keys_last_wins(self):
         """Duplicate agg keys across drain chunks: feature rows land on the
@@ -318,9 +321,11 @@ class TestDecodeEviction:
         assert got == want
 
     def test_hash_collision_falls_back_to_exact_sort(self, monkeypatch):
-        """Force every key onto one hash value: the join must detect the
-        distinct-keys-per-hash-group condition and produce the same result
-        through the lexsort fallback."""
+        """Force every key onto one hash value: the numpy join must detect
+        the distinct-keys-per-hash-group condition and produce the same
+        result through the lexsort fallback. The hash is the numpy twin's,
+        so the forced run takes the numpy align (the native align's own
+        collision test is tests/test_native_align.py)."""
         rng = np.random.default_rng(12)
         agg = _keys_u8(16, rng)
         stats = np.zeros((16, 1), binfmt.FLOW_STATS_DTYPE)
@@ -332,7 +337,9 @@ class TestDecodeEviction:
         monkeypatch.setattr(
             loader, "_hash_keys_u64",
             lambda ku8: np.zeros(len(ku8), np.uint64))
+        monkeypatch.setattr(flowpack, "align_eviction", lambda *a: None)
         got = loader.decode_eviction(agg, stats, {"extra": (fk, fv)})
+        assert got.decode_stats["align_native_rows"] == 0
         assert got.events.tobytes() == ref.events.tobytes()
         assert got.extra.tobytes() == ref.extra.tobytes()
 

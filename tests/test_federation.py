@@ -306,6 +306,39 @@ class TestFederatedEqualsUnion:
         assert agg._roll.retraces == 0
 
 
+def test_cluster_record_total_passes_2_31_without_wrapping():
+    """Agents' window counts add up in the aggregate: past 2^31 records
+    the cluster total stays f32 (approximate past 2^24, never wraps), and
+    the fold and roll keep that one dtype window after window (no
+    retrace)."""
+    rng = np.random.default_rng(12)
+    universe = rng.integers(0, 2**32, (N_DISTINCT, 10), dtype=np.uint32)
+    roll = sk.make_roll_fn(CFG, with_tables=True)
+    per_agent = float(3 << 29)  # 1.61e9 records, exact in f32
+    reports: list[dict] = []
+    agg = FederationAggregator(sketch_cfg=CFG, window_s=3600,
+                               sink=reports.append)
+    try:
+        for window in range(2):
+            for a in range(N_AGENTS):
+                s = sk.ingest(sk.init_state(CFG), make_arrays(rng, universe))
+                _, _, tables = roll(s)
+                host = {k: np.array(v) for k, v in tables.items()}
+                host["scalars"][0] = per_agent
+                frame = fdelta.encode_frame(
+                    host, agent_id=f"agent-{a}", window=window,
+                    ts_ms=1234 + window, dims=DIMS)
+                assert agg.ingest_frame(frame).accepted == 1
+            with agg._lock:
+                agg._close_window_locked()
+            agg._publish_queued()
+        assert [r["Records"] for r in reports] == [N_AGENTS * per_agent] * 2
+        assert agg._fold.retraces == 0
+        assert agg._roll.retraces == 0
+    finally:
+        agg.close()
+
+
 # --- rejection / robustness ---------------------------------------------
 
 class TestAggregatorRejection:

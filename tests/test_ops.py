@@ -760,3 +760,42 @@ def test_keep_state_roll_resets_synack_with_its_ewma():
         rolled, _ = sk.roll_window(s, cfg, **kwargs)
         assert float(np.asarray(rolled.synack).sum()) == 0.0, kwargs
         assert float(np.asarray(rolled.syn.rate).sum()) == 0.0, kwargs
+
+
+@pytest.mark.parametrize("valid_rows", [1, 3, 5])
+def test_window_record_count_is_exact_past_2_24(valid_rows):
+    """A window's record count stays exact past 2^24 records (16.8M, one
+    5-s window at 3.4M records/s): an f32 sum there rounds every odd
+    addition to even, so the published total drifts from the rows folded."""
+    from netobserv_tpu.sketch import state as sk
+
+    cfg = sk.SketchConfig(cm_width=1 << 10, topk=16, ewma_buckets=32)
+    n = 8
+    arrays = {
+        "keys": np.random.default_rng(2).integers(
+            0, 2**32, (n, 10)).astype(np.uint32),
+        "bytes": np.full(n, 10.0, np.float32),
+        "packets": np.ones(n, np.int32),
+        "rtt_us": np.zeros(n, np.int32),
+        "dns_latency_us": np.zeros(n, np.int32),
+        "sampling": np.zeros(n, np.int32),
+        "valid": np.arange(n) < valid_rows,
+        "tcp_flags": np.zeros(n, np.int32),
+        "dscp": np.zeros(n, np.int32),
+        "drop_bytes": np.zeros(n, np.int32),
+        "drop_packets": np.zeros(n, np.int32),
+        "drop_cause": np.zeros(n, np.int32),
+    }
+    s = sk.init_state(cfg)
+    s = s._replace(total_records=jnp.asarray(1 << 24, s.total_records.dtype))
+    for _ in range(3):
+        s = sk.ingest(s, arrays)
+    want = (1 << 24) + 3 * valid_rows
+    assert int(s.total_records) == want
+    _, report = sk.roll_window(s, cfg)
+    assert int(report.total_records) == want
+    # decay scales the count like every other window total (through f32:
+    # an estimate, within one record here)
+    decayed, _ = sk.roll_window(s, cfg, decay_factor=0.5)
+    assert decayed.total_records.dtype == s.total_records.dtype
+    assert abs(int(decayed.total_records) - want / 2) <= 1
